@@ -146,7 +146,7 @@ def report(rows: list) -> str:
 
 #: reference ceilings for hosts without a local accelerator (CPU): the
 #: roofline verdicts are computed against the v5e book numbers
-#: (observability.PEAK_FLOPS / profiling.HBM_BANDWIDTH) so boundedness is
+#: (observability.DEVICE_PEAKS) so boundedness is
 #: still deterministic and real — the report says which ceilings it used.
 REF_DTYPE = "bf16"
 REF_PEAK_FLOPS = 197e12

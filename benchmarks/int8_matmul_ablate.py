@@ -6,7 +6,7 @@ bf16-vs-xla-vs-pallas protocol to the whole kernel tier) — kept so the
 documented command line keeps working. The gate itself is unchanged:
 ``ops/pallas/int8_matmul.USE_FUSED_INT8_MATMUL`` stays default-off until
 the kernel beats the pure-XLA int8 fallback HERE, on the target TPU
-generation; off-TPU runs get an honest ``no-tpu-evidence`` verdict.
+generation; off-TPU the harness refuses to run.
 
 Usage: python benchmarks/int8_matmul_ablate.py [--sizes M,K,N[;M,K,N...]]
        [--iters N]

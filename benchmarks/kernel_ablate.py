@@ -3,11 +3,9 @@
 Every in-tree kernel earns its default-on flag HERE, on the target TPU
 generation, never from a CPU run: each client times a plain-bf16
 baseline, the XLA fallback the repo actually uses while the kernel is
-off, and the Pallas kernel itself. Off-TPU the kernel can only run in
-interpret mode, which measures the interpreter — those rows are labeled
-``pallas-interpret`` and the verdict is a hard ``no-tpu-evidence`` so a
-CPU run can never be mistaken for a speedup (the honest-verdict rule the
-int8 ablation established; this file generalizes it).
+off, and the compiled Pallas kernel itself. Off-TPU there is nothing to
+time (interpret mode measures the interpreter), so the harness refuses to
+run: a CPU run can never be mistaken for a speedup.
 
 Clients (``--kernel``):
 
@@ -53,15 +51,6 @@ def _time_fn(fn, iters: int) -> float:
     return sorted(times)[len(times) // 2]
 
 
-def _on_tpu() -> bool:
-    import jax
-
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
-
-
 def _int8_matmul_cases(shapes):
     """(meta, flops, variants, pallas_fn|None, flag, xla_ref) per
     M,K,N triple."""
@@ -70,7 +59,6 @@ def _int8_matmul_cases(shapes):
 
     from distkeras_tpu.ops.pallas import int8_matmul as k
 
-    on_tpu = _on_tpu()
     shapes = shapes or ((512, 512, 512), (1024, 1024, 1024),
                        (2048, 2048, 2048))
     for (m, kk, n), (qx, qw, sxw) in zip(
@@ -87,7 +75,7 @@ def _int8_matmul_cases(shapes):
         pallas_fn = None
         if k.fits(qx.shape, qw.shape):
             pallas_fn = lambda a=qxd, b=qwd, s=sxw: k.int8_matmul_dequant(
-                a, b, s, interpret=not on_tpu)
+                a, b, s)
         yield ({"m": m, "k": kk, "n": n}, 2 * m * kk * n, variants,
                pallas_fn, "USE_FUSED_INT8_MATMUL", "xla-int8")
 
@@ -100,7 +88,6 @@ def _flash_attention_cases(shapes):
 
     from distkeras_tpu.ops.pallas import flash_attention as k
 
-    on_tpu = _on_tpu()
     shapes = shapes or ((1, 1024, 8, 64), (1, 2048, 12, 64),
                        (2, 4096, 8, 128))
     rng = np.random.default_rng(0)
@@ -118,8 +105,7 @@ def _flash_attention_cases(shapes):
         }
         pallas_fn = None
         if k.fits((b, t, h, d)):
-            pallas_fn = lambda a=qkv16: k.flash_attention(
-                *a, causal=True, interpret=not on_tpu)
+            pallas_fn = lambda a=qkv16: k.flash_attention(*a, causal=True)
         yield ({"b": b, "t": t, "h": h, "d": d}, flops, variants,
                pallas_fn, "USE_FLASH_ATTENTION", "bf16")
 
@@ -131,23 +117,23 @@ CLIENTS = {
 
 
 def ablate(kernel: str, shapes=None, iters: int = 5):
-    """Yield one timing row per (variant, shape) + a verdict per shape.
-
-    The verdict is honest by construction: ``pallas-wins``/``xla-wins``
-    only when the kernel actually ran on a TPU; otherwise
-    ``no-tpu-evidence`` regardless of what interpret mode clocked.
-    """
+    """Yield one timing row per (variant, shape) + a verdict per shape
+    (``pallas-wins`` / ``xla-wins`` / ``kernel-declined`` when ``fits()``
+    rejects the shape). Raises off-TPU: there the kernel could only run
+    interpreted, and an interpreter's clock is not evidence."""
     import jax
 
-    on_tpu = _on_tpu()
+    platform = jax.devices()[0].platform
+    if platform != "tpu":
+        raise RuntimeError(
+            f"kernel_ablate times compiled Pallas TPU kernels; this "
+            f"process runs on {platform!r}")
     for meta, flops, variants, pallas_fn, flag, xla_ref in (
             CLIENTS[kernel](shapes)):
-        base = dict(meta, kernel=kernel,
-                    backend=jax.devices()[0].platform)
+        base = dict(meta, kernel=kernel, backend=platform)
         dts = {name: _time_fn(fn, iters) for name, fn in variants.items()}
         if pallas_fn is not None:
-            dts["pallas" if on_tpu else "pallas-interpret"] = _time_fn(
-                pallas_fn, iters)
+            dts["pallas"] = _time_fn(pallas_fn, iters)
         for variant, dt in dts.items():
             yield dict(base, variant=variant, sec=round(dt, 6),
                        tflops=round(flops / dt / 1e12, 3))
@@ -155,8 +141,8 @@ def ablate(kernel: str, shapes=None, iters: int = 5):
         yield dict(base, verdict=(
             "pallas-wins" if pallas_dt and pallas_dt < dts[xla_ref]
             else "xla-wins" if pallas_dt
-            else f"no-tpu-evidence (interpret timing is not evidence; "
-                 f"keep {flag} off)"))
+            else f"kernel-declined (fits() rejected the shape; keep "
+                 f"{flag} off)"))
 
 
 def parse_shapes(spec):

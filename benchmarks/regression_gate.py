@@ -7,7 +7,9 @@ loop and judges runs ACROSS releases. Three independent checks, each
 emitting machine-readable verdict rows:
 
 history (``--check history``)
-    Loads the committed ``BENCH_r*.json`` release ladder and asks whether
+    Loads the ``BENCH_r*.json`` release ladder under ``--repo-dir`` (none
+    is committed any more: the old one was taken on an earlier
+    installation; the next ladder is the driver's ledger) and asks whether
     the headline metrics (MFU, samples/sec/chip) are still improving:
     the newest release must beat the release ``--lookback`` steps behind
     it by at least ``--min-improvement`` (relative). The r03→r05 MFU
@@ -215,7 +217,7 @@ SOAK_FLOORS = {
 
 def load_history(repo_dir: str = REPO) -> List[Tuple[int, dict]]:
     """``[(release_n, parsed_dict), ...]`` sorted by release, from the
-    committed ``BENCH_r*.json`` files. Entries without a ``parsed`` dict
+    ``BENCH_r*.json`` files in ``repo_dir``. Entries without a ``parsed`` dict
     (failed bench runs) are skipped — absence is not a regression."""
     out = []
     for path in sorted(glob.glob(os.path.join(repo_dir, "BENCH_r*.json"))):

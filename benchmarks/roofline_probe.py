@@ -31,8 +31,8 @@ except ImportError:  # running from a source checkout: use the repo root
         os.path.abspath(__file__))))
 
 #: Reference chip for boundedness verdicts on hosts without a TPU
-#: (v5e bf16 peak / HBM bandwidth; observability.PEAK_FLOPS and
-#: profiling.HBM_BANDWIDTH hold the same numbers).
+#: (v5e bf16 peak / HBM bandwidth; observability.DEVICE_PEAKS holds the
+#: same numbers).
 REF_DTYPE = "bf16"
 REF_PEAK_FLOPS = 197e12
 REF_HBM_BW = 819e9

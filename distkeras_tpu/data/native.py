@@ -1,17 +1,22 @@
 """ctypes binding for the native batch assembler (native_src/batcher.cc).
 
-Compiled on first use with g++, cached next to the source (or under
-``~/.cache/distkeras_tpu`` when the install dir is read-only, e.g. a system
-site-packages); every entry point falls back to NumPy when the toolchain or
-the .so is unavailable, so the framework never hard-depends on the native
-path — it is a throughput optimization for the host side of the input
-pipeline.
+The one thing the program compiles for itself. Built on first use with
+g++ from the tracked source ONLY, into ``native_src/libdkbatch-<hash>.so``
+where ``<hash>`` is the SHA-256 of that source — so a binary built from
+another revision (or dropped in from elsewhere) has another name and can
+never load. Without a toolchain every entry point takes the NumPy path
+(:func:`available` says which is live); with one, a failed build raises
+with the compiler's message instead of silently degrading. The native
+path is a throughput optimization for the host side of the input
+pipeline; results are identical either way.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import shutil
 import subprocess
 import threading
 from typing import Optional
@@ -24,32 +29,34 @@ _TRIED = False
 
 _SRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "native_src", "batcher.cc")
-_CACHE_SO = os.path.join(
-    os.environ.get("XDG_CACHE_HOME",
-                   os.path.join(os.path.expanduser("~"), ".cache")),
-    "distkeras_tpu", "libdkbatch.so")
 
 
 def _build() -> Optional[str]:
-    if not os.path.exists(_SRC):
+    """Path of the library built from the tracked source (building it if
+    this revision's binary is not there yet), or None with no g++."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    so = os.path.join(os.path.dirname(_SRC), f"libdkbatch-{digest}.so")
+    if os.path.exists(so):
+        return so
+    if shutil.which("g++") is None:
         return None
-    # Prefer caching next to the source (source checkouts); fall back to the
-    # user cache dir when the install location is read-only (system installs).
-    for so in (os.path.join(os.path.dirname(_SRC), "libdkbatch.so"),
-               _CACHE_SO):
-        try:
-            if os.path.exists(so) and (os.path.getmtime(so) >=
-                                       os.path.getmtime(_SRC)):
-                return so
-            os.makedirs(os.path.dirname(so), exist_ok=True)
-            subprocess.run(
-                ["g++", "-O3", "-shared", "-fPIC", "-o", so, _SRC,
-                 "-lpthread"],
-                check=True, capture_output=True, timeout=120)
-            return so
-        except Exception:
-            continue
-    return None
+    # build under a private name, then rename: a concurrent process never
+    # loads a half-written library
+    tmp = f"{so}.{os.getpid()}.tmp"
+    try:
+        subprocess.run(
+            ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, _SRC, "-lpthread"],
+            check=True, capture_output=True, text=True, timeout=120)
+        os.replace(tmp, so)
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"building {_SRC} failed (g++ exit {e.returncode}):\n"
+            f"{e.stderr}") from e
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    return so
 
 
 def _lib() -> Optional[ctypes.CDLL]:
@@ -57,26 +64,24 @@ def _lib() -> Optional[ctypes.CDLL]:
     with _LOCK:
         if _TRIED:
             return _LIB
-        _TRIED = True
         so = _build()
+        _TRIED = True
         if so is None:
             return None
-        try:
-            lib = ctypes.CDLL(so)
-            lib.dk_gather_rows.argtypes = [
-                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                ctypes.c_int64, ctypes.c_int64, ctypes.c_int32]
-            lib.dk_gather_rows.restype = None
-            lib.dk_permutation.argtypes = [
-                ctypes.c_void_p, ctypes.c_int64, ctypes.c_uint64]
-            lib.dk_permutation.restype = None
-            _LIB = lib
-        except OSError:
-            _LIB = None
+        lib = ctypes.CDLL(so)
+        lib.dk_gather_rows.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_int32]
+        lib.dk_gather_rows.restype = None
+        lib.dk_permutation.argtypes = [
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_uint64]
+        lib.dk_permutation.restype = None
+        _LIB = lib
         return _LIB
 
 
 def available() -> bool:
+    """True when the native library is live, False on the NumPy path."""
     return _lib() is not None
 
 

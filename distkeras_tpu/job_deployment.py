@@ -310,6 +310,18 @@ class JobHandle:
             return json.load(f)
 
 
+def _holds_tpu() -> bool:
+    """True when THIS process has initialised a JAX backend on a TPU.
+    Never initialises one itself: asking must not take the chip."""
+    if "jax" not in sys.modules:
+        return False
+    import jax
+    from jax._src import xla_bridge
+
+    return (xla_bridge.backends_are_initialized()
+            and jax.default_backend() == "tpu")
+
+
 class LocalLauncher:
     """Submit-and-poll executor for saved bundles — the reference's remote
     job-deployment shape with the transport bound to a local subprocess.
@@ -321,6 +333,14 @@ class LocalLauncher:
     the launcher has every process call ``distributed.initialize``). The
     submit/poll/results contract is transport-agnostic: a remote backend
     only swaps ``subprocess.Popen`` for its own dispatch.
+
+    One process per chip: a TPU belongs to the one process that
+    initialised JAX on it. The child inherits this process's environment,
+    so a launcher process that has already run JAX on the TPU cannot hand
+    the chip to its job — the child would fail or hang at backend init.
+    ``submit`` refuses that case by name. Launch from a process that has
+    not touched JAX (importing this package does not), or pin the child
+    elsewhere with ``env={..., "JAX_PLATFORMS": "cpu"}``.
     """
 
     def __init__(self, python: Optional[str] = None,
@@ -337,6 +357,13 @@ class LocalLauncher:
                 f"{bundle_dir!r} is not a bundle (no run_punchcard.py); "
                 f"create one with Punchcard.save_bundle")
         env = dict(self.env if self.env is not None else os.environ)
+        if _holds_tpu() and "tpu" in (env.get("JAX_PLATFORMS") or "tpu"):
+            raise RuntimeError(
+                "this process has initialised JAX on the TPU and holds the "
+                "chip; a job launched from it could not acquire it (one "
+                "process per chip). Submit from a process that has not "
+                "run JAX, or pin the job off the chip with "
+                "env={..., 'JAX_PLATFORMS': 'cpu'}")
         # the bundle contract requires distkeras_tpu importable in the
         # child; fall back to this interpreter's copy AFTER any
         # caller-supplied PYTHONPATH so an env override (pinned or patched

@@ -40,8 +40,8 @@ class BertMLM(nn.Module):
     #: stays f32
     precision: Optional[str] = None
     #: "xla" | "flash" — attention kernel dispatch (ops/attention.py);
-    #: note the padding mask forces the XLA path per-call until the
-    #: fused kernel learns key-side masks
+    #: note "flash" raises on a call that carries a padding mask until
+    #: the fused kernel learns key-side masks
     attention: Optional[str] = None
 
     @nn.compact

@@ -5,8 +5,9 @@ long-context ABSENT) — this is the framework's first-class long-context
 story: a GPT-style decoder whose attention can run either
 
 - ``attention="full"``: single-device causal attention,
-- ``attention="flash"``: the fused pallas TPU kernel (O(seq) memory;
-  measured 1.4x over the XLA path at seq 8192 on v5e), or
+- ``attention="flash"``: the fused pallas TPU kernel (O(seq) memory,
+  TPU only; measured 1.4x over the XLA path at seq 8192 on v5e on an
+  earlier installation, not re-measured), or
 - ``attention="ring"``: ring attention over a ``seq`` mesh axis
   (ops/ring_attention.py) — the module then operates on the LOCAL sequence
   block inside ``shard_map``, with global positions derived from
@@ -140,7 +141,7 @@ class CausalSelfAttention(nn.Module):
                 new_cache = {"k": cache["k"].at[phys, off].set(k),
                              "v": cache["v"].at[phys, off].set(v)}
                 if _fa.paged_dispatch(q.shape, cache["k"].shape,
-                                      page_table.shape):
+                                      page_table.shape, q.dtype):
                     # fused paged kernel (DESIGN.md §23): the page DMAs
                     # are indexed by page_table INSIDE the kernel grid —
                     # the dense [b, max_len] HBM view below is never
@@ -186,8 +187,8 @@ class CausalSelfAttention(nn.Module):
                                  causal=True)
         elif self.attention == "flash":
             # resolve()-style dispatch (ops/attention.py): in-repo fused
-            # kernel when enabled+fits, else upstream pallas on TPU,
-            # else the XLA path — preserves this field's old semantics
+            # kernel when enabled+fits, else the upstream pallas kernel;
+            # off-TPU it raises rather than run XLA under this name
             from distkeras_tpu.ops.attention import apply_attention
 
             out = apply_attention(q, k, v, causal=True, attention="flash")
@@ -452,7 +453,7 @@ def _paged_int8_attention(q, k, v, cache, page_table, pos, cache_index,
                  "v_scale": cache["v_scale"].at[phys_w].set(vsc)}
     if _fa.PAGED_INT8_KERNEL and _fa.paged_dispatch(
             q.shape, (scratch_page + 1, ps, heads, head_dim),
-            page_table.shape):
+            page_table.shape, q.dtype):
         # follow-up flag (default OFF, the groupnorm lesson): feed the
         # fused kernel a dequantized f32 pool so the page DMAs stay
         # kernel-side. The pool already holds this call's block, so the

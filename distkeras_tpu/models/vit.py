@@ -40,7 +40,7 @@ class ViT(nn.Module):
     precision: Optional[str] = None
     #: "xla" | "flash" — attention kernel dispatch (ops/attention.py);
     #: ViT attention is bidirectional, so "flash" needs the in-repo
-    #: kernel's non-causal path (falls back to XLA until its flag is on)
+    #: kernel's non-causal path (and raises until its flag is on)
     attention: Optional[str] = None
 
     @nn.compact

@@ -14,12 +14,12 @@ lives in ``ops/ring_attention.py``.
 
 Kernel dispatch (DESIGN.md §23): every attention call site routes through
 ``apply_attention(..., attention=)`` — a ``precision.resolve()``-style
-switch. ``"xla"`` (default) is the einsum path below; ``"flash"`` prefers
-the in-repo fused Pallas kernel (``ops/pallas/flash_attention.py``) when
-its ablation flag is on AND ``fits()`` accepts the shape, then the
-upstream pallas kernel on TPU, then falls back to the XLA path — the
-switch never errors on an unsupported shape, it just declines the kernel
-(the groupnorm lesson, DESIGN.md §6).
+switch. ``"xla"`` (default) is the einsum path below. ``"flash"`` names a
+fused Pallas TPU kernel and runs one or raises with the reason: the
+in-repo kernel (``ops/pallas/flash_attention.py``) when its ablation flag
+is on and ``fits()`` accepts the shape, else the upstream pallas kernel
+(causal only). It never substitutes the XLA path in silence — off-TPU,
+under a padding mask, or on a shape neither kernel takes, it raises.
 """
 
 from __future__ import annotations
@@ -40,17 +40,16 @@ MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 def flash_attention_causal(q: jax.Array, k: jax.Array, v: jax.Array
                            ) -> jax.Array:
-    """Fused causal attention via the in-library pallas TPU kernel.
+    """Fused causal attention via the upstream pallas TPU kernel
+    (``jax.experimental.pallas.ops.tpu.flash_attention``).
 
     [batch, seq, heads, head_dim] in/out (transposed to the kernel's BHTD
     internally). O(seq) memory instead of materializing the [seq, seq]
     score matrix — the single-chip long-context path, complementing ring
     attention's cross-chip sequence parallelism. Constraints inherited
-    from the kernel: seq a multiple of its block size (powers of two >=
-    128 are safe); falls back to the XLA path off-TPU.
+    from the kernel: TPU only, seq a multiple of its block size (powers of
+    two >= 128 are safe).
     """
-    if jax.devices()[0].platform != "tpu":
-        return dot_product_attention(q, k, v, causal=True)
     from jax.experimental.pallas.ops.tpu import flash_attention as fa
 
     scale = q.shape[-1] ** -0.5
@@ -80,23 +79,36 @@ def apply_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     attention: Optional[str] = None) -> jax.Array:
     """Dispatch one attention call per the resolved mode.
 
-    ``"flash"`` dispatch chain, best first, each link gated on what it
-    can actually handle: in-repo fused kernel (requires its default-off
-    ablation flag, a TPU, a ``fits()``-shaped input, and no padding
-    mask — the kernel only knows the causal mask), else the upstream
-    pallas kernel (TPU, causal only), else the XLA einsum path. The
-    fallback is silent by design: model code picks a mode once and the
-    switch degrades per-shape.
+    ``"flash"`` runs a fused kernel or raises: the in-repo kernel when
+    its default-off ablation flag is on and ``fits()`` takes the shape,
+    else the upstream pallas kernel (causal only). Both are TPU kernels
+    that know only the causal mask, so another platform or a padding
+    mask is an error naming the reason, never a quiet XLA run.
     """
     mode = resolve_attention(attention)
-    if mode == "flash" and mask is None:
-        from distkeras_tpu.ops.pallas import flash_attention as _fa
+    if mode == "xla":
+        return dot_product_attention(q, k, v, mask=mask, causal=causal)
+    from distkeras_tpu.ops.pallas import flash_attention as _fa
 
-        if _fa.kernel_enabled() and _fa.fits(q.shape):
-            return _fa.flash_attention(q, k, v, causal=causal)
-        if causal:
-            return flash_attention_causal(q, k, v)
-    return dot_product_attention(q, k, v, mask=mask, causal=causal)
+    platform = jax.devices()[0].platform
+    if platform != "tpu":
+        raise RuntimeError(
+            f"attention='flash' names a Pallas TPU kernel but this process "
+            f"runs on {platform!r}; use attention='xla' here")
+    if mask is not None:
+        raise ValueError(
+            "attention='flash': the fused kernels know only the causal "
+            "mask, not a padding mask; use attention='xla'")
+    if _fa.USE_FLASH_ATTENTION and _fa.fits(q.shape):
+        return _fa.flash_attention(q, k, v, causal=causal)
+    if not causal:
+        raise ValueError(
+            f"attention='flash' on a bidirectional call of shape "
+            f"{q.shape}: the upstream kernel is causal-only and the in-repo "
+            f"kernel declined (USE_FLASH_ATTENTION="
+            f"{_fa.USE_FLASH_ATTENTION}, fits={_fa.fits(q.shape)}); use "
+            f"attention='xla'")
+    return flash_attention_causal(q, k, v)
 
 
 def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,

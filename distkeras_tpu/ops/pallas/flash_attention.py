@@ -31,14 +31,19 @@ DEFAULT OFF (``USE_FLASH_ATTENTION = False``), the groupnorm lesson
 (DESIGN.md §6): a custom call is a fusion FENCE to XLA, and this kernel
 must beat the XLA attention in its OWN ablation
 (``benchmarks/kernel_ablate.py --kernel flash_attention``) on real
-hardware before a BENCH round flips the default. Until then every call
-site falls back to the XLA path at trace time. Tests force the kernels
-through ``interpret=True`` on CPU (forward/backward ulp-parity for the
-training kernel; bitwise parity for the paged kernel).
+hardware before the default flips. Until then ``attention="flash"``
+reaches the upstream pallas kernel and the paged branch takes the XLA
+gather. Tests force the kernels through ``interpret=True`` on CPU
+(forward/backward ulp-parity for the training kernel; bitwise parity for
+the paged kernel); ``chip_smoke.py`` compiles both WITHOUT interpret on
+the chip and compares them with their XLA references.
 
-Tiling (see /opt/skills/guides: f32 min tile (8, 128), MXU 128x128):
-default 128x128 tiles; head_dim rides the lane dimension (padded below
-128 — honest cost for small heads, stated by ``fits``).
+Tiling (see /opt/skills/guides: f32 min tile (8, 128), bf16 (16, 128),
+MXU 128x128): default 128x128 tiles; head_dim rides the lane dimension
+(padded below 128 — honest cost for small heads, stated by ``fits``).
+Every block's last two dims are multiples of the tile or the full array
+dims: the per-row statistics (lse, delta) are ``[b, h, 1, t]`` lane-major
+rows, transposed to and from the kernels' sublane-column form in VMEM.
 """
 
 from __future__ import annotations
@@ -74,7 +79,8 @@ PAGED_INT8_KERNEL = False
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 
-#: stay under ~16 MB/core with headroom for double-buffered page DMAs
+#: Mosaic's scoped-VMEM limit on v5e is 16 MiB (measured: the compiler
+#: rejects a 16.02 MiB kernel); stay under it with headroom
 _VMEM_BUDGET_BYTES = 14 * 1024 * 1024
 
 #: per-row softmax statistics are replicated across one lane tile so
@@ -83,10 +89,7 @@ _STATS_LANES = 128
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 def kernel_enabled() -> bool:
@@ -97,9 +100,9 @@ def kernel_enabled() -> bool:
 def fits(q_shape, block_q: int = DEFAULT_BLOCK_Q,
          block_k: int = DEFAULT_BLOCK_K) -> bool:
     """The training kernel handles [batch, seq, heads, head_dim] with the
-    sequence block-aligned and the head riding the lane dim; everything
-    else falls back to XLA (padding ragged sequences inside the kernel
-    would hide the cost being measured)."""
+    sequence block-aligned and the head riding the lane dim; it declines
+    everything else (padding ragged sequences inside the kernel would
+    hide the cost being measured)."""
     if len(q_shape) != 4:
         return False
     _, t, _, d = q_shape
@@ -110,9 +113,21 @@ def fits(q_shape, block_q: int = DEFAULT_BLOCK_Q,
     return 8 <= d <= 128 and d % 8 == 0
 
 
-def paged_fits(q_shape, pages_shape, page_table_shape) -> bool:
+def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of one ``[rows, cols]`` minor pair: Mosaic pads it to
+    whole (sublane, lane) tiles — (8, 128) at 32 bits, (16, 128) at 16."""
+    sublanes = 8 * (4 // itemsize)
+    return (-(-rows // sublanes) * sublanes
+            * -(-cols // 128) * 128 * itemsize)
+
+
+def paged_fits(q_shape, pages_shape, page_table_shape, dtype) -> bool:
     """The paged kernel stages one row's K/V view in VMEM; decline when
-    that staging buffer (plus q/out blocks) would not fit."""
+    that staging buffer (plus the page, q and out blocks and the
+    ``[t, max_len]`` f32 softmax temporaries) would not fit. Every
+    position of the view is a ``[heads, head_dim]`` minor pair, counted
+    at its PADDED tile size — at GPT-2-small's (12, 64) that is 2.7x
+    the unpadded bytes."""
     if len(q_shape) != 4 or len(pages_shape) != 4:
         return False
     b, t, h, d = q_shape
@@ -120,10 +135,13 @@ def paged_fits(q_shape, pages_shape, page_table_shape) -> bool:
     if (h, d) != (hp, dp):
         return False
     max_len = page_table_shape[1] * ps
-    itemsize = 4  # budget at f32; bf16 halves it
-    staging = 2 * max_len * h * d * itemsize       # k_view + v_view
-    blocks = (2 * ps + 2 * t) * h * d * itemsize   # page DMAs + q + out
-    return staging + blocks <= _VMEM_BUDGET_BYTES
+    itemsize = np.dtype(dtype).itemsize
+    cell = _tile_bytes(h, d, itemsize)
+    staging = 2 * max_len * cell                           # k_view + v_view
+    pages = 2 * 2 * ps * cell                              # double-buffered
+    q_out = 2 * 2 * h * _tile_bytes(t, d, itemsize)
+    softmax = 4 * _tile_bytes(t, max_len, 4)   # logits, mask, exp, weights
+    return staging + pages + q_out + softmax <= _VMEM_BUDGET_BYTES
 
 
 # -- training kernel: forward ------------------------------------------------
@@ -167,12 +185,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             s = jnp.where(q_pos >= k_pos, s, MASK_VALUE)
         m_prev = m_ref[...]                                 # [bq, 128]
         l_prev = l_ref[...]
-        m_cur = jnp.max(s, axis=1)[:, None]                 # [bq, 1]
+        m_cur = jnp.max(s, axis=1, keepdims=True)           # [bq, 1]
         m_next = jnp.maximum(m_prev, m_cur)                 # replicated
         alpha = jnp.exp(m_prev - m_next)                    # rescale old
         p = jnp.exp(s - m_next[:, :1])                      # [bq, bk]
         m_ref[...] = m_next
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1)[:, None]
+        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
         pv = jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[0, 0, :, :],
             (((1,), (0,)), ((), ())),
@@ -183,7 +201,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _finish():
         o_ref[0, 0, :, :] = (acc_ref[...]
                              / l_ref[:, :1]).astype(o_ref.dtype)
-        lse_ref[0, 0, :] = m_ref[:, 0] + jnp.log(l_ref[:, 0])
+        # the statistics are lane-replicated sublane columns; the lse
+        # row is stored lane-major ([1, bq]), so transpose the replicated
+        # tile and keep one sublane
+        lse = m_ref[...] + jnp.log(l_ref[...])              # [bq, 128]
+        lse_ref[0, 0, :, :] = lse.T[:1, :]
 
 
 def _fwd_impl(q, k, v, causal, block_q, block_k, interpret):
@@ -195,7 +217,7 @@ def _fwd_impl(q, k, v, causal, block_q, block_k, interpret):
     nq, nk = t // block_q, t // block_k
     kwargs = {}
     if not interpret:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"))
     o, lse = pl.pallas_call(
@@ -214,12 +236,12 @@ def _fwd_impl(q, k, v, causal, block_q, block_k, interpret):
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d),
                          lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda ib, ih, iq, ik: (ib, ih, iq)),
+            pl.BlockSpec((1, 1, 1, block_q),
+                         lambda ib, ih, iq, ik: (ib, ih, 0, iq)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, t), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, 1, t), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
@@ -233,6 +255,15 @@ def _fwd_impl(q, k, v, causal, block_q, block_k, interpret):
 
 
 # -- training kernel: backward (recomputed tiles) ----------------------------
+
+def _row_to_col(row_ref):
+    """A ``(1, 1, 1, bq)`` lane-major statistics block as a ``[bq, 1]``
+    sublane column (the orientation that broadcasts against a
+    ``[bq, bk]`` score tile): replicate over one lane tile of sublanes,
+    transpose, keep one lane."""
+    row = row_ref[0, 0, :, :]                               # [1, bq]
+    return jnp.broadcast_to(row, (_STATS_LANES, row.shape[1])).T[:, :1]
+
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, acc_ref, *,
@@ -266,11 +297,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             s = jnp.where(q_pos >= k_pos, s, MASK_VALUE)
         # recompute the probability tile from the saved log-sum-exp:
         # masked entries underflow to exact zero, so they shed no grad
-        p = jnp.exp(s - lse_ref[0, 0, :][:, None])          # [bq, bk]
+        p = jnp.exp(s - _row_to_col(lse_ref))               # [bq, bk]
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)             # [bq, bk]
-        ds = p * (dp - delta_ref[0, 0, :][:, None])
+        ds = p * (dp - _row_to_col(delta_ref))
         acc_ref[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)             # [bq, d]
@@ -312,14 +343,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             k_pos = ik * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, MASK_VALUE)
-        p = jnp.exp(s - lse_ref[0, 0, :][:, None])          # [bq, bk]
+        p = jnp.exp(s - _row_to_col(lse_ref))               # [bq, bk]
         dv_acc_ref[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)             # [bk, d]
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0, :][:, None])
+        ds = p * (dp - _row_to_col(delta_ref))
         dk_acc_ref[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)             # [bk, d]
@@ -339,12 +370,12 @@ def _bwd_impl(q, k, v, o, lse, do, causal, block_q, block_k, interpret):
     # delta[b,h,i] = sum_d do*o — the rowwise correction term; cheap
     # elementwise work XLA fuses fine, so it stays outside the kernels
     delta = jnp.sum(dot_.astype(jnp.float32) * ot.astype(jnp.float32),
-                    axis=-1)
+                    axis=-1)[:, :, None, :]                 # [b, h, 1, t]
     nq, nk = t // block_q, t // block_k
     scale = d ** -0.5
     kwargs = {}
     if not interpret:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"))
 
@@ -352,8 +383,8 @@ def _bwd_impl(q, k, v, o, lse, do, causal, block_q, block_k, interpret):
                           lambda ib, ih, i, j: (ib, ih, i, 0))
     k_spec = pl.BlockSpec((1, 1, block_k, d),
                           lambda ib, ih, i, j: (ib, ih, j, 0))
-    row_spec = pl.BlockSpec((1, 1, block_q),
-                            lambda ib, ih, i, j: (ib, ih, i))
+    row_spec = pl.BlockSpec((1, 1, 1, block_q),
+                            lambda ib, ih, i, j: (ib, ih, 0, i))
     dq = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, scale=scale, block_q=block_q,
@@ -371,8 +402,8 @@ def _bwd_impl(q, k, v, o, lse, do, causal, block_q, block_k, interpret):
                            lambda ib, ih, j, i: (ib, ih, i, 0))
     kT_spec = pl.BlockSpec((1, 1, block_k, d),
                            lambda ib, ih, j, i: (ib, ih, j, 0))
-    rowT_spec = pl.BlockSpec((1, 1, block_q),
-                             lambda ib, ih, j, i: (ib, ih, i))
+    rowT_spec = pl.BlockSpec((1, 1, 1, block_q),
+                             lambda ib, ih, j, i: (ib, ih, 0, i))
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, scale=scale, block_q=block_q,
@@ -425,8 +456,7 @@ def flash_attention(q, k, v, causal: bool = True,
     if not fits(q.shape, block_q, block_k):
         raise ValueError(
             f"flash_attention fits() rejected shape {q.shape} at blocks "
-            f"({block_q}, {block_k}); dispatch through the resolve "
-            f"switch, which falls back to XLA")
+            f"({block_q}, {block_k})")
     return _flash(q, k, v, causal, block_q, block_k, interpret)
 
 
@@ -457,19 +487,19 @@ def _paged_kernel(pt_ref, ci_ref, q_ref, kp_ref, vp_ref, o_ref,
         key_pos = jax.lax.broadcasted_iota(
             jnp.int32, (block_t, max_len), 1)
         mask = key_pos <= pos
-        outs = []
         for hh in range(num_heads):  # static unroll: rank-2 MXU dots
-            qh = q_ref[0, :, hh, :]                        # [t, d]
+            qh = q_ref[0, hh]                              # [t, d]
             kh = kview_ref[:, hh, :]                       # [max_len, d]
             vh = vview_ref[:, hh, :]
             logits = jax.lax.dot_general(
-                qh, kh, (((1,), (1,)), ((), ()))
-            ).astype(jnp.float32) * scale                  # [t, max_len]
+                qh, kh, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32
+            ).astype(dtype).astype(jnp.float32) * scale    # [t, max_len]
             logits = jnp.where(mask, logits, MASK_VALUE)
             w = jax.nn.softmax(logits, axis=-1).astype(dtype)
-            outs.append(jax.lax.dot_general(
-                w, vh, (((1,), (0,)), ((), ()))))          # [t, d]
-        o_ref[0] = jnp.stack(outs, axis=1)                 # [t, h, d]
+            o_ref[0, hh] = jax.lax.dot_general(
+                w, vh, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(dtype)
 
 
 def paged_flash_attention(q, k_pages, v_pages, page_table, cache_index,
@@ -495,37 +525,40 @@ def paged_flash_attention(q, k_pages, v_pages, page_table, cache_index,
         num_scalar_prefetch=2,
         grid=(b, pmax),
         in_specs=[
-            pl.BlockSpec((1, t, h, d),
+            pl.BlockSpec((1, h, t, d),
                          lambda ib, j, pt, ci: (ib, 0, 0, 0)),
             pl.BlockSpec((1, ps, h, d),
                          lambda ib, j, pt, ci: (pt[ib, j], 0, 0, 0)),
             pl.BlockSpec((1, ps, h, d),
                          lambda ib, j, pt, ci: (pt[ib, j], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, t, h, d),
+        out_specs=pl.BlockSpec((1, h, t, d),
                                lambda ib, j, pt, ci: (ib, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((max_len, h, d), k_pages.dtype),
             pltpu.VMEM((max_len, h, d), v_pages.dtype),
         ],
     )
-    return pl.pallas_call(
+    # heads lead inside the kernel so each head's [t, d] query and
+    # output tile is a leading-dim index, not a sublane gather
+    out = pl.pallas_call(
         functools.partial(
             _paged_kernel, page_size=ps, pages_per_row=pmax,
             block_t=t, num_heads=h, scale=d ** -0.5),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, t, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
         interpret=interpret,
     )(page_table.astype(jnp.int32), cache_index.astype(jnp.int32),
-      q, k_pages, v_pages)
+      q.swapaxes(1, 2), k_pages, v_pages)
+    return out.swapaxes(1, 2)
 
 
-def paged_dispatch(q_shape, pages_shape, page_table_shape) -> bool:
+def paged_dispatch(q_shape, pages_shape, page_table_shape, dtype) -> bool:
     """Trace-time predicate for the gpt paged branch: kernel on (TPU
     ablation flag, or the interpret test hook) AND the shapes fit."""
     if not (kernel_enabled() or PAGED_INTERPRET):
         return False
-    return paged_fits(q_shape, pages_shape, page_table_shape)
+    return paged_fits(q_shape, pages_shape, page_table_shape, dtype)
 
 
 # -- references + cost model -------------------------------------------------

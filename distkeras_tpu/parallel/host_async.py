@@ -207,8 +207,9 @@ class HostAsyncRunner:
                                         accum_steps=self.accum_steps,
                                         precision=precision)
         self.tx = tx
-        # worker k runs on devices[k % D]; default = single-device mode
-        self.devices = list(devices) if devices else [jax.devices()[0]]
+        # worker k runs on devices[k % D]; default = every chip this
+        # process sees (never "everything on the first chip")
+        self.devices = list(devices) if devices else jax.local_devices()
         # wire codec for the PS exchange. With a runner-created (local) PS
         # a non-raw codec wraps it in EncodedParameterServer so commits and
         # pulls see exactly the wire numerics; with an injected ps= the

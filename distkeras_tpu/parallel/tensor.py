@@ -224,7 +224,11 @@ def build_pjit_epoch_fn(model, loss, tx: optax.GradientTransformation,
             make_epoch(bucketed_fold, decorrelate_rng=True),
             mesh=mesh,
             in_specs=(P(), P(None, mesh_lib.WORKER_AXIS), P()),
-            out_specs=(P(), P()))
+            out_specs=(P(), P()),
+            # the body sums gradients itself (bucketed_psum); with the
+            # varying-axes check on, grads of the replicated params arrive
+            # already summed and would be summed twice
+            check_vma=False)
 
     data_sharding = NamedSharding(mesh, P(None, mesh_lib.WORKER_AXIS))
 

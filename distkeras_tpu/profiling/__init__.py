@@ -15,7 +15,6 @@ digest stamped onto the flight recorder.
 from distkeras_tpu.profiling.cost_model import (  # noqa: F401
     OpCost, OpInventory, op_inventory, parse_hlo_ops, source_inventory)
 from distkeras_tpu.profiling.roofline import (  # noqa: F401
-    HBM_BANDWIDTH, RooflineReport, build_report, classify,
-    device_hbm_bandwidth)
+    RooflineReport, build_report, classify)
 from distkeras_tpu.profiling.capture import (  # noqa: F401
     OpTimeTable, capture_op_times)

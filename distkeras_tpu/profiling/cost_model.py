@@ -1,6 +1,6 @@
 """Per-op cost inventory of a compiled executable.
 
-jax 0.4.x exposes two views of a compiled computation: an aggregate
+jax exposes two views of a compiled computation: an aggregate
 ``cost_analysis()`` dict (flops / bytes accessed, whole-program) and the
 post-optimization HLO text via ``as_text()``. There is no structured
 per-op cost API, so the inventory here walks the HLO text: one row per
@@ -491,8 +491,6 @@ def op_inventory(compiled,
     xla_flops = xla_bytes = None
     try:
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax returned [dict]
-            cost = cost[0] if cost else {}
         xla_flops = float(cost["flops"]) if cost.get("flops") else None
         xla_bytes = (float(cost["bytes accessed"])
                      if cost.get("bytes accessed") else None)

@@ -7,9 +7,9 @@ the op is memory-bound — more MXU throughput cannot help it; above, it is
 compute-bound — a faster or lower-precision matmul path can. Ops whose
 modeled time sits under the dispatch floor are latency-bound: neither.
 
-Peaks come from the dtype-aware ``observability.PEAK_FLOPS`` (fp8-sim
-claims the bf16 peak per the PR 6 honesty rule — it runs on the bf16 MXU);
-bandwidths from the ``HBM_BANDWIDTH`` table below. Each top-k row carries a
+Peaks and bandwidths come from the one ``observability.DEVICE_PEAKS`` table
+(fp8-sim claims the bf16 peak per the PR 6 honesty rule — it runs on the
+bf16 MXU). Each top-k row carries a
 "what would fix it" tag keyed to the ROADMAP item-1 candidates: Pallas
 attention, real fp8 matmuls, psum/overlap co-tuning.
 """
@@ -22,38 +22,12 @@ from typing import Dict, List, Optional
 from distkeras_tpu import observability, telemetry
 from distkeras_tpu.profiling.cost_model import OpCost, OpInventory
 
-# Peak HBM bandwidth per chip, bytes/s, by TPU generation (public figures:
-# v2 700 GB/s, v3 900, v4 1228, v5e 819, v5p 2765, v6e 1640). Same
-# substring-match contract as observability.PEAK_FLOPS.
-_GEN_BW = {
-    "v2": 700e9, "v3": 900e9, "v4": 1228e9,
-    "v5e": 819e9, "v5p": 2765e9, "v6e": 1640e9,
-}
-_KIND_ALIASES = {"v5 lite": "v5e", "v5litepod": "v5e", "v6 lite": "v6e"}
-
-#: device-kind substring -> HBM bytes/s
-HBM_BANDWIDTH = dict(_GEN_BW,
-                     **{alias: _GEN_BW[gen]
-                        for alias, gen in _KIND_ALIASES.items()})
-
 #: modeled times under this are dispatch overhead, not data or flops
 LATENCY_FLOOR_S = 1e-6
 
 _COLLECTIVES = frozenset({
     "all-reduce", "reduce-scatter", "all-gather", "all-to-all",
     "collective-permute"})
-
-
-def device_hbm_bandwidth(device=None) -> Optional[float]:
-    """Best-effort HBM bytes/s of one chip; None when unknown (CPU) — the
-    same decline-don't-fabricate contract as ``device_peak_flops``."""
-    import jax
-    device = device or jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    for key, bw in HBM_BANDWIDTH.items():
-        if key in kind:
-            return bw
-    return None
 
 
 def classify(flops: float, bytes_accessed: float, peak: float,
@@ -254,7 +228,7 @@ def build_report(inventory: OpInventory,
     if peak_flops is None:
         peak_flops = observability.device_peak_flops(device, dtype=dtype)
     if hbm_bandwidth is None:
-        hbm_bandwidth = device_hbm_bandwidth(device)
+        hbm_bandwidth = observability.device_hbm_bandwidth(device)
     if not peak_flops or not hbm_bandwidth:
         return RooflineReport(
             available=False, dtype=dtype, top_k=top_k,

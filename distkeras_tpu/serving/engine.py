@@ -98,7 +98,9 @@ class ServingEngine:
                  warmup: bool = True,
                  telemetry_path: Optional[str] = None):
         from distkeras_tpu.predictors import make_forward_fn
+        from distkeras_tpu.utils.jax_compat import enable_compilation_cache
 
+        enable_compilation_cache()  # warm-up below compiles every bucket
         self.model = model
         self.input_shape = tuple(int(d) for d in input_shape)
         self.input_dtype = np.dtype(input_dtype)

@@ -507,6 +507,9 @@ class GenerationEngine:
                  seed: int = 0):
         import jax
 
+        from distkeras_tpu.utils.jax_compat import enable_compilation_cache
+
+        enable_compilation_cache()  # _compile_all() compiles every bucket
         self.model = model
         self.max_len = int(model.max_len)
         self._buckets = BucketSpec(prefill_buckets)

@@ -155,9 +155,9 @@ class Trainer:
 
     # -- bookkeeping (record_training_time parity) -------------------------
     def _start(self):
-        # opt-in persistent XLA compilation cache: no-op unless the user
-        # called distkeras_tpu.enable_compilation_cache(...) or exported
-        # DISTKERAS_TPU_COMPILE_CACHE (see utils/jax_compat.py)
+        # persistent XLA compilation cache, before the first compile
+        # (utils/jax_compat.py: $JAX_COMPILATION_CACHE_DIR, else the
+        # checkout's own .xla_cache/)
         jax_compat.enable_compilation_cache()
         # flight-recorder wiring: the telemetry plane can't import jax, so
         # the trainer pushes the process index down (multi-host artifact
@@ -1080,7 +1080,7 @@ class DistributedTrainer(Trainer):
                 self._async_runner = host_async.HostAsyncRunner(
                     self.model, self.loss, self.tx, self.strategy,
                     self.communication_window, self.metrics, self.seed,
-                    devices=self.devices or jax.local_devices(),
+                    devices=self.devices,
                     codec=self.codec, overlap=self.comms_overlap,
                     accum_steps=self.accum_steps,
                     precision=self.precision)

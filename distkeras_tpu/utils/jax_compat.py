@@ -1,68 +1,42 @@
-"""Shims over jax API drift so the framework runs on a range of releases.
+"""The two places the framework touches JAX's own configuration surface:
+the ``shard_map`` entry point and the persistent compilation cache.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` to the
-top-level ``jax.shard_map``, renaming ``check_rep`` to ``check_vma``
-along the way. The framework writes the modern spelling everywhere;
-this module backfills it on releases that only ship the experimental
-entry point.
+Written for the one installed JAX (``jax.shard_map`` with ``check_vma``);
+there is no branch for any other release.
 """
 
 from __future__ import annotations
 
+import os
+
 import jax
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
+shard_map = jax.shard_map
 
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True, **kwargs):
-        return _experimental_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma, **kwargs)
-
-
-_CACHE_ENV_VAR = "DISTKERAS_TPU_COMPILE_CACHE"
-_cache_dir: str | None = None
+#: where compiled executables persist when the environment names no cache:
+#: one fixed, git-ignored directory at the root of the checkout, resolved
+#: from this file's own path. The directory is part of every cache key,
+#: so it must never depend on a temporary name, a pid or the time.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".xla_cache")
 
 
-def enable_compilation_cache(cache_dir: str | None = None) -> str | None:
-    """Opt into jax's persistent compilation cache.
+def enable_compilation_cache() -> str:
+    """Make sure JAX's persistent compilation cache has a directory, and
+    return it.
 
-    Big-model XLA compiles run minutes; the remat x accumulation sweep in
-    benchmarks/step_probe.py recompiles the same step for every config. A
-    persistent on-disk cache turns every repeat compile (re-runs, warm
-    restarts, the other configs of a sweep that share an executable) into a
-    disk read.
-
-    ``cache_dir`` defaults to ``$DISTKERAS_TPU_COMPILE_CACHE``; with neither
-    set this is a no-op returning None (the cache stays opt-in — a surprise
-    cache directory in CI or a read-only container would be worse than slow
-    compiles). Safe to call repeatedly and on jax releases without the
-    config knob (guarded no-op). Returns the active cache dir or None.
+    One rule. If ``JAX_COMPILATION_CACHE_DIR`` is exported, JAX has
+    already read it into its config — nothing is set here. Otherwise the
+    cache goes to :data:`DEFAULT_CACHE_DIR`. JAX's own thresholds decide
+    what is worth an entry. ``Trainer._start`` and both serving engines'
+    constructors call this before their first compile; it is idempotent.
     """
-    global _cache_dir
-    import os
-
-    if cache_dir is None:
-        cache_dir = os.environ.get(_CACHE_ENV_VAR) or None
-    if cache_dir is None:
-        return _cache_dir
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-        # cache everything, including sub-second CPU test compiles — the
-        # default min-entry-size/min-compile-time heuristics are tuned for
-        # TPU pods and would skip exactly the compiles local runs repeat
-        for knob, val in (("jax_persistent_cache_min_entry_size_bytes", -1),
-                          ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-            try:
-                jax.config.update(knob, val)
-            except (AttributeError, ValueError):
-                pass  # knob not in this release; dir alone still caches
-    except (AttributeError, ValueError):
-        return None  # release without the cache config: guarded no-op
-    _cache_dir = str(cache_dir)
-    return _cache_dir
+    configured = jax.config.jax_compilation_cache_dir
+    if configured:  # from the environment, or from an earlier call
+        return configured
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    return DEFAULT_CACHE_DIR
 
 
-__all__ = ["shard_map", "enable_compilation_cache"]
+__all__ = ["shard_map", "enable_compilation_cache", "DEFAULT_CACHE_DIR"]

@@ -20,7 +20,7 @@ PKG_MODULES = sorted(
 
 def test_discovery_found_the_tools():
     # the floor protects against the glob silently matching nothing
-    assert len(SCRIPTS) >= 22, SCRIPTS
+    assert len(SCRIPTS) >= 21, SCRIPTS
     assert "distkeras_tpu.benchmarks.run_config" in PKG_MODULES
     # the serving load generator (ISSUE 2) must be under the smoke glob
     assert any(os.path.basename(p) == "serving_load.py" for p in SCRIPTS)

@@ -1,53 +1,69 @@
-"""Compat-shim tests: the persistent compilation cache opt-in (DESIGN.md §10).
+"""The compile-cache rule (utils/jax_compat.enable_compilation_cache).
 
-The cache is process-global jax config, so every test restores the prior
-state — leaking a cache dir into the rest of the suite would silently
-change what tier-1 measures.
+If ``JAX_COMPILATION_CACHE_DIR`` is exported JAX already holds it and the
+function sets nothing; otherwise the cache goes to one fixed directory
+inside the checkout. The cache directory is process-global jax config, so
+every in-process test restores it — and conftest.py keeps the cache itself
+switched off, so nothing here (or anywhere in tier-1) writes an entry.
 """
 
 import os
+import subprocess
+import sys
 
 import jax
-import jax.numpy as jnp
 import pytest
 
 from distkeras_tpu.utils import jax_compat
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 @pytest.fixture
-def clean_cache_state(monkeypatch, tmp_path):
-    """Fresh module state + env, and jax config restored afterwards."""
-    monkeypatch.delenv(jax_compat._CACHE_ENV_VAR, raising=False)
-    monkeypatch.setattr(jax_compat, "_cache_dir", None)
-    yield tmp_path
-    try:
-        jax.config.update("jax_compilation_cache_dir", None)
-    except (AttributeError, ValueError):
-        pass
+def restore_cache_dir():
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
 
 
-def test_cache_is_noop_without_optin(clean_cache_state):
-    """No arg, no env var -> None, and jax config untouched."""
-    assert jax_compat.enable_compilation_cache() is None
-    assert jax.config.jax_compilation_cache_dir in (None, "")
+def test_env_var_set_means_the_function_sets_nothing(restore_cache_dir,
+                                                     tmp_path):
+    """JAX reads the variable into its config at import; stand in for that
+    here, then check the function returns it and leaves config alone."""
+    exported = str(tmp_path / "exported")
+    jax.config.update("jax_compilation_cache_dir", exported)
+    assert jax_compat.enable_compilation_cache() == exported
+    assert jax.config.jax_compilation_cache_dir == exported
+    assert not os.path.exists(exported)  # and nothing was created
 
 
-def test_cache_explicit_dir_writes_entries(clean_cache_state):
-    cache_dir = str(clean_cache_state / "xla")
-    assert jax_compat.enable_compilation_cache(cache_dir) == cache_dir
-    # a fresh compile (unique constant -> unique cache key) must land on disk
-    x = jnp.ones((8, 8)) * 1.2345678
-    jax.jit(lambda a: (a @ a) + 0.987654)(x).block_until_ready()
-    entries = [f for root, _, files in os.walk(cache_dir) for f in files]
-    assert entries, "compilation cache dir stayed empty after a jit compile"
+def test_unset_means_the_fixed_in_checkout_directory(restore_cache_dir):
+    jax.config.update("jax_compilation_cache_dir", None)
+    want = os.path.join(REPO, ".xla_cache")
+    assert jax_compat.DEFAULT_CACHE_DIR == want
+    assert jax_compat.enable_compilation_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert jax_compat.enable_compilation_cache() == want  # idempotent
 
 
-def test_cache_env_var_fallback(clean_cache_state, monkeypatch):
-    cache_dir = str(clean_cache_state / "from_env")
-    monkeypatch.setenv(jax_compat._CACHE_ENV_VAR, cache_dir)
-    assert jax_compat.enable_compilation_cache() == cache_dir
-    # repeat calls without an arg report the active dir, not None
-    assert jax_compat.enable_compilation_cache() == cache_dir
+def test_two_processes_agree_and_the_environment_wins(tmp_path):
+    """The directory is part of every cache key: two processes must
+    resolve the same one (no pid, time or temp name in it), and a real
+    exported JAX_COMPILATION_CACHE_DIR must be the one in use."""
+    code = ("from distkeras_tpu.utils import jax_compat; "
+            "print(jax_compat.enable_compilation_cache())")
+    base = {k: v for k, v in os.environ.items()
+            if k != "JAX_COMPILATION_CACHE_DIR"}
+    exported = str(tmp_path / "from_env")
+    procs = [subprocess.Popen([sys.executable, "-c", code], cwd=REPO,
+                              env=env, stdout=subprocess.PIPE, text=True)
+             for env in (base, base,
+                         dict(base, JAX_COMPILATION_CACHE_DIR=exported))]
+    outs = [p.communicate(timeout=120)[0].strip().splitlines()[-1]
+            for p in procs]
+    assert all(p.returncode == 0 for p in procs)
+    assert outs[0] == outs[1] == os.path.join(REPO, ".xla_cache")
+    assert outs[2] == exported
 
 
 def test_cache_exported_at_package_top_level():

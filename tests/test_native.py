@@ -11,6 +11,28 @@ def test_native_available_with_toolchain():
     assert native.available()
 
 
+def test_library_is_named_by_the_hash_of_its_source(tmp_path, monkeypatch):
+    """The binary that loads is the one built from THIS source: its name
+    carries the source's SHA-256, so editing the source (or finding a
+    stale/foreign libdkbatch.so beside it) builds anew instead of
+    loading the wrong code."""
+    import hashlib
+    import os
+    import shutil
+
+    src = tmp_path / "batcher.cc"
+    shutil.copy(native._SRC, src)
+    (tmp_path / "libdkbatch.so").write_bytes(b"not a library")  # foreign
+    monkeypatch.setattr(native, "_SRC", str(src))
+    first = native._build()
+    digest = hashlib.sha256(src.read_bytes()).hexdigest()[:16]
+    assert os.path.basename(first) == f"libdkbatch-{digest}.so"
+    src.write_text(src.read_text() + "\n// edited\n")
+    second = native._build()
+    assert second != first and os.path.exists(second)
+    assert not list(tmp_path.glob("*.tmp"))
+
+
 def test_gather_rows_matches_numpy():
     rng = np.random.default_rng(0)
     for shape, dtype in [((1000, 784), np.float32), ((257, 3, 5), np.int32),

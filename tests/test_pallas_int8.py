@@ -4,15 +4,12 @@ The kernel is DEFAULT OFF (the groupnorm lesson: a custom call is a
 fusion fence). Tier-1 pins three things on CPU: the default stays off,
 the dispatch predicate is honest, and interpret-mode execution is
 bit-exact against the pure-XLA fallback (same int32 accumulate, same
-final f32 scale multiply). The TPU compile+parity test rides the
-``pallas`` marker — run it on a real TPU host alongside
-benchmarks/int8_matmul_ablate.py before ever flipping the default.
+final f32 scale multiply). The compiled (non-interpret) kernel is checked
+against the same fallback on the chip by ``chip_smoke.py``'s kernels leg.
 """
 
-import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from distkeras_tpu.ops.pallas import int8_matmul as k
 
@@ -59,15 +56,3 @@ def test_precision_path_uses_xla_fallback_while_off():
     ref = k.xla_int8_matmul_dequant(qx, qw, sx * sw).astype(x.dtype)
     np.testing.assert_array_equal(np.asarray(scaled_int8_matmul(x, w)),
                                   np.asarray(ref))
-
-
-@pytest.mark.pallas
-@pytest.mark.skipif(jax.devices()[0].platform != "tpu",
-                    reason="compiles the Mosaic kernel for a real TPU")
-def test_tpu_kernel_matches_xla_fallback():
-    for qx, qw, sxw in k.reference_rows(sizes=((512, 512, 512),)):
-        ref = np.asarray(k.xla_int8_matmul_dequant(
-            jnp.asarray(qx), jnp.asarray(qw), sxw))
-        out = np.asarray(k.int8_matmul_dequant(
-            jnp.asarray(qx), jnp.asarray(qw), sxw))
-        np.testing.assert_allclose(ref, out, rtol=1e-6)

@@ -70,20 +70,19 @@ def test_ring_grads_finite(seq_mesh):
         assert np.all(np.isfinite(np.asarray(g)))
 
 
-def test_flash_attention_option_cpu_fallback():
-    """attention="flash" plumbs through the GPT family; off-TPU it falls
-    back to the XLA path, so outputs match attention="full" exactly."""
+def test_flash_attention_option_raises_off_tpu():
+    """attention="flash" plumbs through the GPT family to the kernel
+    dispatch, which refuses to run a TPU kernel's name on the CPU."""
     import jax
     import jax.numpy as jnp
     import numpy as np
+    import pytest
 
     from distkeras_tpu.models.gpt import gpt_tiny
 
     ids = jnp.asarray(np.random.default_rng(0).integers(0, 256, (2, 16)),
                       jnp.int32)
-    full = gpt_tiny(attention="full")
-    flash = gpt_tiny(attention="flash")
-    params = full.init(jax.random.key(0), ids)["params"]
-    y_full = full.apply({"params": params}, ids)
-    y_flash = flash.apply({"params": params}, ids)
-    np.testing.assert_array_equal(np.asarray(y_full), np.asarray(y_flash))
+    params = gpt_tiny(attention="full").init(jax.random.key(0),
+                                             ids)["params"]
+    with pytest.raises(RuntimeError, match="'cpu'"):
+        gpt_tiny(attention="flash").apply({"params": params}, ids)
