@@ -384,6 +384,12 @@ def profiler_trace(logdir: str):
         jax.profiler.stop_trace()
 
 
+# The program's spans and scheduler phases on the profiler's clock:
+# telemetry.span / PhaseTimer enter this around their blocks. Inert unless
+# a profiler session is running (profiler_trace above, or any other).
+telemetry.set_annotator(jax.profiler.TraceAnnotation)
+
+
 def time_threaded_steps(step_fn: Callable, state, batch, warmup: int = 2,
                         steps: int = 10) -> tuple:
     """Time a state-threading train step (``state, aux = step(state, batch)``).

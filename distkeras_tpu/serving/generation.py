@@ -621,6 +621,13 @@ class GenerationEngine:
         self._tps_g = telemetry.gauge("serving.decode.tokens_per_s")
         self._active_g = telemetry.gauge("serving.decode.slots_active")
         self._depth_g = telemetry.gauge("serving.decode.queue_depth")
+        # the scheduler iteration partitioned on the host clock; each
+        # phase is also a profiler annotation (module docstring of
+        # telemetry.PhaseTimer, DESIGN.md §5b)
+        self._sched = telemetry.PhaseTimer(
+            "serving.sched.",
+            ("control", "admit", "prefill_wait", "launch", "wait", "copy",
+             "pick", "stream", "retire"), whole="iter")
         self._spec_proposed_c = telemetry.counter(
             "serving.decode.spec.proposed")
         self._spec_accepted_c = telemetry.counter(
@@ -1101,6 +1108,7 @@ class GenerationEngine:
     def _scheduler_loop(self) -> None:
         active = {}      # slot -> _GenRequest (decoding)
         prefilling = {}  # slot -> _GenRequest (chunked prefill cursor)
+        sched = self._sched
         try:
             while True:
                 with self._cv:
@@ -1120,14 +1128,24 @@ class GenerationEngine:
                             "engine is shut down; no weight swaps"))
                         self._fail_host_ops()
                         return
-                self._apply_pending_swap()
-                self._apply_host_ops()
-                self._admit(active, prefilling)
-                self._expire(active, prefilling)
+                    # an iteration with a lane to serve; a wake-up for a
+                    # weight swap or a host op alone is not one
+                    busy = bool(self._dq or active or prefilling)
+                sched.start()
+                with sched.phase("control"):
+                    self._apply_pending_swap()
+                    self._apply_host_ops()
+                with sched.phase("admit"):
+                    self._admit(active, prefilling)
+                with sched.phase("control"):
+                    self._expire(active, prefilling)
                 if prefilling:
-                    self._chunk_step(active, prefilling)
+                    with sched.phase("admit"):
+                        self._chunk_step(active, prefilling)
                 if active:
                     self._decode_step(active)
+                if busy:
+                    sched.commit()
         except BaseException as e:  # scheduler must never die silently
             self._loop_err_c.inc()
             telemetry.record_event("serving", outcome="loop_error",
@@ -1223,15 +1241,19 @@ class GenerationEngine:
         ids[0, :n] = req.prompt
         t0 = time.monotonic()
         tp0 = time.perf_counter()
-        new_pool, logits = self._prefill_exec[lb](
-            self._params, self.pool.pool, ids, np.int32(slot), np.int32(n))
-        # pin the version this sequence started on: every later decode
-        # step for this slot runs on the SAME params even if a swap lands
-        # mid-generation (in-flight requests provably finish on it)
-        self._slot_version[slot] = self.model_version
-        self.pool.swap(new_pool)
-        self.pool.lengths[slot] = n
-        tok = self._pick_token(req, np.asarray(logits))
+        with self._sched.phase("prefill_wait"):
+            new_pool, logits = self._prefill_exec[lb](
+                self._params, self.pool.pool, ids, np.int32(slot),
+                np.int32(n))
+            # pin the version this sequence started on: every later decode
+            # step for this slot runs on the SAME params even if a swap
+            # lands mid-generation (in-flight requests provably finish on
+            # it)
+            self._slot_version[slot] = self.model_version
+            self.pool.swap(new_pool)
+            self.pool.lengths[slot] = n
+            logits = np.asarray(logits)
+        tok = self._pick_token(req, logits)
         now = time.monotonic()
         self._prefills_c.inc()
         self._prefill_h.record(now - t0)
@@ -1325,11 +1347,12 @@ class GenerationEngine:
             ids = np.zeros((1, lb), np.int32)
             ids[0, :suffix.size] = suffix
             pts = self.pool.page_table_row(slot)[None, :]
-            new_pool, logits = self._prefill_exec[lb](
-                self._params, self.pool.pool, pts, ids,
-                np.full(1, start, np.int32))
-            self.pool.swap(new_pool)
-            logits_row = np.asarray(logits)[0, n - start - 1]
+            with self._sched.phase("prefill_wait"):
+                new_pool, logits = self._prefill_exec[lb](
+                    self._params, self.pool.pool, pts, ids,
+                    np.full(1, start, np.int32))
+                self.pool.swap(new_pool)
+                logits_row = np.asarray(logits)[0, n - start - 1]
         self._finish_prefill(req, slot, logits_row, ran_prefill, t0, tp0,
                              hit)
 
@@ -1385,15 +1408,17 @@ class GenerationEngine:
             params = self._versions.get(
                 self._slot_version.get(slot, self.model_version),
                 self._params)
-            new_pool, logits = self._chunk_exec(
-                params, self.pool.pool, pts, ids,
-                np.full(1, pos, np.int32))
-            self.pool.swap(new_pool)
+            with self._sched.phase("prefill_wait"):
+                new_pool, logits = self._chunk_exec(
+                    params, self.pool.pool, pts, ids,
+                    np.full(1, pos, np.int32))
+                self.pool.swap(new_pool)
             self._chunk_steps_c.inc()
             req.prefill_pos = pos + chunk.size
             self.pool.lengths[slot] = req.prefill_pos
             if req.prefill_pos >= n:
-                logits_row = np.asarray(logits)[0, n - pos - 1]
+                with self._sched.phase("prefill_wait"):
+                    logits_row = np.asarray(logits)[0, n - pos - 1]
                 del prefilling[slot]
                 self._finish_prefill(req, slot, logits_row,
                                      ran_prefill=True, t0=t0, tp0=tp0,
@@ -1490,7 +1515,8 @@ class GenerationEngine:
                 self._spec_group(active, slots, version)
             else:
                 self._decode_group(active, slots, version)
-        self._reclaim_versions()
+        with self._sched.phase("control"):
+            self._reclaim_versions()
         self._active_g.set(len(active))
 
     def _group_arrays(self, active, slots, lane: int, t: int):
@@ -1514,51 +1540,63 @@ class GenerationEngine:
         params = self._versions.get(version, self._params)
         n = len(slots)
         lane = self._ladder.bucket_for(n)
-        slot_ids, tokens, lengths = self._group_arrays(active, slots,
-                                                       lane, 2)
-        t0 = time.monotonic()
-        tp0 = time.perf_counter()
+        sched = self._sched
+        with sched.phase("launch"):
+            slot_ids, tokens, lengths = self._group_arrays(active, slots,
+                                                           lane, 2)
+            tp0 = time.perf_counter()
+            if self._paged:
+                new_pool, logits = self._decode_exec[lane](
+                    params, self.pool.pool, self._page_tables_for(slot_ids),
+                    tokens, lengths)
+            else:
+                new_pool, logits = self._decode_exec[lane](
+                    params, self.pool.pool, slot_ids, tokens[:, 0], lengths)
+        with sched.phase("wait"):
+            logits.block_until_ready()  # the step lands
+        with sched.phase("copy"):
+            logits = np.asarray(logits)  # device to host, nothing else
         if self._paged:
-            new_pool, logits = self._decode_exec[lane](
-                params, self.pool.pool, self._page_tables_for(slot_ids),
-                tokens, lengths)
-            logits = np.asarray(logits)[:, 0, :]
-        else:
-            new_pool, logits = self._decode_exec[lane](
-                params, self.pool.pool, slot_ids, tokens[:, 0], lengths)
-            logits = np.asarray(logits)  # blocks until the step lands
+            logits = logits[:, 0, :]
         self.pool.swap(new_pool)
-        dt = time.monotonic() - t0
-        dt_p = time.perf_counter() - tp0
+        dt = time.perf_counter() - tp0
         self._steps_c.inc()
         self._tokens_c.inc(n)
         self._step_h.record(dt)
         self._padded_h.record(lane - n)
         if dt > 0:
             self._tps_g.set(n / dt)
-        for i, s in enumerate(slots):
-            req = active[s]
-            self.pool.lengths[s] += 1  # the fed token is now cached
-            tok = self._pick_token(req, logits[i])
-            req.generated.append(tok)
-            req.last_token = tok
-            if self._prefix is not None:
-                req.last_logits = logits[i].copy()
-            if self._draft is not None:
-                self._draft.observe(s, (tok,))
-            if req.trace is not None:
-                # one decode iteration serves every lane at once, so each
-                # traced request gets a child span with the SHARED step
-                # interval — per-lane attribution of a batched step would
-                # be an invention, not a measurement
-                telemetry.record_trace_span(
-                    req.trace, "trace.decode", tp0, dt_p,
-                    step=len(req.generated), lanes=lane,
-                    model_version=version)
-            self._stream_token(req, tok)
-            reason = self._emit(req, s)
-            if reason is not None:
-                del active[s]
+        # the lane loop keeps its order lane by lane (what a client sees):
+        # one annotation around it, its three phases summed lap by lap
+        with telemetry.annotation("serving.sched.emit"):
+            sched.lap()
+            for i, s in enumerate(slots):
+                req = active[s]
+                self.pool.lengths[s] += 1  # the fed token is now cached
+                tok = self._pick_token(req, logits[i])
+                sched.lap("pick")
+                req.generated.append(tok)
+                req.last_token = tok
+                if self._prefix is not None:
+                    req.last_logits = logits[i].copy()
+                if self._draft is not None:
+                    self._draft.observe(s, (tok,))
+                if req.trace is not None:
+                    # one decode iteration serves every lane at once, so
+                    # each traced request gets a child span with the SHARED
+                    # step interval — per-lane attribution of a batched
+                    # step would be an invention, not a measurement
+                    telemetry.record_trace_span(
+                        req.trace, "trace.decode", tp0, dt,
+                        step=len(req.generated), lanes=lane,
+                        model_version=version)
+                sched.lap("retire")
+                self._stream_token(req, tok)
+                sched.lap("stream")
+                reason = self._emit(req, s)
+                if reason is not None:
+                    del active[s]
+                sched.lap("retire")
 
     def _sampled_accept_walk(self, req: _GenRequest, props_i, logits_i):
         """Host side of sampling-capable speculative verification
@@ -1606,69 +1644,79 @@ class GenerationEngine:
         n = len(slots)
         s = self._spec_k
         lane = self._ladder.bucket_for(n)
-        slot_ids, tokens, lengths = self._group_arrays(active, slots,
-                                                       lane, s + 1)
-        props = self._draft.propose(
-            slots, tokens[:n, 0], lengths[:n], s)
-        tokens[:n, 1:] = props
-        t0 = time.monotonic()
-        tp0 = time.perf_counter()
-        if self._paged:
-            new_pool, logits = self._verify_exec[lane](
-                params, self.pool.pool, self._page_tables_for(slot_ids),
-                tokens, lengths)
-        else:
-            new_pool, logits = self._verify_exec[lane](
-                params, self.pool.pool, slot_ids, tokens, lengths)
-        self.pool.swap(new_pool)
-        logits = np.asarray(logits)  # [lane, s+1, V]
-        greedy = np.argmax(logits, axis=-1)  # [lane, s+1]
-        dt = time.monotonic() - t0
-        dt_p = time.perf_counter() - tp0
+        sched = self._sched
+        with sched.phase("launch"):
+            slot_ids, tokens, lengths = self._group_arrays(active, slots,
+                                                           lane, s + 1)
+            props = self._draft.propose(
+                slots, tokens[:n, 0], lengths[:n], s)
+            tokens[:n, 1:] = props
+            tp0 = time.perf_counter()
+            if self._paged:
+                new_pool, logits = self._verify_exec[lane](
+                    params, self.pool.pool, self._page_tables_for(slot_ids),
+                    tokens, lengths)
+            else:
+                new_pool, logits = self._verify_exec[lane](
+                    params, self.pool.pool, slot_ids, tokens, lengths)
+            self.pool.swap(new_pool)
+        with sched.phase("wait"):
+            logits.block_until_ready()
+        with sched.phase("copy"):
+            logits = np.asarray(logits)  # [lane, s+1, V]
+        with sched.phase("pick"):
+            greedy = np.argmax(logits, axis=-1)  # [lane, s+1]
+        dt = time.perf_counter() - tp0
         self._steps_c.inc()
         self._step_h.record(dt)
         self._padded_h.record(lane - n)
         self._spec_iters_c.inc()
         emitted_total = 0
-        for i, slot in enumerate(slots):
-            req = active[slot]
-            if self._sampling:
-                emit, resampled = self._sampled_accept_walk(
-                    req, props[i], logits[i])
-            else:
-                m = 0
-                while m < s and props[i, m] == greedy[i, m]:
-                    m += 1
-                emit = [int(t) for t in greedy[i, :m + 1]]
-                # caps: never emit past max_new_tokens, truncate at EOS
-                emit = emit[:req.max_new_tokens - len(req.generated)]
-                if req.eos_id is not None and req.eos_id in emit:
-                    emit = emit[:emit.index(req.eos_id) + 1]
-                resampled = False
-            p = len(emit)
-            self._spec_proposed_c.inc(s)
-            self._spec_accepted_c.inc(p - 1)
-            if self._sampling:
-                self._spec_s_accepts_c.inc(p - 1)
-                if resampled:
-                    self._spec_s_resamples_c.inc()
-            self.pool.lengths[slot] += p  # cells L..L+p-1 are now true
-            for tok in emit:
-                req.generated.append(tok)
-                req.last_token = tok
-                self._stream_token(req, tok)
-            if self._prefix is not None:
-                req.last_logits = logits[i, p - 1].copy()
-            self._draft.observe(slot, emit)
-            emitted_total += p
-            if req.trace is not None:
-                telemetry.record_trace_span(
-                    req.trace, "trace.decode", tp0, dt_p,
-                    step=len(req.generated), lanes=lane, spec=p,
-                    model_version=version)
-            reason = self._emit(req, slot)
-            if reason is not None:
-                del active[slot]
+        with telemetry.annotation("serving.sched.emit"):
+            sched.lap()
+            for i, slot in enumerate(slots):
+                req = active[slot]
+                if self._sampling:
+                    emit, resampled = self._sampled_accept_walk(
+                        req, props[i], logits[i])
+                else:
+                    m = 0
+                    while m < s and props[i, m] == greedy[i, m]:
+                        m += 1
+                    emit = [int(t) for t in greedy[i, :m + 1]]
+                    # caps: never emit past max_new_tokens, truncate at EOS
+                    emit = emit[:req.max_new_tokens - len(req.generated)]
+                    if req.eos_id is not None and req.eos_id in emit:
+                        emit = emit[:emit.index(req.eos_id) + 1]
+                    resampled = False
+                p = len(emit)
+                sched.lap("pick")
+                self._spec_proposed_c.inc(s)
+                self._spec_accepted_c.inc(p - 1)
+                if self._sampling:
+                    self._spec_s_accepts_c.inc(p - 1)
+                    if resampled:
+                        self._spec_s_resamples_c.inc()
+                self.pool.lengths[slot] += p  # cells L..L+p-1 are now true
+                sched.lap("retire")
+                for tok in emit:
+                    req.generated.append(tok)
+                    req.last_token = tok
+                    self._stream_token(req, tok)
+                sched.lap("stream")
+                if self._prefix is not None:
+                    req.last_logits = logits[i, p - 1].copy()
+                self._draft.observe(slot, emit)
+                emitted_total += p
+                if req.trace is not None:
+                    telemetry.record_trace_span(
+                        req.trace, "trace.decode", tp0, dt,
+                        step=len(req.generated), lanes=lane, spec=p,
+                        model_version=version)
+                reason = self._emit(req, slot)
+                if reason is not None:
+                    del active[slot]
+                sched.lap("retire")
         self._tokens_c.inc(emitted_total)
         if dt > 0:
             self._tps_g.set(emitted_total / dt)

@@ -60,6 +60,7 @@ __all__ = [
     "TraceContext", "current_trace", "use_trace", "inject", "extract",
     "record_trace_span", "flush_at_exit",
     "set_recorder", "get_recorder", "record_event",
+    "set_annotator", "get_annotator", "annotation", "PhaseTimer",
     "set_process_index", "process_index", "per_process_path",
 ]
 
@@ -258,6 +259,23 @@ METRIC_NAMES = {
     "serving.decode.chunk.admitted": "counter",
     "serving.decode.chunk.queue_depth": "gauge",
     "serving.decode.chunk.steps": "counter",
+    # generation scheduler iteration, partitioned on the host clock
+    # (serving/generation.py via PhaseTimer, DESIGN.md §5b): one sample
+    # per iteration that held a lane; every phase but iter_s is also a
+    # profiler annotation of the same name without its "_s"
+    "serving.sched.admit_s": "histogram",
+    "serving.sched.control_s": "histogram",
+    "serving.sched.copy_s": "histogram",
+    "serving.sched.iter_s": "histogram",
+    "serving.sched.launch_s": "histogram",
+    "serving.sched.pick_s": "histogram",
+    "serving.sched.prefill_wait_s": "histogram",
+    "serving.sched.retire_s": "histogram",
+    "serving.sched.stream_s": "histogram",
+    "serving.sched.wait_s": "histogram",
+    # profiler annotation only (no instrument): the lane loop that
+    # pick_s + stream_s + retire_s split on the host clock
+    "serving.sched.emit": "annotation",
     # live rollout / canary / rollback plane (serving/rollout.py,
     # DESIGN.md §18)
     "rollout.canary.agreement": "gauge",
@@ -386,8 +404,9 @@ METRIC_PREFIXES = {
 
 def declared_kind(name: str):
     """The registered kind for ``name`` ("counter" | "gauge" |
-    "histogram" | "span"), or None when the name is undeclared (ad-hoc
-    names are allowed; they are simply outside the registry's contract)."""
+    "histogram" | "span" | "annotation"), or None when the name is
+    undeclared (ad-hoc names are allowed; they are simply outside the
+    registry's contract)."""
     k = METRIC_NAMES.get(name)
     if k is not None:
         return k
@@ -930,29 +949,36 @@ def span(name: str, **labels):
     child of that context, a fresh child context is made current for the
     duration of the block, and that context is yielded (None when
     untraced) — so nested spans chain parent -> child and the context can
-    be injected into outbound wire headers."""
+    be injected into outbound wire headers.
+
+    The block also runs inside :func:`annotation` ``(name)``: while a
+    profiler session is running, the span is an event of the profiler's
+    own trace, on the device trace's clock."""
     reg = _installed
     if reg is None:
         yield None
         return
-    parent = current_trace()
-    if parent is None:
+    # the annotation opens before t0 and closes after the record, so what
+    # record_span stores is the interval it always was
+    with annotation(name):
+        parent = current_trace()
+        if parent is None:
+            t0 = time.perf_counter()
+            try:
+                yield None
+            finally:
+                reg.record_span(name, t0, time.perf_counter() - t0, labels)
+            return
+        ctx = parent.child()
+        labels = dict(labels, trace_id=ctx.trace_id, span_id=ctx.span_id,
+                      parent_id=parent.span_id)
+        _trace_local.ctx = ctx
         t0 = time.perf_counter()
         try:
-            yield None
+            yield ctx
         finally:
+            _trace_local.ctx = parent
             reg.record_span(name, t0, time.perf_counter() - t0, labels)
-        return
-    ctx = parent.child()
-    labels = dict(labels, trace_id=ctx.trace_id, span_id=ctx.span_id,
-                  parent_id=parent.span_id)
-    _trace_local.ctx = ctx
-    t0 = time.perf_counter()
-    try:
-        yield ctx
-    finally:
-        _trace_local.ctx = parent
-        reg.record_span(name, t0, time.perf_counter() - t0, labels)
 
 
 def record_trace_span(ctx: Optional["TraceContext"], name: str, t0: float,
@@ -971,6 +997,115 @@ def record_trace_span(ctx: Optional["TraceContext"], name: str, t0: float,
         labels = dict(labels, trace_id=child.trace_id,
                       span_id=child.span_id, parent_id=ctx.span_id)
     reg.record_span(name, t0, dur_s, labels)
+
+
+# -- profiler bridge (observability.py plugs the profiler in here) ----------
+#
+# One slot in the style of ``set_recorder``: a callable that, given a name,
+# returns a context manager. ``observability.py`` (which has the device
+# runtime anyway) installs the profiler's ``TraceAnnotation`` at import, so
+# the dependency points observability -> telemetry and this module stays
+# device-runtime-free. An annotation is inert unless a profiler session is
+# running: the session is the switch, there is no other.
+
+_annotator: Optional[Any] = None
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def set_annotator(fn: Optional[Any]) -> Optional[Any]:
+    """Install (or clear, with None) the callable ``name -> context
+    manager`` that puts program spans into the profiler's trace."""
+    global _annotator
+    _annotator = fn
+    return fn
+
+
+def get_annotator() -> Optional[Any]:
+    return _annotator
+
+
+def annotation(name: str):
+    """A context manager that marks its block ``name`` in the profiler's
+    trace; a shared no-op when the slot is empty."""
+    ann = _annotator
+    return _NO_ANNOTATION if ann is None else ann(name)
+
+
+class _Phase:
+    """One phase of a :class:`PhaseTimer`, reused every iteration."""
+
+    __slots__ = ("_timer", "_name", "_label", "_outer", "_ann")
+
+    def __init__(self, timer: "PhaseTimer", name: str, label: str):
+        self._timer, self._name, self._label = timer, name, label
+        self._outer = self._ann = None
+
+    def __enter__(self) -> None:
+        tm = self._timer
+        self._ann = annotation(self._label)
+        self._ann.__enter__()
+        now = time.perf_counter()
+        self._outer = tm._open
+        if self._outer is not None:  # pause the phase this one opens in
+            tm._sums[self._outer] += now - tm._t
+        tm._open, tm._t = self._name, now
+
+    def __exit__(self, *exc) -> bool:
+        tm = self._timer
+        now = time.perf_counter()
+        tm._sums[self._name] += now - tm._t
+        tm._open, tm._t = self._outer, now
+        self._ann.__exit__(*exc)
+        return False
+
+
+class PhaseTimer:
+    """Partition of a loop iteration into named phases on the host clock
+    (``time.perf_counter``, the registry's span time base): one histogram
+    ``<prefix><phase>_s`` per phase and ``<prefix><whole>_s`` for the whole
+    iteration, all bound once here.
+
+    ``start()`` opens an iteration (sums cleared, clock read). ``with
+    timer.phase(p):`` adds the block's seconds to the phase's sum and runs
+    it inside :func:`annotation` ``(<prefix><p>)``; a phase opened inside
+    another pauses the outer one, so the sums are self times and no second
+    counts twice. ``lap`` splits a loop whose phases interleave item by
+    item, one clock read a boundary. ``commit()`` records every sum, zeros
+    included, so each histogram holds one sample per committed iteration;
+    an iteration that is not committed leaves no trace. It writes to
+    neither the span ring nor the flight recorder (a dozen rows an
+    iteration would push every other reader's rows out of both), and
+    belongs to the one thread that runs the loop."""
+
+    def __init__(self, prefix: str, phases, whole: str):
+        self._whole = histogram(f"{prefix}{whole}_s")
+        self._hists = {p: histogram(f"{prefix}{p}_s") for p in phases}
+        self._phases = {p: _Phase(self, p, prefix + p) for p in phases}
+        self._sums: Dict[str, float] = dict.fromkeys(phases, 0.0)
+        self._open: Optional[str] = None
+        self._t = self._t_start = 0.0
+
+    def start(self) -> None:
+        for p in self._sums:
+            self._sums[p] = 0.0
+        self._t_start = time.perf_counter()
+
+    def phase(self, name: str) -> _Phase:
+        return self._phases[name]
+
+    def lap(self, name: Optional[str] = None) -> None:
+        """Read the clock and add the seconds since this timer's last
+        read to ``name``'s sum (to nothing when None: the first read of a
+        run of laps). For a loop whose phases interleave item by item."""
+        now = time.perf_counter()
+        if name is not None:
+            self._sums[name] += now - self._t
+        self._t = now
+
+    def commit(self) -> None:
+        self._whole.record(time.perf_counter() - self._t_start)
+        for p, h in self._hists.items():
+            h.record(self._sums[p])
 
 
 # -- flight-recorder sink (health/recorder.py plugs in here) -----------------
