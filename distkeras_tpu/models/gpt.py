@@ -19,19 +19,29 @@ single-device and vice versa.
 
 Decode mode (generative serving, DESIGN.md §14): every module also
 accepts ``cache``/``cache_index``. The cache is a per-layer
-``{"k", "v"}`` pytree of ``[batch, max_len, heads, head_dim]`` arrays
-(see :func:`init_cache`); ``cache_index[b]`` is the number of tokens
-already cached for row ``b``, i.e. the position of this call's first
-input token. The module writes the block's K/V into the cache and
-attends over the FULL fixed-length cache with positions
-``>= cache_index + q`` masked to exact-zero softmax weight, then
-returns ``(logits, new_cache)``. One code path covers both phases:
-prefill is a T-token call at ``cache_index=0``, decode a T=1 call at
-``cache_index=lengths``. Because the attention contraction always runs
-over ``max_len`` keys with an exact-zero tail, decode logits are
-bitwise-equal (f32) to the standard full forward evaluated at the same
-``max_len`` padded shape (NUMERICS.md "Decode-step equivalence");
-cache mode requires ``attention="full"``.
+``{"k", "v"}`` pytree of ``[rows, max_len, width]`` arrays (see
+:func:`init_cache`): one row a sequence, one ``width``-wide line a
+position, every head's ``head_dim`` values side by side in it — the
+form the qkv projection emits and the form the TPU stores as written
+(a last dimension that is a multiple of 128 lanes; a ``head_dim`` of 64
+there is kept position-minor, and every step pays to turn it round).
+``cache_index[b]`` is the number of tokens already cached for lane
+``b``, i.e. the position of this call's first input token. The module
+writes the block's K/V lines into the cache IN PLACE, first, then
+attends over the FULL fixed-length rows with positions
+``> cache_index + q`` masked to exact-zero softmax weight, and returns
+``(logits, new_cache)``: every cell the write changes is either an
+in-call position or masked, so writing first changes no output. One
+code path covers both phases: prefill is a T-token call at
+``cache_index=0``, decode a short call at ``cache_index=lengths``.
+Lane ``b`` reads and writes cache row ``b``; ``cache_rows`` (``[batch]``
+row ids) points the lanes at rows of a larger cache instead — the
+serving slot pool, which the step then updates in place and attends
+through a gather, building no second rectangle. Because the attention
+contraction always runs over ``max_len`` keys with an exact-zero tail,
+decode logits equal (f32, to rounding order) the standard full forward
+evaluated at the same ``max_len`` padded shape (NUMERICS.md
+"Decode-step equivalence"); cache mode requires ``attention="full"``.
 
 Paged decode mode (DESIGN.md §19): passing ``page_table`` alongside
 ``cache`` switches the cache layout from one ``max_len`` row per batch
@@ -41,12 +51,14 @@ row to a shared **page pool** — per layer ``{"k", "v"}`` arrays of
 ``page_table[b, j]`` naming the physical page that backs row ``b``'s
 logical token positions ``[j*page_size, (j+1)*page_size)``. The forward
 gathers each row's pages into a dense ``[batch, max_len, ...]`` view,
-places the in-call K/V block into that view, and runs the IDENTICAL
-fixed-length masked attention as the rectangular path — the view holds
-bitwise-the-same values at every unmasked position, so paged decode
-logits stay bitwise-equal to rectangular decode (asserted in
-tests/test_paged_generation.py). The new K/V block is then scattered to
-its physical page cells; positions past ``max_len`` (the ghost slot)
+places the in-call K/V block into that view, and runs the same
+fixed-length masked attention as the rectangular path, per head
+(:func:`dot_product_attention`) — the view holds bitwise-the-same
+values at every unmasked position, so paged decode logits equal
+rectangular decode up to the order the sums are taken in (each is held
+to the full forward in its own test file). The new K/V block is then
+scattered to its physical page cells; positions past ``max_len`` (the
+ghost slot)
 and cells of unmapped table entries land in the scratch page.
 
 Int8 KV pages (DESIGN.md §19, ISSUE 20): when the paged cache carries
@@ -75,8 +87,73 @@ import numpy as np
 from distkeras_tpu import precision as precision_lib
 from distkeras_tpu.models.remat import remat_wrap
 from distkeras_tpu.models.transformer import MlpBlock
-from distkeras_tpu.ops.attention import dot_product_attention
+from distkeras_tpu.ops.attention import MASK_VALUE, dot_product_attention
 from distkeras_tpu.ops.ring_attention import ring_attention
+
+#: largest slice, in elements, that the TPU compiler gathers where it
+#: lies; a larger one it first cuts into pieces by copying the whole
+#: operand (tests/test_decode_layout.py reads the compiled step)
+_GATHER_SLICE_ELEMS = 1 << 18
+
+#: most query rows (block positions x heads) the cache attention spreads
+#: over the width: up to one pass of the MXU's rows the spread costs no
+#: more than streaming K and V once, past it `heads` times the matmul
+_SPREAD_QUERY_ROWS = 128
+
+
+def _gather_rows(leaf, rows):
+    """``leaf[rows]`` of a ``[n, max_len, width]`` cache leaf, taken in
+    runs of positions of at most :data:`_GATHER_SLICE_ELEMS` elements (a
+    row is contiguous, so a run is a view of it). ``rows=None`` is lane
+    i = row i: the leaf itself."""
+    if rows is None:
+        return leaf
+    n, max_len, width = leaf.shape
+    runs = 1
+    while (max_len // runs) * width > _GATHER_SLICE_ELEMS \
+            and max_len % (2 * runs) == 0:
+        runs *= 2
+    idx = (rows[:, None] * runs + jnp.arange(runs)[None, :]).reshape(-1)
+    taken = leaf.reshape(n * runs, max_len // runs, width)[idx]
+    return taken.reshape(rows.shape[0], max_len, width)
+
+
+def _attend_rows(q, k_rows, v_rows, pos, num_heads):
+    """Causal attention of a block's queries ``q [b, t, width]`` at
+    positions ``pos [b, t]`` over whole cache rows ``[b, max_len,
+    width]``, K and V read as they lie. Key ``p`` is visible to query
+    ``j`` iff ``p <= pos[j]``; masked keys get exact-zero softmax weight
+    (MASK_VALUE underflows), so the fixed-length contraction matches the
+    max_len-padded full forward (NUMERICS.md "Decode-step equivalence").
+
+    A short block (``t * heads <= _SPREAD_QUERY_ROWS``: decode, verify)
+    never splits K or V into heads: each head's query is spread over the
+    width, zeros outside its own ``head_dim`` columns, and contracted
+    with the ``[max_len, width]`` rows as matrices. The zeros add
+    nothing to the float32 sums, so these are
+    :func:`dot_product_attention`'s numbers: bf16 K/V, float32 logits
+    and softmax, weights cast back for PV. A long block (prefill)
+    reshapes its rows to heads and calls it."""
+    b, t, width = q.shape
+    head_dim = width // num_heads
+    max_len = k_rows.shape[1]
+    mask = jnp.arange(max_len)[None, None, None, :] <= pos[:, None, :, None]
+    if t * num_heads > _SPREAD_QUERY_ROWS:
+        heads = lambda a: a.reshape(a.shape[:2] + (num_heads, head_dim))
+        out = dot_product_attention(heads(q), heads(k_rows), heads(v_rows),
+                                    mask=mask)
+        return out.reshape(b, t, width)
+    own = (jnp.arange(width)[None, :] // head_dim
+           == jnp.arange(num_heads)[:, None])[None, :, None, :]  # [1,h,1,w]
+    spread = jnp.where(own, q[:, None], 0).reshape(b, num_heads * t, width)
+    logits = jnp.einsum("bqw,bkw->bqk", spread, k_rows).astype(jnp.float32)
+    logits = logits.reshape(b, num_heads, t, max_len) * head_dim ** -0.5
+    logits = jnp.where(mask, logits, MASK_VALUE)
+    weights = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bqk,bkw->bqw",
+                     weights.reshape(b, num_heads * t, max_len), v_rows)
+    out = out.reshape(b, num_heads, t, width)
+    return jnp.where(own, out, 0).sum(axis=1)
 
 
 class CausalSelfAttention(nn.Module):
@@ -87,7 +164,8 @@ class CausalSelfAttention(nn.Module):
     precision: Optional[str] = None
 
     @nn.compact
-    def __call__(self, x, cache=None, cache_index=None, page_table=None):
+    def __call__(self, x, cache=None, cache_index=None, page_table=None,
+                 cache_rows=None):
         dtype, dense_kw, _, _ = precision_lib.resolve(self.precision,
                                                       self.dtype)
         width = x.shape[-1]
@@ -167,21 +245,25 @@ class CausalSelfAttention(nn.Module):
                 out = nn.Dense(width, dtype=dtype, name="out",
                                **dense_kw)(out)
                 return out, new_cache
-            # mode="drop": a ghost position past max_len-1 (the decode
-            # step's gemm-path padding, DESIGN.md §14) must not clamp
-            # onto the last real cell
-            k_cache = cache["k"].at[rows, pos].set(k, mode="drop")
-            v_cache = cache["v"].at[rows, pos].set(v, mode="drop")
-            # causal across history + block: key p visible to query j iff
-            # p <= cache_index + j; masked keys get exact-zero softmax
-            # weight (MASK_VALUE underflows), so the fixed-length
-            # contraction matches the max_len-padded full forward bitwise
-            key_pos = jnp.arange(k_cache.shape[1])
-            mask = key_pos[None, None, None, :] <= pos[:, None, :, None]
-            out = dot_product_attention(q, k_cache, v_cache, mask=mask)
-            out = out.reshape(out.shape[:2] + (width,))
+            # rectangular cache, leaves [rows, max_len, width]: write the
+            # block's lines into their rows in place FIRST (the paged
+            # branch's argument: every cell this changes is an in-call
+            # position or masked), then attend the lanes' rows where
+            # they lie. mode="drop": a position past max_len-1 (the
+            # decode step's ghost, DESIGN.md §14) must not clamp onto
+            # the last real cell
+            if cache_rows is not None:
+                rows = cache_rows[:, None]
+            lines = lambda a: a.reshape(b, t, width)
+            new_cache = {
+                "k": cache["k"].at[rows, pos].set(lines(k), mode="drop"),
+                "v": cache["v"].at[rows, pos].set(lines(v), mode="drop")}
+            out = _attend_rows(lines(q),
+                               _gather_rows(new_cache["k"], cache_rows),
+                               _gather_rows(new_cache["v"], cache_rows),
+                               pos, self.num_heads)
             out = nn.Dense(width, dtype=dtype, name="out", **dense_kw)(out)
-            return out, {"k": k_cache, "v": v_cache}
+            return out, new_cache
         if self.attention == "ring":
             out = ring_attention(q, k, v, axis_name=self.axis_name,
                                  causal=True)
@@ -212,14 +294,15 @@ class DecoderBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = False, cache=None, cache_index=None,
-                 page_table=None):
+                 page_table=None, cache_rows=None):
         dtype = precision_lib.resolve(self.precision, self.dtype)[0]
         y = nn.LayerNorm(dtype=jnp.float32, name="ln1")(x).astype(dtype)
         attn = CausalSelfAttention(self.num_heads, self.dtype, self.attention,
                                    self.axis_name, precision=self.precision,
                                    name="attn")
         if cache is not None:
-            y, new_cache = attn(y, cache, cache_index, page_table)
+            y, new_cache = attn(y, cache, cache_index, page_table,
+                                cache_rows)
         else:
             y, new_cache = attn(y), None
         x = x + y
@@ -249,7 +332,7 @@ class CausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, train: bool = False, cache=None,
-                 cache_index=None, page_table=None):
+                 cache_index=None, page_table=None, cache_rows=None):
         dtype = precision_lib.resolve(self.precision, self.dtype)[0]
         ids = input_ids.astype(jnp.int32)
         b, t = ids.shape  # t = LOCAL block length under sequence parallelism
@@ -271,7 +354,7 @@ class CausalLM(nn.Module):
                     self.attention, self.axis_name,
                     precision=self.precision, name=f"layer_{i}")(
                         x, train, cache=cache[i], cache_index=cache_index,
-                        page_table=page_table)
+                        page_table=page_table, cache_rows=cache_rows)
                 new_cache.append(layer_cache)
             x = nn.LayerNorm(dtype=jnp.float32, name="ln_final")(x)
             logits = nn.Dense(self.vocab_size, dtype=jnp.float32,
@@ -308,14 +391,14 @@ class CausalLM(nn.Module):
 def init_cache(model: CausalLM, batch: int, dtype=None):
     """Zeroed per-layer K/V cache for ``batch`` rows of ``model.max_len``
     context: a tuple (one entry per layer) of ``{"k", "v"}`` arrays shaped
-    ``[batch, max_len, num_heads, head_dim]`` in the model's resolved
+    ``[batch, max_len, width]`` (a position's heads side by side, as the
+    qkv projection emits them; module docstring) in the model's resolved
     compute dtype (K/V are produced by the qkv projection, which runs in
     that dtype). ~``2 * layers * max_len * width * itemsize`` bytes per
     row — the number the serving slot pool budgets against."""
     if dtype is None:
         dtype = precision_lib.resolve(model.precision, model.dtype)[0]
-    head_dim = model.width // model.num_heads
-    shape = (batch, model.max_len, model.num_heads, head_dim)
+    shape = (batch, model.max_len, model.width)
     return tuple({"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
                  for _ in range(model.num_layers))
 

@@ -29,8 +29,8 @@ attention contraction always runs over all ``max_len`` keys with an
 exact-zero masked tail, and each decode step feeds a **ghost position**
 — a T=2 block ``[token, 0]`` — because XLA:CPU's M=1 matmul (gemv)
 path associates the K-reduction differently from the M>=2 gemm path.
-The ghost's query output is discarded and its cache write never leaves
-the step (only the real cell is scattered back to the pool).
+The ghost's query output is discarded and its cache line lands past
+the lane's length, masked until the next token overwrites it.
 
 Backpressure/deadline semantics are PR 2's, with the same typed errors:
 bounded admission queue (:class:`QueueFull`, all-or-nothing), deadlines
@@ -106,7 +106,7 @@ from distkeras_tpu.serving.kv_cache import (KVCachePool, PagedKVCachePool,
 from distkeras_tpu.utils import fault
 
 #: token id fed at the decode step's ghost position (its output is
-#: discarded and its cache write dropped, so any valid id works)
+#: discarded and its cache line masked, so any valid id works)
 GHOST_TOKEN = 0
 
 
@@ -124,10 +124,12 @@ def _default_ladder(num_slots: int) -> Tuple[int, ...]:
 
 def make_prefill_fn(model):
     """Pure ``(params, pool, ids[1, Lb], slot, length) -> (pool',
-    last_logits[V])``: write the prompt's K/V into pool row ``slot`` and
-    return the logits at position ``length - 1`` (the first-token
-    distribution). Bucket padding beyond ``length`` writes cells the
-    length mask hides until real tokens overwrite them."""
+    last_logits[V])``: run the prompt through a fresh ``[1, max_len,
+    width]`` row a leaf, put the row into pool row ``slot`` (the donated
+    pool, one ``dynamic_update_slice`` a leaf) and return the logits at
+    position ``length - 1`` (the first-token distribution). Bucket
+    padding beyond ``length`` writes cells the length mask hides until
+    real tokens overwrite them."""
     import jax
     import jax.numpy as jnp
 
@@ -149,29 +151,22 @@ def make_decode_fn(model):
     """Pure ``(params, pool, slot_ids[n], tokens[n], lengths[n]) ->
     (pool', logits[n, V])``: advance ``n`` lanes one token. Each lane
     feeds ``[token, GHOST_TOKEN]`` at positions ``[len, len+1]`` (the
-    ghost keeps every matmul on the gemm path — see module docstring);
-    only the real position's new K/V cell is scattered back, and only
-    its logits returned. Padded lanes point at the pool's scratch row
-    with length 0; their writes land in scratch and their outputs are
-    discarded by the caller."""
-    import jax
+    ghost keeps every matmul on the gemm path — see module docstring).
+    The model writes both K/V lines into pool row ``slot_ids[i]`` in
+    place and attends the lanes' rows where they lie
+    (``cache_rows=slot_ids``); only the real position's logits return.
+    The ghost's line sits past the lane's new length, masked until the
+    next token overwrites it, and is dropped at ``max_len``. Padded
+    lanes point at the pool's scratch row with length 0; their writes
+    land in scratch and their outputs are discarded by the caller."""
     import jax.numpy as jnp
 
+    step = make_verify_fn(model)
+
     def decode(params, pool, slot_ids, tokens, lengths):
-        n = slot_ids.shape[0]
-        rows = jax.tree.map(lambda a: a[slot_ids], pool)
         ids = jnp.stack(
             [tokens, jnp.full_like(tokens, GHOST_TOKEN)], axis=1)
-        logits, new_rows = model.apply(
-            {"params": params}, ids, cache=rows, cache_index=lengths)
-        lane = jnp.arange(n)
-        # scatter back ONLY the real cell [slot, len]; the ghost cell
-        # never reaches the pool. Scratch-lane duplicates collide only
-        # with each other on the scratch row (mode="drop" is for a real
-        # cell at max_len-1 whose ghost would otherwise clamp).
-        pool = jax.tree.map(
-            lambda p, c: p.at[slot_ids, lengths].set(
-                c[lane, lengths], mode="drop"), pool, new_rows)
+        pool, logits = step(params, pool, slot_ids, ids, lengths)
         return pool, logits[:, 0, :]
 
     return decode
@@ -180,26 +175,19 @@ def make_decode_fn(model):
 def make_verify_fn(model):
     """Pure ``(params, pool, slot_ids[n], tokens[n, T], lengths[n]) ->
     (pool', logits[n, T, V])``: the speculative verify step over the
-    rectangular pool. Each lane feeds ``[pending, d_1 .. d_{T-1}]`` at
-    positions ``len .. len+T-1``; ALL T new K/V cells are scattered back
+    rectangular pool, decode's step at T positions. Each lane feeds
+    ``[pending, d_1 .. d_{T-1}]`` at positions ``len .. len+T-1``; ALL T
+    new K/V lines are written into the lane's pool row in place
     (accepted cells are exactly what sequential greedy would have
     written; rejected cells sit past the post-accept length, masked and
     overwritten before ever becoming visible) and all T logit rows
     return for the host-side accept/reject walk. T >= 2 keeps the gemm
     path, same as the decode ghost."""
-    import jax
-    import jax.numpy as jnp
 
     def verify(params, pool, slot_ids, tokens, lengths):
-        n, t = tokens.shape
-        rows = jax.tree.map(lambda a: a[slot_ids], pool)
-        logits, new_rows = model.apply(
-            {"params": params}, tokens, cache=rows, cache_index=lengths)
-        lane = jnp.arange(n)[:, None]
-        pos = lengths[:, None] + jnp.arange(t)[None, :]
-        pool = jax.tree.map(
-            lambda p, c: p.at[slot_ids[:, None], pos].set(
-                c[lane, pos], mode="drop"), pool, new_rows)
+        logits, pool = model.apply(
+            {"params": params}, tokens, cache=pool, cache_index=lengths,
+            cache_rows=slot_ids)
         return pool, logits
 
     return verify

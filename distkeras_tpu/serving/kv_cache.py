@@ -2,9 +2,11 @@
 
 One device-resident pytree holds the K/V cache for every in-flight
 sequence: per layer, ``{"k", "v"}`` arrays shaped
-``[num_slots + 1, max_len, heads, head_dim]``. Row ``s < num_slots`` is
-*slot s* — one sequence's full-context cache, written by the prefill and
-decode executables at positions ``< lengths[s]``. The extra last row is
+``[num_slots + 1, max_len, width]`` (a position's heads side by side in
+one ``width``-wide line: the form the device stores as written, see
+models/gpt.py). Row ``s < num_slots`` is *slot s* — one sequence's
+full-context cache, written in place by the prefill and decode
+executables at positions ``< lengths[s]``. The extra last row is
 the **scratch slot**: padded decode lanes (the slot ladder pads the
 in-flight batch up to a compiled lane count) point their reads *and*
 writes at it, so padding never perturbs a live sequence and never needs

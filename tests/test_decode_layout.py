@@ -1,0 +1,118 @@
+"""The compiled serving step, read without a chip.
+
+The rectangular KV pool lives on the device as ``[rows, max_len, width]``
+leaves: a last dimension of a multiple of 128 lanes, which the TPU stores
+as written. A leaf whose last dimension is a 64-wide ``head_dim`` it
+keeps position-minor instead, and every step then copies the whole pool
+between the two forms, on entry, for the gathered rows, and on exit
+(59 of a 70 ms decode step at gpt2-medium's widths; PERF.md, PR 25).
+
+These tests compile ``make_decode_fn`` / ``make_prefill_fn`` /
+``make_verify_fn`` at gpt2-medium's widths (2 layers, shapes only, no
+weights) for a described v5e and assert what the compiler made of the
+pool: the default layout at entry, and no ``copy`` or ``transpose`` of a
+leaf's size, or of a quarter of one, anywhere in the entry computation.
+The TPU compiler is installed with libtpu; where it cannot describe the
+topology the tests skip. Nothing here runs, and nothing here is a time.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from distkeras_tpu.models import gpt as gpt_lib
+from distkeras_tpu.serving import generation
+
+NUM_SLOTS = 32
+MAX_LEN = 1024
+WIDTH = 1024
+#: a quarter of the smallest rectangle a step could copy (8 lanes)
+QUARTER = 8 * MAX_LEN * WIDTH // 4
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or it cannot describe a v5e here
+        pytest.skip(f"no v5e topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def shapes(one_chip):
+    """gpt2-medium's widths at 2 layers: the model, and shape structs of
+    its parameters and of a 32-slot pool, placed on the described chip."""
+    model = gpt_lib.CausalLM(vocab_size=50304, max_len=MAX_LEN,
+                             num_layers=2, num_heads=16, width=WIDTH,
+                             mlp_dim=4096)
+    put = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = put(jax.eval_shape(
+        lambda: model.init(jax.random.key(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"]))
+    pool = put(jax.eval_shape(
+        lambda: gpt_lib.init_cache(model, NUM_SLOTS + 1)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32,
+                                              sharding=one_chip)
+    return model, params, pool, i32
+
+
+def _compile(shapes, step, lanes_or_bucket):
+    model, params, pool, i32 = shapes
+    n = lanes_or_bucket
+    if step == "decode":
+        fn, args = generation.make_decode_fn(model), (i32(n), i32(n), i32(n))
+    elif step == "verify":
+        fn, args = generation.make_verify_fn(model), (i32(n), i32(n, 4),
+                                                      i32(n))
+    else:
+        fn, args = generation.make_prefill_fn(model), (i32(1, n), i32(),
+                                                       i32())
+    return jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pool, *args).compile().as_text()
+
+
+def _pool_layouts(text):
+    """``(dimensions, layout)`` of the pool's leaves (the bf16 arrays of
+    ``NUM_SLOTS + 1`` rows) in ``entry_computation_layout``, parameters
+    and results alike."""
+    header = text[text.index("entry_computation_layout"):].split("\n", 1)[0]
+    return re.findall(rf"bf16\[({NUM_SLOTS + 1},[\d,]+)\]\{{([\d,]+)", header)
+
+
+def _rectangle_moves(text):
+    """``copy``/``transpose`` instructions of the entry computation whose
+    result, in the pool's dtype, holds at least a quarter of an 8-lane
+    rectangle (the float32 logits are no part of the pool). (The
+    compiler's own ``copy-start``/``copy-done`` pairs are not counted:
+    they prefetch an operand into faster memory in the layout it has.)"""
+    entry = text[text.index("\nENTRY"):]
+    found = []
+    for m in re.finditer(
+            r"= \(?bf16\[([\d,]+)\]\S* (copy|transpose)\(", entry):
+        if np.prod([int(d) for d in m.group(1).split(",")]) >= QUARTER:
+            found.append(m.group(0))
+    return found
+
+
+@pytest.mark.parametrize("step,size", [
+    ("decode", 8), ("decode", 16), ("decode", 32),
+    ("prefill", 64), ("prefill", 768), ("verify", 32)])
+def test_compiled_step_keeps_the_pool_as_stored(shapes, step, size):
+    text = _compile(shapes, step, size)
+    leaves = _pool_layouts(text)
+    # 2 layers x (k, v), as parameters and as results
+    assert len(leaves) == 8, leaves
+    for dims, layout in leaves:
+        rank = dims.count(",") + 1
+        default = ",".join(str(d) for d in reversed(range(rank)))
+        assert layout == default, f"bf16[{dims}] is kept as {{{layout}}}"
+    assert _rectangle_moves(text) == []

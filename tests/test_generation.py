@@ -31,7 +31,9 @@ from distkeras_tpu.serving import (
     KVCachePool,
     QueueFull,
 )
-from distkeras_tpu.serving.generation import make_decode_fn, make_prefill_fn
+from distkeras_tpu.serving.generation import (make_decode_fn,
+                                              make_prefill_fn,
+                                              make_verify_fn)
 
 
 @pytest.fixture(autouse=True)
@@ -140,6 +142,188 @@ def test_batched_decode_lanes_keep_per_row_bitwise_parity(lm):
             seq.append(toks[j])
             np.testing.assert_array_equal(logits[j], ref(seq))
             toks[j] = int(np.argmax(logits[j]))
+
+
+# The pool's leaves are [rows, max_len, width]; a step writes its K/V
+# lines into the pool in place first and attends the lanes' rows where
+# they lie (models/gpt.py). float32 tolerance of the repo's other
+# reference comparisons: the sums are the full forward's up to the order
+# XLA:CPU adds them in.
+TOL = dict(rtol=1e-5, atol=1e-5)
+
+
+def _prefilled(model, params, seqs, num_slots, bucket=8):
+    """A pool with ``seqs`` prefilled into slots 0.., and each one's
+    first-token logits."""
+    pool = KVCachePool(model, num_slots=num_slots)
+    prefill = jax.jit(make_prefill_fn(model))
+    slots, lasts = [], []
+    for seq in seqs:
+        ids = np.zeros((1, bucket), np.int32)
+        ids[0, :len(seq)] = seq
+        slot = pool.allocate()
+        new_pool, last = prefill(params, pool.pool, ids, np.int32(slot),
+                                 np.int32(len(seq)))
+        pool.swap(new_pool)
+        pool.lengths[slot] = len(seq)
+        slots.append(slot)
+        lasts.append(np.asarray(last))
+    return pool, slots, lasts
+
+
+def test_pool_leaves_are_rows_by_positions_by_width(lm):
+    model, _ = lm
+    pool = KVCachePool(model, num_slots=3)
+    assert len(pool.pool) == model.num_layers
+    for layer in pool.pool:
+        assert sorted(layer) == ["k", "v"]
+        for leaf in layer.values():
+            assert leaf.shape == (4, model.max_len, model.width)
+            assert leaf.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("bucket,steps", [(8, 24), (32, 6), (128, 6)])
+def test_prefill_then_decode_matches_full_forward(lm, bucket, steps):
+    """Bucket 8 and 32 prefill through the spread-query form, bucket 128
+    through the reshape-to-heads form (128 positions x 2 heads > 128
+    rows); every decode step through the spread form over the pool."""
+    model, params = lm
+    ref = _ref_fn(model, params)
+    seq = _prompt(5)
+    pool, (slot,), (last,) = _prefilled(model, params, [seq], 1, bucket)
+    np.testing.assert_allclose(last, ref(seq), **TOL)
+    decode = jax.jit(make_decode_fn(model), donate_argnums=(1,))
+    tok = int(np.argmax(last))
+    for _ in range(steps):
+        new_pool, logits = decode(
+            params, pool.pool, np.array([slot], np.int32),
+            np.array([tok], np.int32),
+            np.array([pool.lengths[slot]], np.int32))
+        pool.swap(new_pool)
+        pool.lengths[slot] += 1
+        seq.append(tok)
+        step = np.asarray(logits)[0]
+        np.testing.assert_allclose(step, ref(seq), **TOL)
+        tok = int(np.argmax(step))
+
+
+@pytest.mark.parametrize("t", [2, 4])
+def test_verify_agrees_with_decode_step_by_step(lm, t):
+    """One verify call over T fed tokens gives, row by row, the logits of
+    T decode steps, and leaves the same K/V lines in the pool."""
+    model, params = lm
+    seqs = [_prompt(5, seed=1), _prompt(7, seed=2)]
+    pool, slots, lasts = _prefilled(model, params, seqs, 2)
+    decode = jax.jit(make_decode_fn(model))
+    verify = jax.jit(make_verify_fn(model))
+    slot_ids = np.array(slots, np.int32)
+    start = pool.lengths[slots].copy()
+    fed = np.zeros((2, t), np.int32)
+    stepwise = np.zeros((2, t, model.vocab_size), np.float32)
+    seq_pool, toks = pool.pool, [int(np.argmax(r)) for r in lasts]
+    for j in range(t):
+        fed[:, j] = toks
+        seq_pool, logits = decode(params, seq_pool, slot_ids,
+                                  np.array(toks, np.int32),
+                                  (start + j).astype(np.int32))
+        stepwise[:, j] = np.asarray(logits)
+        toks = [int(np.argmax(r)) for r in stepwise[:, j]]
+    ver_pool, ver_logits = verify(params, pool.pool, slot_ids, fed, start)
+    np.testing.assert_allclose(np.asarray(ver_logits), stepwise, **TOL)
+    for want, got in zip(jax.tree.leaves(seq_pool),
+                         jax.tree.leaves(ver_pool)):
+        for i, s in enumerate(slots):
+            np.testing.assert_allclose(
+                np.asarray(got)[s, :start[i] + t],
+                np.asarray(want)[s, :start[i] + t], **TOL)
+
+
+def test_last_cell_is_written_and_its_ghost_writes_nothing(lm, monkeypatch):
+    """A lane at max_len - 1 writes cell max_len - 1; its ghost, at
+    max_len, is dropped: whatever token the ghost feeds, the pool comes
+    back the same, and no other row changes."""
+    from distkeras_tpu.serving import generation
+
+    model, params = lm
+    last = model.max_len - 1
+    pools = []
+    for ghost in (0, 5):
+        monkeypatch.setattr(generation, "GHOST_TOKEN", ghost)
+        pool = KVCachePool(model, num_slots=2)
+        decode = jax.jit(make_decode_fn(model))
+        new_pool, logits = decode(
+            params, pool.pool, np.array([0], np.int32),
+            np.array([7], np.int32), np.array([last], np.int32))
+        assert np.isfinite(np.asarray(logits)).all()
+        pools.append(jax.tree.map(np.asarray, new_pool))
+    for a, b in zip(jax.tree.leaves(pools[0]), jax.tree.leaves(pools[1])):
+        np.testing.assert_array_equal(a, b)
+        assert np.abs(a[0, last]).max() > 0          # the real cell
+        assert not a[0, :last].any()                 # nothing clamped back
+        assert not a[1:].any()                       # nor wrapped forward
+    # one position earlier the ghost does land, past the new length
+    monkeypatch.setattr(generation, "GHOST_TOKEN", 0)
+    pool = KVCachePool(model, num_slots=2)
+    new_pool, _ = jax.jit(make_decode_fn(model))(
+        params, pool.pool, np.array([0], np.int32),
+        np.array([7], np.int32), np.array([last - 1], np.int32))
+    for leaf in jax.tree.leaves(new_pool):
+        written = np.abs(np.asarray(leaf)[0]).max(axis=-1) > 0
+        assert written.nonzero()[0].tolist() == [last - 1, last]
+
+
+def test_padded_lanes_on_scratch_disturb_no_live_row(lm):
+    """The same two live lanes through a 2-wide step and through a 4-wide
+    step padded with scratch lanes: the live rows come back the same and
+    cells no lane wrote are untouched, bit for bit."""
+    model, params = lm
+    seqs = [_prompt(5, seed=1), _prompt(7, seed=2)]
+    pool, slots, lasts = _prefilled(model, params, seqs, 2)
+    decode = jax.jit(make_decode_fn(model))
+    before = jax.tree.map(np.asarray, pool.pool)
+    toks = [int(np.argmax(r)) for r in lasts]
+    lens = [int(pool.lengths[s]) for s in slots]
+    scratch = pool.scratch_slot
+    two, logits2 = decode(params, pool.pool, np.array(slots, np.int32),
+                          np.array(toks, np.int32),
+                          np.array(lens, np.int32))
+    four, logits4 = decode(
+        params, pool.pool, np.array(slots + [scratch] * 2, np.int32),
+        np.array(toks + [0, 0], np.int32), np.array(lens + [0, 0], np.int32))
+    np.testing.assert_allclose(np.asarray(logits4)[:2], np.asarray(logits2),
+                               **TOL)
+    for old, a, b in zip(jax.tree.leaves(before), jax.tree.leaves(two),
+                         jax.tree.leaves(four)):
+        a, b = np.asarray(a), np.asarray(b)
+        for s, n in zip(slots, lens):
+            np.testing.assert_allclose(b[s], a[s], **TOL)
+            # the step wrote cells n (token) and n + 1 (ghost) only
+            np.testing.assert_array_equal(b[s, :n], old[s, :n])
+            np.testing.assert_array_equal(b[s, n + 2:], old[s, n + 2:])
+        np.testing.assert_array_equal(a[scratch], old[scratch])
+
+
+def test_model_draft_proposes_the_greedy_continuation(lm):
+    """A ModelDraft on the target's own weights keeps its own pool
+    through the same three functions: its k proposals are the reference's
+    greedy continuation, token for token."""
+    from distkeras_tpu.serving.generation import ModelDraft
+
+    model, params = lm
+    ref = _ref_fn(model, params)
+    prompt = _prompt(6, seed=14)
+    seq, want = list(prompt), []
+    for _ in range(5):
+        want.append(int(np.argmax(ref(seq))))
+        seq.append(want[-1])
+    draft = ModelDraft(model, params)
+    with GenerationEngine(model, params, num_slots=2,
+                          prefill_buckets=(8, 32), draft=draft,
+                          spec_k=4) as eng:
+        assert "draft_prefill" in eng.compiled_executables
+        draft.begin(0, prompt, want[0])
+        got = draft.propose([0], [want[0]], [len(prompt)], 4)
+    assert got[0].tolist() == want[1:]
 
 
 def test_engine_matches_padded_full_forward_greedy(lm):
