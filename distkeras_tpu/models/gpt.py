@@ -18,9 +18,15 @@ Both paths share weights: a model trained sequence-parallel serves
 single-device and vice versa.
 
 Decode mode (generative serving, DESIGN.md §14): every module also
-accepts ``cache``/``cache_index``. The cache is a per-layer
+accepts ``cache``/``cache_index``. **The model owns its cache's leaves**:
+the serving pool (serving/kv_cache.py) and the draft ask the model for
+them and for their cost (:meth:`CausalLM.init_cache`,
+:meth:`CausalLM.cache_bytes_per_row`, :meth:`CausalLM.prefill_row_len`
+— the protocol every family that serves through ``GenerationEngine``
+meets; models/latent_moe.py is the second), and treat what comes back as
+a pytree whose leaves have rows first. This family's cache is a per-layer
 ``{"k", "v"}`` pytree of ``[rows, max_len, width]`` arrays (see
-:func:`init_cache`): one row a sequence, one ``width``-wide line a
+:meth:`CausalLM.init_cache`): one row a sequence, one ``width``-wide line a
 position, every head's ``head_dim`` values side by side in it — the
 form the qkv projection emits and the form the TPU stores as written
 (a last dimension that is a multiple of 128 lanes; a ``head_dim`` of 64
@@ -88,34 +94,13 @@ from distkeras_tpu import precision as precision_lib
 from distkeras_tpu.models.remat import remat_wrap
 from distkeras_tpu.models.transformer import MlpBlock
 from distkeras_tpu.ops.attention import MASK_VALUE, dot_product_attention
+from distkeras_tpu.ops.cache_rows import gather_rows
 from distkeras_tpu.ops.ring_attention import ring_attention
-
-#: largest slice, in elements, that the TPU compiler gathers where it
-#: lies; a larger one it first cuts into pieces by copying the whole
-#: operand (tests/test_decode_layout.py reads the compiled step)
-_GATHER_SLICE_ELEMS = 1 << 18
 
 #: most query rows (block positions x heads) the cache attention spreads
 #: over the width: up to one pass of the MXU's rows the spread costs no
 #: more than streaming K and V once, past it `heads` times the matmul
 _SPREAD_QUERY_ROWS = 128
-
-
-def _gather_rows(leaf, rows):
-    """``leaf[rows]`` of a ``[n, max_len, width]`` cache leaf, taken in
-    runs of positions of at most :data:`_GATHER_SLICE_ELEMS` elements (a
-    row is contiguous, so a run is a view of it). ``rows=None`` is lane
-    i = row i: the leaf itself."""
-    if rows is None:
-        return leaf
-    n, max_len, width = leaf.shape
-    runs = 1
-    while (max_len // runs) * width > _GATHER_SLICE_ELEMS \
-            and max_len % (2 * runs) == 0:
-        runs *= 2
-    idx = (rows[:, None] * runs + jnp.arange(runs)[None, :]).reshape(-1)
-    taken = leaf.reshape(n * runs, max_len // runs, width)[idx]
-    return taken.reshape(rows.shape[0], max_len, width)
 
 
 def _attend_rows(q, k_rows, v_rows, pos, num_heads):
@@ -259,8 +244,8 @@ class CausalSelfAttention(nn.Module):
                 "k": cache["k"].at[rows, pos].set(lines(k), mode="drop"),
                 "v": cache["v"].at[rows, pos].set(lines(v), mode="drop")}
             out = _attend_rows(lines(q),
-                               _gather_rows(new_cache["k"], cache_rows),
-                               _gather_rows(new_cache["v"], cache_rows),
+                               gather_rows(new_cache["k"], cache_rows),
+                               gather_rows(new_cache["v"], cache_rows),
                                pos, self.num_heads)
             out = nn.Dense(width, dtype=dtype, name="out", **dense_kw)(out)
             return out, new_cache
@@ -330,6 +315,40 @@ class CausalLM(nn.Module):
     #: stays f32
     precision: Optional[str] = None
 
+    # -- the cache protocol (module docstring): the model owns its
+    # cache's leaves; the serving pool asks for them and for their cost
+
+    def init_cache(self, batch: int, dtype=None):
+        """Zeroed per-layer K/V cache for ``batch`` rows of ``max_len``
+        context: a tuple (one entry per layer) of ``{"k", "v"}`` arrays
+        shaped ``[batch, max_len, width]`` (a position's heads side by
+        side, as the qkv projection emits them; module docstring) in the
+        model's resolved compute dtype (K/V are produced by the qkv
+        projection, which runs in that dtype)."""
+        if dtype is None:
+            dtype = precision_lib.resolve(self.precision, self.dtype)[0]
+        shape = (batch, self.max_len, self.width)
+        return tuple({"k": jnp.zeros(shape, dtype),
+                      "v": jnp.zeros(shape, dtype)}
+                     for _ in range(self.num_layers))
+
+    def cache_bytes_per_row(self, dtype=None) -> int:
+        """HBM bytes one cache row costs (k + v, every layer): ``2 *
+        layers * max_len * width * itemsize``, the unit the serving slot
+        pool's budget check multiplies by its rows."""
+        if dtype is None:
+            dtype = precision_lib.resolve(self.precision, self.dtype)[0]
+        return (2 * self.num_layers * self.max_len * self.width
+                * np.dtype(dtype).itemsize)
+
+    def prefill_row_len(self, block: int) -> int:
+        """Positions of the fresh row a ``block``-token prefill is given:
+        all ``max_len``, because this family's attention contracts over
+        the whole row at every step (NUMERICS.md "Decode-step
+        equivalence")."""
+        del block
+        return self.max_len
+
     @nn.compact
     def __call__(self, input_ids, train: bool = False, cache=None,
                  cache_index=None, page_table=None, cache_rows=None):
@@ -388,19 +407,11 @@ class CausalLM(nn.Module):
         return logits.astype(jnp.float32)
 
 
-def init_cache(model: CausalLM, batch: int, dtype=None):
-    """Zeroed per-layer K/V cache for ``batch`` rows of ``model.max_len``
-    context: a tuple (one entry per layer) of ``{"k", "v"}`` arrays shaped
-    ``[batch, max_len, width]`` (a position's heads side by side, as the
-    qkv projection emits them; module docstring) in the model's resolved
-    compute dtype (K/V are produced by the qkv projection, which runs in
-    that dtype). ~``2 * layers * max_len * width * itemsize`` bytes per
-    row — the number the serving slot pool budgets against."""
-    if dtype is None:
-        dtype = precision_lib.resolve(model.precision, model.dtype)[0]
-    shape = (batch, model.max_len, model.width)
-    return tuple({"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-                 for _ in range(model.num_layers))
+def init_cache(model, batch: int, dtype=None):
+    """``model.init_cache(batch, dtype)``: the model says what its cache
+    is (:meth:`CausalLM.init_cache`; any family that keeps the cache
+    protocol answers for itself). Kept for ``perf/aot_check.py``."""
+    return model.init_cache(batch, dtype)
 
 
 def init_paged_cache(model: CausalLM, num_pages: int, page_size: int,
@@ -562,7 +573,7 @@ def page_bytes(model: CausalLM, page_size: int, dtype=None,
                kv_dtype=None) -> int:
     """HBM bytes one logical page costs (k + v cells across every
     layer) — the allocation unit the paged pool budgets in, replacing
-    the per-slot :func:`cache_bytes_per_row` rectangle. With
+    the per-slot :meth:`CausalLM.cache_bytes_per_row` rectangle. With
     ``kv_dtype="int8"`` a page is int8 codes plus one f32 scale per
     (layer, k/v): ~4x smaller than f32 pages, ~2x smaller than bf16."""
     if kv_dtype == "int8":
@@ -572,16 +583,6 @@ def page_bytes(model: CausalLM, page_size: int, dtype=None,
         dtype = precision_lib.resolve(model.precision, model.dtype)[0]
     return (2 * model.num_layers * page_size * model.width
             * np.dtype(dtype).itemsize)
-
-
-def cache_bytes_per_row(model: CausalLM, dtype=None) -> int:
-    """HBM bytes one cache slot costs (k + v, every layer) — the unit the
-    KV-cache manager's budget check multiplies by ``num_slots``."""
-    if dtype is None:
-        dtype = precision_lib.resolve(model.precision, model.dtype)[0]
-    head_dim = model.width // model.num_heads
-    per_tensor = model.max_len * model.num_heads * head_dim
-    return 2 * model.num_layers * per_tensor * np.dtype(dtype).itemsize
 
 
 def gpt_small(**kw) -> CausalLM:

@@ -124,19 +124,22 @@ def _default_ladder(num_slots: int) -> Tuple[int, ...]:
 
 def make_prefill_fn(model):
     """Pure ``(params, pool, ids[1, Lb], slot, length) -> (pool',
-    last_logits[V])``: run the prompt through a fresh ``[1, max_len,
-    width]`` row a leaf, put the row into pool row ``slot`` (the donated
-    pool, one ``dynamic_update_slice`` a leaf) and return the logits at
-    position ``length - 1`` (the first-token distribution). Bucket
-    padding beyond ``length`` writes cells the length mask hides until
-    real tokens overwrite them."""
+    last_logits[V])``: run the prompt through a fresh one-row cache of
+    the pool's leaves (``model.prefill_row_len(Lb)`` positions: the whole
+    ``max_len`` for ``CausalLM``, the bucket for a family that attends
+    only what it wrote), put the row into pool row ``slot`` from position
+    0 (the donated pool, one ``dynamic_update_slice`` a leaf) and return
+    the logits at position ``length - 1`` (the first-token distribution).
+    Bucket padding beyond ``length`` writes cells the length mask hides
+    until real tokens overwrite them."""
     import jax
     import jax.numpy as jnp
 
     def prefill(params, pool, ids, slot, length):
+        positions = model.prefill_row_len(ids.shape[1])
         row = jax.tree.map(
-            lambda a: jnp.zeros((1,) + a.shape[1:], a.dtype), pool)
-        logits, new_row = model.apply(
+            lambda a: jnp.zeros((1, positions) + a.shape[2:], a.dtype), pool)
+        logits, new_row, *_ = model.apply(
             {"params": params}, ids, cache=row,
             cache_index=jnp.zeros((1,), jnp.int32))
         pool = jax.tree.map(
@@ -158,16 +161,28 @@ def make_decode_fn(model):
     The ghost's line sits past the lane's new length, masked until the
     next token overwrites it, and is dropped at ``max_len``. Padded
     lanes point at the pool's scratch row with length 0; their writes
-    land in scratch and their outputs are discarded by the caller."""
-    import jax.numpy as jnp
+    land in scratch and their outputs are discarded by the caller.
 
-    step = make_verify_fn(model)
+    A model with routed experts hands back, token by token, which of the
+    experts it holds each was sent to; the step then returns a third
+    value, int32 ``[layers, experts_held]``: tokens per held expert,
+    counted over the real position of the lanes that are not padding.
+    A model without experts returns two values and pays nothing."""
+    import jax
+    import jax.numpy as jnp
 
     def decode(params, pool, slot_ids, tokens, lengths):
         ids = jnp.stack(
             [tokens, jnp.full_like(tokens, GHOST_TOKEN)], axis=1)
-        pool, logits = step(params, pool, slot_ids, ids, lengths)
-        return pool, logits[:, 0, :]
+        logits, pool, *routed = model.apply(
+            {"params": params}, ids, cache=pool, cache_index=lengths,
+            cache_rows=slot_ids)
+        if not routed:
+            return pool, logits[:, 0, :]
+        scratch = jax.tree.leaves(pool)[0].shape[0] - 1
+        live = (slot_ids != scratch)[None, :, None]
+        return pool, logits[:, 0, :], jnp.sum(
+            routed[0][:, :, 0, :] & live, axis=1, dtype=jnp.int32)
 
     return decode
 
@@ -185,7 +200,7 @@ def make_verify_fn(model):
     path, same as the decode ghost."""
 
     def verify(params, pool, slot_ids, tokens, lengths):
-        logits, pool = model.apply(
+        logits, pool, *_ = model.apply(
             {"params": params}, tokens, cache=pool, cache_index=lengths,
             cache_rows=slot_ids)
         return pool, logits
@@ -310,8 +325,6 @@ class ModelDraft:
     def bind(self, engine) -> None:
         import jax
 
-        from distkeras_tpu.models import gpt as gpt_lib
-
         if int(self.model.max_len) < engine.max_len:
             raise ValueError(
                 f"draft max_len {self.model.max_len} < target max_len "
@@ -322,8 +335,8 @@ class ModelDraft:
         self._scratch = engine.pool.num_slots
         if engine._device is not None:
             self.params = jax.device_put(self.params, engine._device)
-        cache = gpt_lib.init_cache(self.model, engine.pool.num_slots + 1,
-                                   self._dtype)
+        cache = self.model.init_cache(engine.pool.num_slots + 1,
+                                      self._dtype)
         if engine._device is not None:
             cache = jax.device_put(cache, engine._device)
         self._cache = cache
@@ -357,8 +370,8 @@ class ModelDraft:
         for n, ex in self._decode_exec.items():
             lanes = np.full(n, scratch, np.int32)
             zeros = np.zeros(n, np.int32)
-            self._cache, _ = ex(self.params, self._cache, lanes, zeros,
-                                zeros)
+            self._cache, *_ = ex(self.params, self._cache, lanes, zeros,
+                                 zeros)
 
     @property
     def compiled_executables(self):
@@ -391,7 +404,7 @@ class ModelDraft:
             slot_ids[:n] = slots
             toks[:n] = feed
             lens[:n] = lens_live
-            self._cache, logits = self._decode_exec[lane](
+            self._cache, logits, *_ = self._decode_exec[lane](
                 self.params, self._cache, slot_ids, toks, lens)
             lens_live += 1
             if step < k:
@@ -645,6 +658,16 @@ class GenerationEngine:
             self._chunk_depth_g = telemetry.gauge(
                 "serving.decode.chunk.queue_depth")
             self._chunk_depth_g.set(0)
+        if hasattr(model, "experts_per_token"):
+            # a model with routed experts: its decode step hands back
+            # tokens per held expert beside the logits (make_decode_fn)
+            self._moe_assign_c = telemetry.counter("serving.moe.assignments")
+            self._moe_held_c = telemetry.counter(
+                "serving.moe.assignments_held")
+            self._moe_active_h = telemetry.histogram(
+                "serving.moe.experts_active")
+            self._moe_load_h = telemetry.histogram(
+                "serving.moe.load_max_over_mean")
         if self._sampling and self._spec_k:
             self._spec_s_accepts_c = telemetry.counter(
                 "serving.decode.spec.sampled_accepts")
@@ -810,8 +833,8 @@ class GenerationEngine:
             for n, ex in self._decode_exec.items():
                 lanes = np.full(n, scratch, np.int32)
                 zeros = np.zeros(n, np.int32)
-                new_pool, _ = ex(self._params, self.pool.pool, lanes,
-                                 zeros, zeros)
+                new_pool, *_ = ex(self._params, self.pool.pool, lanes,
+                                  zeros, zeros)
                 self.pool.swap(new_pool)
             for n, ex in self._verify_exec.items():
                 lanes = np.full(n, scratch, np.int32)
@@ -1534,16 +1557,18 @@ class GenerationEngine:
                                                            lane, 2)
             tp0 = time.perf_counter()
             if self._paged:
-                new_pool, logits = self._decode_exec[lane](
+                new_pool, logits, *routed = self._decode_exec[lane](
                     params, self.pool.pool, self._page_tables_for(slot_ids),
                     tokens, lengths)
             else:
-                new_pool, logits = self._decode_exec[lane](
+                new_pool, logits, *routed = self._decode_exec[lane](
                     params, self.pool.pool, slot_ids, tokens[:, 0], lengths)
         with sched.phase("wait"):
             logits.block_until_ready()  # the step lands
         with sched.phase("copy"):
             logits = np.asarray(logits)  # device to host, nothing else
+            # tokens per held expert, [layers, experts_held] int32
+            routed = [np.asarray(a) for a in routed]
         if self._paged:
             logits = logits[:, 0, :]
         self.pool.swap(new_pool)
@@ -1554,6 +1579,8 @@ class GenerationEngine:
         self._padded_h.record(lane - n)
         if dt > 0:
             self._tps_g.set(n / dt)
+        if routed:
+            self._record_routing(routed[0], n)
         # the lane loop keeps its order lane by lane (what a client sees):
         # one annotation around it, its three phases summed lap by lap
         with telemetry.annotation("serving.sched.emit"):
@@ -1585,6 +1612,24 @@ class GenerationEngine:
                 if reason is not None:
                     del active[s]
                 sched.lap("retire")
+
+    def _record_routing(self, held: np.ndarray, lanes: int) -> None:
+        """A decode step's tokens per held expert, ``[layers,
+        experts_held]`` (make_decode_fn), into ``serving.moe.*``: the
+        assignments its ``lanes`` real tokens made in all, those that
+        fell on experts held here, and one sample a step of how many
+        held experts worked (mean over layers) and of the fullest
+        expert's load over the mean (mean over the layers that got a
+        token)."""
+        layers = held.shape[0]
+        self._moe_assign_c.inc(
+            lanes * self.model.experts_per_token * layers)
+        self._moe_held_c.inc(int(held.sum()))
+        self._moe_active_h.record(float((held > 0).sum(axis=1).mean()))
+        mean = held.mean(axis=1)
+        if mean.any():
+            self._moe_load_h.record(
+                float((held.max(axis=1)[mean > 0] / mean[mean > 0]).mean()))
 
     def _sampled_accept_walk(self, req: _GenRequest, props_i, logits_i):
         """Host side of sampling-capable speculative verification

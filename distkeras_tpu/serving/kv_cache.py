@@ -22,8 +22,15 @@ Host-side state (free list, per-slot lengths) is plain numpy owned by
 the single scheduler thread in serving/generation.py; this class does no
 locking of its own.
 
+The model says what its cache is: the pool builds its leaves with
+``model.init_cache(rows, dtype)`` and budgets with
+``model.cache_bytes_per_row(dtype)`` (the cache protocol of
+models/gpt.py; ``CausalLM`` answers ``{"k", "v"}`` lines of ``width``,
+models/latent_moe.py one latent line a position), and never looks inside
+a leaf beyond its leading rows.
+
 Capacity is budgeted *before* allocation: ``cache_bytes`` multiplies
-:func:`models.gpt.cache_bytes_per_row` by the row count, and on devices
+``model.cache_bytes_per_row`` by the row count, and on devices
 that report allocator stats (``observability.hbm_stats``; None on CPU)
 the constructor refuses pools that would exceed ``hbm_fraction`` of the
 device limit — slot exhaustion must surface as queue backpressure
@@ -63,8 +70,9 @@ class KVCachePool:
 
     Parameters
     ----------
-    model: a ``CausalLM`` (or anything :func:`models.gpt.init_cache`
-        accepts).
+    model: a model that keeps the cache protocol (``init_cache``,
+        ``cache_bytes_per_row``, ``max_len``): ``CausalLM``,
+        ``LatentMoELM``.
     num_slots: concurrent sequences the pool can hold. One extra scratch
         row is always added for padded decode lanes.
     device: optional ``jax.Device`` to place the pool on (default: JAX's
@@ -82,7 +90,7 @@ class KVCachePool:
 
         self.num_slots = int(num_slots)
         self.max_len = int(model.max_len)
-        per_row = gpt_lib.cache_bytes_per_row(model, dtype)
+        per_row = model.cache_bytes_per_row(dtype)
         self.cache_bytes = per_row * (self.num_slots + 1)
         stats = observability.hbm_stats(device)
         if stats and stats.get("limit_bytes"):
@@ -94,7 +102,7 @@ class KVCachePool:
                     f"budget is {int(budget)} B ({hbm_fraction:.0%} of the "
                     f"device limit {stats['limit_bytes']} B); lower "
                     f"num_slots or max_len")
-        pool = gpt_lib.init_cache(model, self.num_slots + 1, dtype)
+        pool = model.init_cache(self.num_slots + 1, dtype)
         if device is not None:
             pool = jax.device_put(pool, device)
         #: live device pytree; replaced wholesale by swap() after every
@@ -189,6 +197,13 @@ class PagedKVCachePool:
                 f"{kv_dtype!r}")
         import jax
 
+        if not isinstance(model, gpt_lib.CausalLM):
+            raise TypeError(
+                f"PagedKVCachePool holds {{k, v}} pages of [page_size, "
+                f"heads, head_dim] and serves CausalLM only; "
+                f"{type(model).__name__} owns other cache leaves and has "
+                f"no paged form yet: use the rectangular KVCachePool "
+                f"(GenerationEngine without page_size)")
         #: page storage format — "native" (compute dtype) or "int8"
         #: (per-page affine codes + f32 scales, models/gpt.py
         #: quantize_kv_page); a pytree-shape property, so host swap,

@@ -276,6 +276,13 @@ METRIC_NAMES = {
     # profiler annotation only (no instrument): the lane loop that
     # pick_s + stream_s + retire_s split on the host clock
     "serving.sched.emit": "annotation",
+    # routed experts (models/latent_moe.py): the decode step's tokens per
+    # held expert, [layers, experts_held], fed in once a step
+    # (GenerationEngine._record_routing); only a model with experts
+    "serving.moe.assignments": "counter",
+    "serving.moe.assignments_held": "counter",
+    "serving.moe.experts_active": "histogram",
+    "serving.moe.load_max_over_mean": "histogram",
     # live rollout / canary / rollback plane (serving/rollout.py,
     # DESIGN.md §18)
     "rollout.canary.agreement": "gauge",

@@ -14,6 +14,13 @@ pool: the default layout at entry, and no ``copy`` or ``transpose`` of a
 leaf's size, or of a quarter of one, anywhere in the entry computation.
 The TPU compiler is installed with libtpu; where it cannot describe the
 topology the tests skip. Nothing here runs, and nothing here is a time.
+
+The latent-attention family (models/latent_moe.py) keeps one line a
+position of 256 + 64 values. Stored as 320 the compiler keeps the leaf
+position-minor (``{1,2,0}``) and copies the whole pool every step, the
+same trap; padded to 384 (three 128-lane tiles) the leaf is stored as
+written. Its cases compile the steps at the published widths, 2 layers,
+and hold them to the same two assertions.
 """
 
 import re
@@ -59,7 +66,7 @@ def shapes(one_chip):
         lambda: model.init(jax.random.key(0),
                            jnp.zeros((1, 8), jnp.int32))["params"]))
     pool = put(jax.eval_shape(
-        lambda: gpt_lib.init_cache(model, NUM_SLOTS + 1)))
+        lambda: model.init_cache(NUM_SLOTS + 1)))
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32,
                                               sharding=one_chip)
     return model, params, pool, i32
@@ -88,17 +95,20 @@ def _pool_layouts(text):
     return re.findall(rf"bf16\[({NUM_SLOTS + 1},[\d,]+)\]\{{([\d,]+)", header)
 
 
-def _rectangle_moves(text):
+def _rectangle_moves(text, least=QUARTER, line=None):
     """``copy``/``transpose`` instructions of the entry computation whose
-    result, in the pool's dtype, holds at least a quarter of an 8-lane
-    rectangle (the float32 logits are no part of the pool). (The
+    result, in the pool's dtype, holds at least ``least`` elements: a
+    quarter of an 8-lane rectangle (the float32 logits are no part of
+    the pool), and, given ``line``, whose last dimension is the cache
+    line's (bfloat16 weights are no part of the pool either). (The
     compiler's own ``copy-start``/``copy-done`` pairs are not counted:
     they prefetch an operand into faster memory in the layout it has.)"""
     entry = text[text.index("\nENTRY"):]
     found = []
     for m in re.finditer(
             r"= \(?bf16\[([\d,]+)\]\S* (copy|transpose)\(", entry):
-        if np.prod([int(d) for d in m.group(1).split(",")]) >= QUARTER:
+        dims = [int(d) for d in m.group(1).split(",")]
+        if np.prod(dims) >= least and line in (None, dims[-1]):
             found.append(m.group(0))
     return found
 
@@ -116,3 +126,46 @@ def test_compiled_step_keeps_the_pool_as_stored(shapes, step, size):
         default = ",".join(str(d) for d in reversed(range(rank)))
         assert layout == default, f"bf16[{dims}] is kept as {{{layout}}}"
     assert _rectangle_moves(text) == []
+
+
+# ------------------------------------------------- the latent line (PR 27)
+
+@pytest.fixture(scope="module")
+def latent_shapes(one_chip):
+    """Mistral-Small-4's widths (latent 256 + rotary 64, 32 heads, 32 of
+    128 experts held), 2 layers, a 32-slot pool of 4608 positions."""
+    from distkeras_tpu.models.latent_moe import LatentMoELM
+
+    model = LatentMoELM(
+        vocab_size=32768, max_len=4608, num_layers=2, width=4096,
+        num_heads=32, q_lora_rank=1024, kv_lora_rank=256,
+        qk_nope_head_dim=64, qk_rope_head_dim=64, v_head_dim=128,
+        moe_width=2048, num_experts=128, experts_per_token=4,
+        expert_share=(0, 4), rope_factor=128.0, position_beta=0.1)
+    put = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = put(jax.eval_shape(
+        lambda: model.init(jax.random.key(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"]))
+    pool = put(jax.eval_shape(lambda: model.init_cache(NUM_SLOTS + 1)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32,
+                                              sharding=one_chip)
+    return model, params, pool, i32
+
+
+@pytest.mark.parametrize("step,size", [("decode", 32), ("prefill", 512)])
+def test_compiled_latent_step_keeps_the_pool_as_stored(latent_shapes, step,
+                                                       size):
+    model = latent_shapes[0]
+    assert model.cache_line == 384      # 256 + 64, padded to whole tiles
+    text = _compile(latent_shapes, step, size)
+    leaves = _pool_layouts(text)
+    assert len(leaves) == 4, leaves     # 2 layers, parameters and results
+    for dims, layout in leaves:
+        assert dims == f"{NUM_SLOTS + 1},4608,384"
+        assert layout == "2,1,0", f"bf16[{dims}] is kept as {{{layout}}}"
+    # lines only: a prefill turns its own expanded keys and values round
+    # ([1, 512, 32, 128] each, the long-block form's cost) and the compiler
+    # copies bfloat16 weights into fast memory; neither is the pool
+    assert _rectangle_moves(text, least=8 * 4608 * 384 // 4, line=384) == []

@@ -23,7 +23,7 @@ import numpy as np
 import pytest
 
 from distkeras_tpu import telemetry
-from distkeras_tpu.models.gpt import cache_bytes_per_row, gpt_tiny
+from distkeras_tpu.models.gpt import gpt_tiny
 from distkeras_tpu.serving import (
     DeadlineExceeded,
     EngineClosed,
@@ -352,7 +352,7 @@ def test_kv_cache_pool_accounting(lm):
     model, _ = lm
     pool = KVCachePool(model, num_slots=3)
     assert pool.scratch_slot == 3
-    assert pool.cache_bytes == 4 * cache_bytes_per_row(model)  # 3 + scratch
+    assert pool.cache_bytes == 4 * model.cache_bytes_per_row()  # 3 + scratch
     got = [pool.allocate() for _ in range(3)]
     assert sorted(got) == [0, 1, 2]
     assert pool.allocate() is None  # exhausted, not an error
