@@ -32,6 +32,18 @@ path associates the K-reduction differently from the M>=2 gemm path.
 The ghost's query output is discarded and its cache line lands past
 the lane's length, masked until the next token overwrites it.
 
+Where the greedy token is chosen: an engine that is greedy and keeps no
+prefix cache wants an index from its decode step, not a distribution, so
+the executable it compiles per ladder entry ends in the argmax over the
+real position's float32 logits (:func:`pick_on_device` around the step
+functions below) and returns ``(pool', tokens[n] int32, *routed)``: the
+scheduler fetches one integer a lane and ``[n, V]`` never leaves the
+device. ``jnp.argmax`` and ``np.argmax`` both take the first maximum, so
+the stream is the host argmax's. ``sampling=True`` draws from a host
+float64 CDF on the request's seeded stream and a prefix cache parks
+every lane's last logits: both are fixed at construction, and their
+steps keep returning logits. Verify and prefill keep their logits too.
+
 Backpressure/deadline semantics are PR 2's, with the same typed errors:
 bounded admission queue (:class:`QueueFull`, all-or-nothing), deadlines
 checked at admission AND between decode steps (:class:`DeadlineExceeded`
@@ -90,6 +102,7 @@ in ``__init__`` — the compile cache cannot grow under any traffic mix.
 from __future__ import annotations
 
 import collections
+import functools
 import threading
 import time
 from concurrent.futures import Future
@@ -167,7 +180,11 @@ def make_decode_fn(model):
     experts it holds each was sent to; the step then returns a third
     value, int32 ``[layers, experts_held]``: tokens per held expert,
     counted over the real position of the lanes that are not padding.
-    A model without experts returns two values and pays nothing."""
+    A model without experts returns two values and pays nothing.
+
+    This is the step's contract whoever compiles it. A greedy
+    :class:`GenerationEngine` compiles it inside :func:`pick_on_device`,
+    which puts the token in the logits' place."""
     import jax
     import jax.numpy as jnp
 
@@ -225,6 +242,28 @@ def make_paged_step_fn(model):
         return new_pages, logits
 
     return step
+
+
+def pick_on_device(step):
+    """``step`` with the greedy token in the logits' place: ``(pool',
+    tokens[n] int32, *routed)`` from a decode step that returns ``(pool',
+    logits, *routed)``, the argmax taken inside the same jitted function
+    over the real position's float32 logits (``[n, V]``, or position 0
+    of the paged step's ``[n, 2, V]``). The first maximum wins, as in
+    ``np.argmax``, so a greedy stream is the host argmax's. The wrapper
+    keeps ``step``'s name: the executable is ``jit_decode`` (``jit_step``
+    for the paged one) either way."""
+    import jax.numpy as jnp
+
+    @functools.wraps(step)
+    def picked(*args):
+        pool, logits, *routed = step(*args)
+        if logits.ndim == 3:
+            logits = logits[:, 0, :]
+        return (pool, jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                *routed)
+
+    return picked
 
 
 def make_swap_out_fn():
@@ -571,6 +610,9 @@ class GenerationEngine:
                 f"temperature must be > 0, got {temperature}")
         self._seed = int(seed)
         self._req_seq = 0  # submission index: per-request stream ids
+        # a greedy engine that parks no logits wants an index from its
+        # decode step: the token is chosen inside it (pick_on_device)
+        self._device_pick = not self._sampling and not prefix_cache_bytes
         if self._paged:
             self.pool = PagedKVCachePool(
                 model, num_slots, page_size=page_size, num_pages=num_pages,
@@ -613,6 +655,8 @@ class GenerationEngine:
         self._prefills_c = telemetry.counter("serving.decode.prefills")
         self._steps_c = telemetry.counter("serving.decode.steps")
         self._tokens_c = telemetry.counter("serving.decode.tokens")
+        self._device_picks_c = telemetry.counter(
+            "serving.decode.device_picks")
         self._stream_err_c = telemetry.counter("serving.decode.stream_errors")
         self._loop_err_c = telemetry.counter("serving.decode.loop_errors")
         self._prefill_h = telemetry.histogram("serving.decode.prefill_s")
@@ -705,6 +749,7 @@ class GenerationEngine:
         self._chunk_exec = None
         self._swap_out_exec = None
         self._swap_in_exec = None
+        pick = pick_on_device if self._device_pick else (lambda fn: fn)
         if self._paged:
             step = make_paged_step_fn(self.model)
             pmax = self.pool.pages_per_slot
@@ -732,7 +777,7 @@ class GenerationEngine:
             for n in self._ladder:
                 with telemetry.span("serving.decode.compile", lanes=n):
                     self._decode_exec[n] = jax.jit(
-                        step, donate_argnums=(1,)).lower(
+                        pick(step), donate_argnums=(1,)).lower(
                             p_sds, pool_sds, i32(n, pmax), i32(n, 2),
                             i32(n)).compile()
                 compiles.inc()
@@ -761,7 +806,7 @@ class GenerationEngine:
                 compiles.inc()
             return
         prefill = make_prefill_fn(self.model)
-        decode = make_decode_fn(self.model)
+        decode = pick(make_decode_fn(self.model))
         for lb in self._buckets:
             with telemetry.span("serving.decode.compile", prefill=lb):
                 self._prefill_exec[lb] = jax.jit(
@@ -1548,6 +1593,8 @@ class GenerationEngine:
         return self.pool.page_tables[slot_ids]
 
     def _decode_group(self, active, slots, version: int) -> None:
+        import jax
+
         params = self._versions.get(version, self._params)
         n = len(slots)
         lane = self._ladder.bucket_for(n)
@@ -1556,25 +1603,31 @@ class GenerationEngine:
             slot_ids, tokens, lengths = self._group_arrays(active, slots,
                                                            lane, 2)
             tp0 = time.perf_counter()
+            # out: the lanes' tokens where the step chose them
+            # (pick_on_device), else their logits
             if self._paged:
-                new_pool, logits, *routed = self._decode_exec[lane](
+                new_pool, out, *routed = self._decode_exec[lane](
                     params, self.pool.pool, self._page_tables_for(slot_ids),
                     tokens, lengths)
             else:
-                new_pool, logits, *routed = self._decode_exec[lane](
+                new_pool, out, *routed = self._decode_exec[lane](
                     params, self.pool.pool, slot_ids, tokens[:, 0], lengths)
         with sched.phase("wait"):
-            logits.block_until_ready()  # the step lands
+            out.block_until_ready()  # the step lands
         with sched.phase("copy"):
-            logits = np.asarray(logits)  # device to host, nothing else
-            # tokens per held expert, [layers, experts_held] int32
-            routed = [np.asarray(a) for a in routed]
-        if self._paged:
-            logits = logits[:, 0, :]
+            # device to host, nothing else, in one round trip: out and
+            # the tokens per held expert, [layers, experts_held] int32
+            out, *routed = jax.device_get([out, *routed])
+        if self._device_pick:
+            picked = out.tolist()
+        else:
+            logits = out[:, 0, :] if self._paged else out
         self.pool.swap(new_pool)
         dt = time.perf_counter() - tp0
         self._steps_c.inc()
         self._tokens_c.inc(n)
+        if self._device_pick:
+            self._device_picks_c.inc(n)
         self._step_h.record(dt)
         self._padded_h.record(lane - n)
         if dt > 0:
@@ -1588,7 +1641,8 @@ class GenerationEngine:
             for i, s in enumerate(slots):
                 req = active[s]
                 self.pool.lengths[s] += 1  # the fed token is now cached
-                tok = self._pick_token(req, logits[i])
+                tok = (picked[i] if self._device_pick
+                       else self._pick_token(req, logits[i]))
                 sched.lap("pick")
                 req.generated.append(tok)
                 req.last_token = tok

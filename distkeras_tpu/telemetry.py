@@ -217,6 +217,7 @@ METRIC_NAMES = {
     "serving.decode.cache_bytes": "gauge",
     "serving.decode.compiles": "counter",
     "serving.decode.deadline_exceeded": "counter",
+    "serving.decode.device_picks": "counter",
     "serving.decode.loop_errors": "counter",
     "serving.decode.padded_lanes": "histogram",
     "serving.decode.prefill_s": "histogram",

@@ -169,3 +169,37 @@ def test_compiled_latent_step_keeps_the_pool_as_stored(latent_shapes, step,
     # ([1, 512, 32, 128] each, the long-block form's cost) and the compiler
     # copies bfloat16 weights into fast memory; neither is the pool
     assert _rectangle_moves(text, least=8 * 4608 * 384 // 4, line=384) == []
+
+
+# ------------------------------------- the greedy engine's step (PR 28)
+
+@pytest.mark.parametrize("family,line", [
+    ("gpt2_medium", None), ("mistral_small_4", 384)])
+def test_greedy_step_hands_back_tokens_and_keeps_the_pool(
+        shapes, latent_shapes, family, line):
+    """What a greedy ``GenerationEngine`` compiles per ladder entry
+    (``pick_on_device`` around ``make_decode_fn``, the pool donated), at
+    32 lanes: the executable keeps the name the trace readers look for,
+    its results hold the lanes' tokens and no ``[lanes, vocabulary]``
+    array, and the pool is still updated in place, as stored."""
+    model, params, pool, i32 = (shapes if family == "gpt2_medium"
+                                else latent_shapes)
+    n = NUM_SLOTS
+    fn = generation.pick_on_device(generation.make_decode_fn(model))
+    text = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pool, i32(n), i32(n), i32(n)).compile().as_text()
+    assert re.match(r"HloModule jit_decode[,\s]", text)
+    header = text[text.index("entry_computation_layout"):].split("\n", 1)[0]
+    results = header[header.index("->"):]
+    assert f"s32[{n}]" in results
+    assert "f32[" not in results    # no [lanes, vocabulary] logits, nor any
+    # donated: every pool leaf among the parameters is aliased to a result
+    leaves = len(jax.tree.leaves(pool))
+    aliases = text[text.index("input_output_alias"):].split("\n", 1)[0]
+    assert aliases.count("may-alias") + aliases.count("must-alias") == leaves
+    for dims, layout in _pool_layouts(text):
+        rank = dims.count(",") + 1
+        assert layout == ",".join(str(d) for d in reversed(range(rank)))
+    assert len(_pool_layouts(text)) == 2 * leaves
+    least = QUARTER if line is None else 8 * 4608 * 384 // 4
+    assert _rectangle_moves(text, least=least, line=line) == []
