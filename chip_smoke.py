@@ -192,7 +192,7 @@ def leg_train():
     assert moved > 0.0, "parameters did not change"
 
     # One more device call of the compiled epoch function, timed under
-    # both completion barriers (bench.py fetches a scalar because
+    # both completion barriers (a scalar fetch, because
     # block_until_ready returned early on an earlier installation). The
     # third number is what a fetch still costs once block_until_ready has
     # returned: near zero if block_until_ready really waits.
@@ -382,8 +382,8 @@ def leg_kernels():
         assert err <= tol, f"{name}: error {err:.2e} over tolerance {tol:.2e}"
         notes.append(f"{name} {err:.1e}")
 
-    # training flash attention, forward and backward, at the step_probe GPT
-    # row's shape (sequence 2048, 12 heads of 64, bf16); batch cut to 2
+    # training flash attention, forward and backward, at a GPT-small
+    # shape (sequence 2048, 12 heads of 64, bf16); batch cut to 2
     # because the XLA reference materializes [b, h, T, T] f32 logits
     q, k, v, w = (normal((2, 2048, 12, 64), jnp.bfloat16) for _ in range(4))
     assert fa.fits(q.shape)

@@ -36,7 +36,7 @@ __all__ = [
 ]
 
 # Directories (relative to repo root) whose .py files enter the scan set.
-DEFAULT_SCAN_ROOTS = ("distkeras_tpu", "benchmarks", "tests")
+DEFAULT_SCAN_ROOTS = ("distkeras_tpu", "tests")
 
 # Path fragments excluded from every checker: the lint suite itself (its
 # config embeds metric/op names as data) and its fixture-bearing tests

@@ -251,7 +251,7 @@ class JitPurityChecker(Checker):
     name = "jit-purity"
     rules = ("jit-host-effect", "jit-closure-mutation", "jit-tracer-branch")
 
-    SCOPE = ("distkeras_tpu/", "benchmarks/")
+    SCOPE = ("distkeras_tpu/",)
 
     def check(self, modules: List[ModuleInfo]) -> List[Finding]:
         out: List[Finding] = []

@@ -44,19 +44,7 @@ _METRIC_SHAPE = re.compile(r"[a-z][a-z0-9_]*(\.[a-z0-9_]+)+")
 
 # consumers scanned for dangling metric references (besides tests/)
 _CONSUMER_PATHS = (
-    "benchmarks/telemetry_summary.py",
-    "benchmarks/health_probe.py",
-    "benchmarks/attribution.py",
-    "benchmarks/regression_gate.py",
-    "benchmarks/rollout_probe.py",
-    "benchmarks/decode_bench.py",
-    "benchmarks/paged_memory_probe.py",
-    "benchmarks/data_probe.py",
-    "benchmarks/roofline_probe.py",
-    "benchmarks/fleet_probe.py",
-    "benchmarks/kernel_ablate.py",
-    "benchmarks/step_probe.py",
-    "benchmarks/soak.py",
+    "distkeras_tpu/health/summary.py",
     "distkeras_tpu/profiling/cost_model.py",
     "distkeras_tpu/profiling/roofline.py",
     "distkeras_tpu/profiling/capture.py",
@@ -154,7 +142,7 @@ class TelemetryRegistryChecker(Checker):
     rules = ("telemetry-undeclared-name", "telemetry-kind-mismatch",
              "telemetry-unknown-consumer-name")
 
-    PRODUCER_SCOPE = ("distkeras_tpu/", "benchmarks/")
+    PRODUCER_SCOPE = ("distkeras_tpu/",)
 
     def check(self, modules: List[ModuleInfo]) -> List[Finding]:
         if not any(m.relpath == _TELEMETRY_MODULE for m in modules):

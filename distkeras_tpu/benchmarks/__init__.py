@@ -1,9 +1,6 @@
-"""Benchmark harness for the five BASELINE.md configs.
+"""The reference system's second metric: staleness against wall time.
 
-Run with ``python -m distkeras_tpu.benchmarks <1-5|all> [--full]`` or the
-``distkeras-tpu-bench`` console script.
+``python -m distkeras_tpu.benchmarks.staleness_tradeoff`` sweeps strategy x
+window x workers; it measures no chip. What measures the chip is ``perf/``
+(``BENCHMARK.json``).
 """
-
-from distkeras_tpu.benchmarks.run_config import CONFIGS, main
-
-__all__ = ["CONFIGS", "main"]

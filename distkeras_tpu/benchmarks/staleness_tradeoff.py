@@ -1,8 +1,8 @@
 """Staleness vs wall-clock trade-off benchmark — the async zoo's raison d'être.
 
-BASELINE.md names TWO halves of the primary metric: samples/sec/chip (served
-by bench.py / run_config) and **"async staleness vs wall-clock"** — the curve
-that justifies choosing a communication window and an async mode at all.
+BASELINE.md names TWO halves of the primary metric: samples/sec/chip (the
+train cell of BENCHMARK.json) and **"async staleness vs wall-clock"** — the
+curve that justifies choosing a communication window and an async mode at all.
 This harness serves the second half (VERDICT r4 ask #1): it sweeps
 
     strategy x communication_window x num_workers x {sync, host_async}
@@ -21,8 +21,8 @@ and reports, per point,
 Reference parity note: dist-keras could only ever observe this trade-off as
 an accident of TCP timing; here both the deterministic emulation and the
 live-center mode measure it on purpose (SURVEY.md §5 race/staleness
-testing). Run ``python -m distkeras_tpu.benchmarks.staleness_tradeoff`` on
-the TPU for the committed artifact (STALENESS_r*.json at repo root).
+testing). Run ``python -m distkeras_tpu.benchmarks.staleness_tradeoff``;
+it writes one JSON document (``--out``); none is committed.
 """
 
 from __future__ import annotations
@@ -59,7 +59,7 @@ def _strategy_for(name: str, learning_rate: float, rho: float,
 
 def _fetch_sync(tree) -> float:
     """Completion barrier via an actual device->host fetch of one scalar
-    (the same barrier bench.py uses)."""
+    (on this installation it agrees with ``block_until_ready``)."""
     return float(np.asarray(jax.tree.leaves(tree)[0]).ravel()[0])
 
 

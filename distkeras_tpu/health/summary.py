@@ -1,17 +1,15 @@
 """Render a telemetry JSONL artifact into the staleness/latency tables.
 
-Sibling of trace_summary.py: that tool digests the *compute*-side Chrome
-trace; this one digests the *system*-side artifact the telemetry layer
-leaves next to BENCH_*.json (``Trainer(telemetry_path=...)`` or
-``trainer.dump_telemetry(path)``). The headline sections — per-commit
-staleness distribution, PS commit/pull counts, per-worker window
-durations, prefetch queue occupancy — are exactly what a STALENESS_r*
-round cites.
+The operator's tool for the *system*-side artifact every trainer writes
+(``Trainer(telemetry_path=...)`` or ``trainer.dump_telemetry(path)``); the
+*compute*-side profiler trace is reduced by ``perf/trace_reduce.py``. The
+headline sections: per-commit staleness distribution, PS commit/pull
+counts, per-worker window durations, prefetch queue occupancy.
 
 Usage:
-  python benchmarks/telemetry_summary.py <run.telemetry.jsonl> [--top N]
-  python benchmarks/telemetry_summary.py <run.telemetry.jsonl> --format prom
-  python benchmarks/telemetry_summary.py <p0.jsonl> <p1.jsonl> ... --merge
+  python -m distkeras_tpu.health.summary <run.telemetry.jsonl> [--top N]
+  python -m distkeras_tpu.health.summary <run.telemetry.jsonl> --format prom
+  python -m distkeras_tpu.health.summary <p0.jsonl> <p1.jsonl> ... --merge
 
 ``--format prom`` renders the artifact in the Prometheus text exposition
 format instead of the human tables (same exporter as the live
@@ -35,12 +33,6 @@ import argparse
 import collections
 import os
 import sys
-
-try:
-    import distkeras_tpu  # noqa: F401  (pip-installed)
-except ImportError:  # running from a source checkout: use the repo root
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
 
 
 def load_rows(path: str) -> list:

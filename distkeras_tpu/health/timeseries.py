@@ -1,8 +1,7 @@
 """Time-series metrics plane: bounded history + trend detection (§24).
 
 Every observability layer so far judges an instant (``SloEngine`` reads
-the live registry, ``watch`` polls a snapshot) or a committed artifact
-(``regression_gate``). Nothing can see a slow HBM leak, a creeping queue
+the live registry, ``watch`` polls a snapshot). Nothing can see a slow HBM leak, a creeping queue
 depth, or a stalled watermark *over time* — which is exactly how
 hours-scale runs die. This module adds the time dimension:
 

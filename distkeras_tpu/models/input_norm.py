@@ -4,7 +4,7 @@ The staged-data contract: image trainers stage RAW uint8 bytes (4x fewer
 host->device and HBM bytes than f32) and the model normalizes on device as
 ``(x - 127.5) / 58`` — approximately (x - mean) / std for natural images,
 fused by XLA into the stem conv. One definition, used by ResNet, the CIFAR
-CNN, and ViT, so the magic constants (which README, tests, and benchmarks
+CNN, and ViT, so the magic constants (which README and tests
 all rely on) cannot drift apart between models.
 """
 

@@ -325,8 +325,7 @@ def resnet50_nf(**kw) -> ResNet:
     """The ≥50%-MFU flagship recipe: norm-free ResNet-50 (Scaled Weight
     Standardization instead of GroupNorm) + on-device uint8 normalization.
 
-    This is exactly what bench.py runs: 54.3% MFU / ~3790 samples/s/chip on
-    a v5e at batch 128, vs ~36% for the GN default — the round-3 profile
+    ``chip_smoke.py``'s train leg runs it at batch 128. The round-3 profile
     (DESIGN.md §4b) showed the GN step is HBM-bandwidth-bound on activation
     norm traffic, which the NF parameterization removes entirely. Stage
     uint8 images (the model normalizes on device, 4x fewer staged bytes)

@@ -1,15 +1,15 @@
 """Pallas TPU kernels for hot ops the XLA autofuser leaves on the table.
 
 Every kernel here follows the groupnorm lesson (DESIGN.md §6): shape
-`fits()` predicates, interpret-mode parity tests on CPU, an ablation gate
-(`benchmarks/kernel_ablate.py`) that must show a real-TPU win, and —
-for the newer kernels — a default-OFF module flag until that win lands.
+`fits()` predicates, interpret-mode parity tests on CPU, and — for the
+newer kernels — a default-OFF module flag until a cell of `BENCHMARK.json`
+measured on the chip (parent against change) shows the kernel winning.
 
 :func:`kernel_registry` is the join point for the roofline report's
 ``fix_available`` column (profiling/roofline.py): it maps roofline fix
 tags to the in-tree kernel behind them and whether its flag is on, so
-``attribution.py --ops`` can say "a fix for this op EXISTS in-tree but is
-disabled" instead of only naming the tag.
+the report can say "a fix for this op EXISTS in-tree but is disabled"
+instead of only naming the tag.
 """
 
 from __future__ import annotations

@@ -29,9 +29,9 @@ blocks while per-query-block statistics live in VMEM scratch:
 
 DEFAULT OFF (``USE_FLASH_ATTENTION = False``), the groupnorm lesson
 (DESIGN.md §6): a custom call is a fusion FENCE to XLA, and this kernel
-must beat the XLA attention in its OWN ablation
-(``benchmarks/kernel_ablate.py --kernel flash_attention``) on real
-hardware before the default flips. Until then ``attention="flash"``
+must beat the XLA attention end to end in a cell of ``BENCHMARK.json``
+(``perf/run.py``, parent against change on the chip) before the default
+flips. Until then ``attention="flash"``
 reaches the upstream pallas kernel and the paged branch takes the XLA
 gather. Tests force the kernels through ``interpret=True`` on CPU
 (forward/backward ulp-parity for the training kernel; bitwise parity for
@@ -57,9 +57,9 @@ import numpy as np
 
 from distkeras_tpu.ops.attention import MASK_VALUE
 
-#: flip only when benchmarks/kernel_ablate.py --kernel flash_attention
-#: shows the fused kernel beating the XLA attention on the target TPU
-#: generation (default-off per the groupnorm precedent)
+#: flip only when a cell of BENCHMARK.json shows the fused kernel beating
+#: the XLA attention on the target TPU generation (default-off per the
+#: groupnorm precedent)
 USE_FLASH_ATTENTION = False
 
 #: test hook: dispatch the PAGED kernel in interpret mode off-TPU so the

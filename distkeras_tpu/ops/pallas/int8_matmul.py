@@ -11,9 +11,9 @@ store — one HBM round-trip instead of two.
 DEFAULT OFF (``USE_FUSED_INT8_MATMUL = False``), the groupnorm lesson:
 a custom call is an optimization FENCE to XLA's fusion pass, and the
 groupnorm kernel that ignored that cost the flagship 14 MFU points.
-This kernel must beat the pure-XLA int8 fallback in its OWN ablation
-(``benchmarks/int8_matmul_ablate.py``) on real hardware before a BENCH
-round flips the default. Until then `precision.py` selects the XLA
+This kernel must beat the pure-XLA int8 fallback end to end in a cell of
+``BENCHMARK.json`` (``perf/run.py``, parent against change on the chip)
+before the default flips. Until then `precision.py` selects the XLA
 fallback at trace time.
 
 Tiling (see /opt/skills/guides: int8 min tile is (32, 128); MXU is
@@ -31,9 +31,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-#: flip only when benchmarks/int8_matmul_ablate.py shows the fused kernel
-#: beating the XLA int8 dot on the target TPU generation (default-off per
-#: the groupnorm precedent — see module docstring)
+#: flip only when a cell of BENCHMARK.json shows the fused kernel beating
+#: the XLA int8 dot on the target TPU generation (default-off per the
+#: groupnorm precedent — see module docstring)
 USE_FUSED_INT8_MATMUL = False
 
 #: block shape: multiples of the int8 min tile (32, 128); 256x256x256

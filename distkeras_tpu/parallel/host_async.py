@@ -191,7 +191,7 @@ class HostAsyncRunner:
         # one trace whose spans follow the commit through the transport
         # (retries, reconnects) and across shard folds. trace=False keeps
         # the plain (context-free) span events — the tracing-off baseline
-        # benchmarks/attribution.py measures overhead against.
+        # (test_tracing.py's trajectory test runs both).
         self.trace = bool(trace)
         # merged multi-process rows from the last run_cross_process (set
         # on process 0 when the coordinator mounts a collector)
@@ -564,7 +564,7 @@ class HostAsyncRunner:
         last_center = None  # last successfully pulled (center, clock)
         # step-time decomposition (DESIGN.md §15): the top-level phases
         # data_wait/pull/h2d/compute/commit/bookkeep PARTITION each window
-        # (attribution.py asserts they sum to >=95% of window wall-time);
+        # (the flight recorder's window_profile events carry the same six);
         # encode/decode/fold land as nested sub-phases from the codec/PS
         prof = {name: telemetry.histogram(f"profile.phase.{name}_s",
                                           worker=wid)

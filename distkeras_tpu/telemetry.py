@@ -320,7 +320,7 @@ METRIC_NAMES = {
     "timeseries.series": "gauge",
     "timeseries.trend_breaches": "counter",
     "timeseries.trends_active": "gauge",
-    # chaos soak harness (benchmarks/soak.py): wall-clock-budgeted
+    # chaos soak harness (tests/soak_harness.py): wall-clock-budgeted
     # whole-loop run under a seeded kill schedule
     "soak.cycles": "counter",
     "soak.elapsed_s": "gauge",
@@ -337,11 +337,11 @@ METRIC_NAMES = {
     "collector.dropped_rows": "counter",
     "collector.processes": "gauge",
     "collector.rows": "counter",
-    # step-time decomposition (DESIGN.md §15): the canonical phase
-    # vocabulary attribution.py renders. Also covered by the
-    # "profile.phase." family so per-worker variants stay legal.
+    # step-time decomposition (DESIGN.md §15): the phases that partition
+    # a host_async window, and the codec/PS sub-phases nested in them. Also
+    # covered by the "profile.phase." family so per-worker variants stay
+    # legal.
     "profile.phase.bookkeep_s": "histogram",
-    "profile.phase.collective_s": "histogram",
     "profile.phase.commit_s": "histogram",
     "profile.phase.compute_s": "histogram",
     "profile.phase.data_wait_s": "histogram",
@@ -359,9 +359,6 @@ METRIC_NAMES = {
     "profile.op.coverage": "gauge",
     "profile.op.inventory_unavailable": "counter",
     "profile.op.share": "gauge",
-    # attention group's share of modeled step time, baseline-vs-kernel
-    # (regression_gate --check roofline, ISSUE 18)
-    "profile.op.attention_share": "gauge",
     # span names (the `with span("..."):` vocabulary; each also emits a
     # `span.<name>.duration_s` histogram via the prefix family below)
     "serving.compile": "span",
@@ -403,7 +400,7 @@ METRIC_PREFIXES = {
     "observability.hbm_": "gauge",
     # distributed-trace span names (DESIGN.md §15)
     "trace.": "span",
-    # step-time decomposition phases (benchmarks/attribution.py)
+    # step-time decomposition phases (parallel/host_async.py)
     "profile.phase.": "histogram",
     # op-level roofline shares (profiling/roofline.py), labeled per op
     "profile.op.": "gauge",

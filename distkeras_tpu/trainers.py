@@ -206,7 +206,7 @@ class Trainer:
         return reg.snapshot() if reg is not None else {}
 
     def dump_telemetry(self, path: str) -> Optional[str]:
-        """Write the JSONL artifact (``benchmarks/telemetry_summary.py``
+        """Write the JSONL artifact (``python -m distkeras_tpu.health.summary``
         renders it); returns the path, or None when uninstalled."""
         reg = telemetry.get_registry()
         return reg.dump_jsonl(path) if reg is not None else None

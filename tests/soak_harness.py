@@ -36,8 +36,9 @@ weight version through :class:`WeightPublisher` → fleet-wide
 a :class:`TrendMonitor` + :class:`SloEngine` judge it continuously —
 leaks, stalls and drift are failures even when every request succeeded.
 
-The three flywheel invariants (summary row, gated by
-``regression_gate.py --check soak``): **zero lost windows**, **zero
+The three flywheel invariants (summary row, asserted by
+``test_timeseries.py::test_soak_smoke_all_authorities_and_invariants``
+and by ``main()``'s exit code): **zero lost windows**, **zero
 failed requests** (token-exactness counts as success), **strictly
 monotone model_version** across every published cycle. After the soak, a
 deliberate HBM-leak drill injects a synthetic monotone series, requires
@@ -46,8 +47,8 @@ into a flight-recorder postmortem bundle — proving the forensic path,
 not just the happy path.
 
 Usage:
-  python benchmarks/soak.py [--budget-s 120] [--seed 0]
-      [--out benchmarks/results/pr19_soak.jsonl]
+  python tests/soak_harness.py [--budget-s 120] [--seed 0]
+      [--out soak.jsonl]
       [--workers 2] [--shards 2] [--replicas 3]
 
 CPU-safe (MNIST MLP trainer + gpt_tiny serving over loopback TCP).
@@ -81,7 +82,7 @@ DATA_ROWS = 112
 DATA_RANGE = 16
 
 
-# -- shared model stack (fleet_probe's recipe) --------------------------------
+# -- shared model stack --------------------------------------------------------
 
 def _setup():
     import jax
@@ -301,7 +302,7 @@ def _data_leg(seed, kill):
 def _serve_leg(fleet, prompts, want, new_tokens, kill, rng):
     """One prompt burst through the router, token-exact against the local
     greedy reference. ``kill=True``: concurrent storm with a mid-storm
-    replica kill (fleet_probe's recipe), then replenish the pool."""
+    replica kill, then replenish the pool."""
     total = failed = wrong = 0
 
     def score(p, res):
@@ -401,7 +402,7 @@ def _leak_drill(out_dir):
 
 def run_soak(budget_s=120.0, seed=0, workers=2, shards=2, replicas=3,
              window=4, batch=16, train_rows=1024, lease_s=0.3,
-             num_prompts=4, new_tokens=4, out_dir="benchmarks/results"):
+             num_prompts=4, new_tokens=4, out_dir="."):
     from distkeras_tpu import telemetry
     from distkeras_tpu.health import recorder, slo, timeseries
     from distkeras_tpu.serving.rollout import WeightPublisher
@@ -583,9 +584,7 @@ def main(argv=None):
     ap.add_argument("--train-rows", type=int, default=1024)
     ap.add_argument("--prompts", type=int, default=4)
     ap.add_argument("--new-tokens", type=int, default=4)
-    ap.add_argument("--out", default="benchmarks/results/pr19_soak.jsonl",
-                    help="report JSONL (judged by regression_gate.py "
-                         "--check soak)")
+    ap.add_argument("--out", default="soak.jsonl", help="report JSONL")
     args = ap.parse_args(argv)
 
     rows, summary = run_soak(
@@ -611,8 +610,7 @@ def main(argv=None):
           f" monotone={summary['version_monotone']:.0f}"
           f" leak_drill={summary['leak_drill_caught']:.0f}")
 
-    # the soak asserts the contracts it measures — committed evidence
-    # from a run that violated them would be worse than no evidence
+    # the soak asserts the contracts it measures
     ok = True
     if summary["zero_lost_windows"] < 1.0:
         print(f"FAIL: lost {summary['windows_lost']} window(s) / "
@@ -635,7 +633,7 @@ def main(argv=None):
         ok = False
     if summary["trend_breaches"]:
         # surfaced, not fatal: a trend breach during chaos is signal the
-        # observatory works; the committed-evidence gate reads the row
+        # observatory works
         print(f"note: {len(summary['trend_breaches'])} trend breach(es) "
               f"during the soak: "
               f"{[b['trend'] for b in summary['trend_breaches']]}")

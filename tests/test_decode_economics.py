@@ -3,9 +3,9 @@
 Three compounding accelerations, each pinned to the same exactness
 standard the serving stack already carries:
 
-- **chunked prefill** is BITWISE-equal to one-shot prefill at every
-  chunk boundary (the §14 fixed-contraction-length masked-softmax
-  argument covers mid-sequence positions), the engine's chunked path is
+- **chunked prefill** equals one-shot prefill, and the full forward, at
+  the decode-step tolerance (test_generation.TOL) at every chunk
+  boundary and mid-sequence position, the engine's chunked path is
   token-identical to the unchunked engine, and the chunk executable is
   declared up front — the compile cache still never grows under
   traffic;
@@ -44,6 +44,7 @@ from distkeras_tpu.serving import (
 from distkeras_tpu.serving.generation import make_paged_step_fn
 from distkeras_tpu import precision
 from distkeras_tpu.utils import fault
+from test_generation import TOL, _prompt
 
 
 @pytest.fixture(autouse=True)
@@ -63,11 +64,6 @@ def lm():
     return model, params
 
 
-def _prompt(n, seed=0):
-    return np.random.default_rng(seed).integers(1, 256, size=n,
-                                                dtype=np.int64).tolist()
-
-
 def _tokens(eng, prompts, max_new=16, timeout=120):
     futs = [eng.generate(p, max_new_tokens=max_new) for p in prompts]
     return [f.result(timeout=timeout).tokens.tolist() for f in futs]
@@ -78,11 +74,11 @@ def _tokens(eng, prompts, max_new=16, timeout=120):
 # ---------------------------------------------------------------------------
 
 
-def test_chunked_prefill_bitwise_parity_at_every_boundary(lm):
+def test_chunked_prefill_parity_at_every_boundary(lm):
     """Feeding a 29-token prompt in 8-token chunks through the paged
-    step family yields logits BITWISE-equal to the one-shot bucket-32
-    prefill at every covered position — including the mid-sequence
-    chunk starts at 8, 16, 24."""
+    step family yields the one-shot bucket-32 prefill's logits, and the
+    full forward's, at the decode-step tolerance at every covered
+    position — including the mid-sequence chunk starts at 8, 16, 24."""
     model, params = lm
     step = jax.jit(make_paged_step_fn(model), donate_argnums=(1,))
     seq = _prompt(29, seed=5)
@@ -107,7 +103,11 @@ def test_chunked_prefill_bitwise_parity_at_every_boundary(lm):
 
     one_shot = run([32])[:29]
     chunked = run([chunk] * 4)[:29]
-    np.testing.assert_array_equal(chunked, one_shot)
+    pad = np.zeros((1, model.max_len), np.int32)
+    pad[0, :29] = seq
+    full = np.asarray(model.apply({"params": params}, pad))[0, :29]
+    np.testing.assert_allclose(chunked, one_shot, **TOL)
+    np.testing.assert_allclose(chunked, full, **TOL)
 
 
 def test_chunked_engine_token_identical_and_cache_fixed(lm):
@@ -251,18 +251,42 @@ def test_int8_prefix_hit_roundtrip_token_identical(lm):
 
 
 def test_int8_decode_close_to_native(lm):
-    """int8 KV is lossy by design, but on gpt_tiny the 10-token greedy
-    continuation matches native — the bound is tight enough that argmax
-    never flips on this model."""
+    """int8 KV is lossy by design: a cell is off by at most ``scale / 2``,
+    one half-step of a 254-level grid over its page's range. Step by step
+    the int8 pool's logits stay within eight such half-steps of the native
+    pool's (relative to the largest native logit), both pools fed the
+    NATIVE greedy stream so that one argmax flip cannot cascade, and the
+    greedy token is the native one wherever the native top-two gap exceeds
+    twice that bound."""
     model, params = lm
-    prompts = [_prompt(20, seed=6), _prompt(13, seed=8)]
-    with GenerationEngine(model, params, num_slots=2,
-                          page_size=16) as eng:
-        want = _tokens(eng, prompts, max_new=10)
-    with GenerationEngine(model, params, num_slots=2, page_size=16,
-                          kv_dtype="int8") as eng:
-        got = _tokens(eng, prompts, max_new=10)
-    assert got == want
+    step = jax.jit(make_paged_step_fn(model))
+
+    def feed(pool, ids, length):
+        pts = pool.page_table_row(0)[None, :]
+        new_pool, logits = step(params, pool.pool, pts, ids,
+                                np.array([length], np.int32))
+        pool.swap(new_pool)
+        return np.asarray(logits)[0]
+
+    for seq in (_prompt(20, seed=6), _prompt(13, seed=8)):
+        native, quant = (PagedKVCachePool(model, num_slots=1, page_size=16,
+                                          kv_dtype=kv)
+                         for kv in (None, "int8"))
+        for pool in (native, quant):
+            assert pool.reserve(pool.allocate(), model.max_len)
+        ids = np.zeros((1, 32), np.int32)
+        ids[0, :len(seq)] = seq
+        want = feed(native, ids, 0)[len(seq) - 1]
+        got = feed(quant, ids, 0)[len(seq) - 1]
+        for length in range(len(seq), len(seq) + 10):
+            bound = 8 * np.max(np.abs(want)) / KV_QUANT_LEVELS
+            np.testing.assert_allclose(got, want, rtol=0, atol=bound)
+            top = np.sort(want)[-2:]
+            if top[1] - top[0] > 2 * bound:
+                assert np.argmax(got) == np.argmax(want)
+            tok = np.array([[np.argmax(want), 0]], np.int32)  # + ghost
+            want = feed(native, tok, length)[0]
+            got = feed(quant, tok, length)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -309,7 +333,7 @@ def test_sampled_spec_stream_identical_model_draft(lm):
 def test_sampled_paged_chunked_spec_composition(lm):
     """Paged + chunked prefill + sampling + spec (native KV) emits the
     same stream as the identically configured engine without spec —
-    chunking is bitwise and the accept coupling is exact, so the
+    both engines chunk alike and the accept coupling is exact, so the
     identity receipt survives the composition."""
     model, params = lm
     prompts = [_prompt(21, seed=70), _prompt(9, seed=71)]

@@ -394,10 +394,10 @@ def test_churn_run_survives_resets_eviction_and_outage():
     services = make_ps_fleet(
         lambda part: DynSGDParameterServer(jax.device_put(part)),
         params, 2, lease_s=0.05)
+    retry = RetryPolicy(max_retries=2, base_s=0.01, max_s=0.05)
     fleet = ShardedRemoteParameterServer(
         [f"127.0.0.1:{svc.port}" for svc in services], params,
-        retry=RetryPolicy(max_retries=2, base_s=0.01, max_s=0.05),
-        op_timeout=2.0)
+        retry=retry, op_timeout=2.0)
     try:
         # (a) reply-loss resets while the run is in flight
         fault.inject_chaos("remote_ps.send", "reset_after_send",
@@ -422,20 +422,35 @@ def test_churn_run_survives_resets_eviction_and_outage():
         assert fleet.num_updates == before + 1
         assert _counter("remote_ps.server.dedup_hits") >= 1
 
-        # (c) full outage mid-run: every send resets until a timer lifts
-        # it; workers degrade to compute-only windows, then fold the
-        # backlog and finish the epoch
-        def lift():
-            time.sleep(0.6)
-            fault.clear_chaos()
+        # (c) full outage mid-run: once every worker holds a center,
+        # every send resets for a counted stretch; workers degrade to
+        # compute-only windows, then fold the backlog and finish the
+        # epoch. The stretch is counted in sends and not in seconds, so
+        # what the run survives does not depend on the clock: an
+        # operation dies after `attempts` resets, a worker must attempt a
+        # commit after each pull (2 shards x attempts resets), so more
+        # resets than every worker's pull can absorb kill at least one
+        # commit, and at most outage / attempts operations die in all:
+        # fewer than the ladder's max_degraded_windows=8
+        attempts = retry.max_retries + 1
+        outage = len(staged) * len(services) * attempts + attempts
+        assert outage // attempts < 8
+        start_clock = fleet.num_updates
+        pulled, lock, real_pull = set(), threading.Lock(), fleet.pull
 
-        fault.inject_chaos("remote_ps.send", "reset", after=4,
-                           count=None)
-        lifter = threading.Thread(target=lift, daemon=True)
-        lifter.start()
-        runner.run(params, [staged], ps=fleet,
-                   start_clock=fleet.num_updates)
-        lifter.join()
+        def pull_then_outage():
+            out = real_pull()
+            with lock:
+                pulled.add(threading.get_ident())
+                if len(pulled) == len(staged):  # the last first pull
+                    pulled.add(None)            # arm once
+                    fault.inject_chaos("remote_ps.send", "reset",
+                                       count=outage)
+            return out
+
+        fleet.pull = pull_then_outage
+        runner.run(params, [staged], ps=fleet, start_clock=start_clock)
+        fleet.pull = real_pull
         assert _counter("host_async.degraded_windows") >= 1
         # the fleet recovered: it answers, and the run's windows all
         # reached the merged history despite the outage

@@ -10,11 +10,13 @@ parity is pinned where CI actually runs:
   ``apply_attention("flash")`` raising (never substituting XLA) off-TPU;
 - remat composition: ``jax.checkpoint`` over the custom_vjp recomputes
   to identical gradients;
-- paged decode kernel: BITWISE-equal logits through the full gpt decode
-  path against tests/test_paged_generation.py's oracle (the full-prefix
-  forward), with the kernel genuinely dispatched (spied) and the dense
-  ``[max_len]`` view never materialized (it reads ``pages[page_table]``
-  inside the kernel grid).
+- paged decode kernel: BITWISE vs the dense-gather reference (the same
+  math, so equality is the claim), and through the full gpt decode path
+  equal to tests/test_paged_generation.py's oracle (the full-prefix
+  forward) at the decode-step tolerance (test_generation.TOL), with the
+  kernel genuinely dispatched (spied) and the dense ``[max_len]`` view
+  never materialized (it reads ``pages[page_table]`` inside the kernel
+  grid).
 
 The compiled (non-interpret) kernels are checked against their XLA
 references on the chip by ``chip_smoke.py``'s kernels leg.
@@ -27,6 +29,7 @@ import pytest
 
 from distkeras_tpu.ops import attention as attn
 from distkeras_tpu.ops.pallas import flash_attention as fa
+from test_generation import TOL
 
 
 def _qkv(b=2, t=256, h=2, d=32, dtype=jnp.float32, seed=0):
@@ -227,13 +230,13 @@ def test_paged_fits_counts_padded_tiles():
     assert not fa.paged_fits(q_shape, pages, table, jnp.float32)
 
 
-def test_gpt_decode_through_paged_kernel_bitwise(monkeypatch):
+def test_gpt_decode_through_paged_kernel_equals_full_forward(monkeypatch):
     """The acceptance oracle: the SAME harness as test_paged_generation's
-    bitwise test, but with the paged kernel forced into the dispatch
-    (PAGED_INTERPRET) and spied on — every decode step's logits stay
-    bitwise-equal to the padded full-prefix forward while the attention
-    contraction runs inside the kernel, pages indexed by page_table with
-    no dense [max_len] gather in the traced program."""
+    every-position test, but with the paged kernel forced into the
+    dispatch (PAGED_INTERPRET) and spied on — every decode step's logits
+    equal the padded full-prefix forward's at the decode-step tolerance
+    while the attention contraction runs inside the kernel, pages indexed
+    by page_table with no dense [max_len] gather in the traced program."""
     from distkeras_tpu.models.gpt import gpt_tiny
     from distkeras_tpu.serving import PagedKVCachePool
     from distkeras_tpu.serving.generation import make_paged_step_fn
@@ -270,7 +273,7 @@ def test_gpt_decode_through_paged_kernel_bitwise(monkeypatch):
                             np.zeros(1, np.int32))
     pool.swap(new_pool)
     pool.lengths[a] = 5
-    np.testing.assert_array_equal(np.asarray(logits)[0, 4], ref(seq))
+    np.testing.assert_allclose(np.asarray(logits)[0, 4], ref(seq), **TOL)
     tok = int(np.argmax(np.asarray(logits)[0, 4]))
     for _ in range(24):
         feed = np.array([[tok, 0]], np.int32)  # token + ghost
@@ -280,7 +283,7 @@ def test_gpt_decode_through_paged_kernel_bitwise(monkeypatch):
         pool.lengths[a] += 1
         seq.append(tok)
         row = np.asarray(logits)[0, 0]
-        np.testing.assert_array_equal(row, ref(seq))
+        np.testing.assert_allclose(row, ref(seq), **TOL)
         tok = int(np.argmax(row))
     assert calls, "paged kernel never dispatched — oracle ran the fallback"
 

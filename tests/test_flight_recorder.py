@@ -1,4 +1,4 @@
-"""Flight recorder + SLO engine + regression sentinel (DESIGN.md §16).
+"""Flight recorder + SLO engine (DESIGN.md §16).
 
 Unit layers: the bounded forensic ring and its atomic postmortem bundles,
 the cross-process merge, the declarative SLO engine (breach/recovery/
@@ -8,14 +8,11 @@ burn-rate), the watchdog's SloBreach policy-ladder seam, and the CLI
 Integration (the ISSUE acceptance): a fault-injected NaN and a
 chaos-injected terminal ``PSUnavailable`` each leave a postmortem bundle
 whose merged timeline carries the trailing windows' phase profiles and
-the breaching alert; the regression gate flags the committed r03→r05 MFU
-plateau and passes a synthetic +5% run.
+the breaching alert.
 """
 
-import importlib.util
 import json
 import os
-import sys
 import threading
 import time
 
@@ -31,7 +28,6 @@ from distkeras_tpu.health.slo import AlertEvent, SloEngine, SloSpec
 from distkeras_tpu.health.watchdog import SloBreach, TrainingWatchdog
 from distkeras_tpu.utils import fault
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -569,121 +565,3 @@ def test_ps_outage_leaves_postmortem_with_profiles(tmp_path):
     wires = [e for e in merged["events"] if e["kind"] == "wire"]
     assert any(e["fields"]["outcome"] == "unavailable" for e in wires)
     assert any(e["kind"] == "degraded_window" for e in merged["events"])
-
-
-# -- regression sentinel -----------------------------------------------------
-
-def _load_gate():
-    spec = importlib.util.spec_from_file_location(
-        "regression_gate",
-        os.path.join(REPO, "benchmarks", "regression_gate.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-#: a synthetic five-release ladder with the shape the gate was built to
-#: catch: a jump (r2 -> r3), then a plateau (r3 -> r5: +0.79% MFU, under
-#: the 1% improvement budget). Same ``parsed`` layout bench.py prints.
-_LADDER = {1: (2413.02, 0.3458), 2: (2377.71, 0.3407), 3: (3789.72, 0.5431),
-           4: (3824.0, 0.548), 5: (3820.33, 0.5474)}
-
-
-def _write_ladder(repo_dir):
-    for n, (value, mfu) in _LADDER.items():
-        with open(os.path.join(repo_dir, f"BENCH_r{n:02d}.json"), "w") as f:
-            json.dump({"n": n, "rc": 0, "parsed": {
-                "metric": "resnet50_adag_samples_per_sec_per_chip",
-                "value": value, "unit": "samples/sec/chip", "mfu": mfu}}, f)
-    return str(repo_dir)
-
-
-def test_gate_flags_an_mfu_plateau(tmp_path):
-    """Against a BENCH_r*.json ladder whose r03→r05 MFU move (+0.79%) is
-    below the 1% improvement budget, the verdict says plateau."""
-    gate = _load_gate()
-    out = str(tmp_path / "verdicts.jsonl")
-    rc = gate.main(["--check", "history", "--out", out,
-                    "--repo-dir", _write_ladder(tmp_path)])
-    assert rc == 1
-    verdicts = [json.loads(line) for line in open(out)]
-    mfu = next(v for v in verdicts if v["metric"] == "mfu")
-    assert mfu["status"] == "fail"
-    assert mfu["baseline_release"] == 3 and mfu["release"] == 5
-    assert mfu["baseline"] == pytest.approx(0.5431)
-    assert mfu["observed"] == pytest.approx(0.5474)
-    assert 0.0 < mfu["delta_frac"] < 0.01
-
-
-def test_gate_passes_synthetic_five_percent_run(tmp_path):
-    gate = _load_gate()
-    repo_dir = _write_ladder(tmp_path)
-    history = gate.load_history(repo_dir)
-    assert history[-1][0] == 5
-    base = history[-1][1]
-    fresh = {"mfu": round(base["mfu"] * 1.05, 4),
-             "value": round(base["value"] * 1.05, 2)}
-    fresh_path = str(tmp_path / "fresh.json")
-    with open(fresh_path, "w") as f:
-        json.dump(fresh, f)
-    out = str(tmp_path / "verdicts.jsonl")
-    rc = gate.main(["--check", "fresh", "--fresh", fresh_path,
-                    "--out", out, "--repo-dir", repo_dir])
-    assert rc == 0
-    verdicts = [json.loads(line) for line in open(out)]
-    assert all(v["status"] == "pass" for v in verdicts)
-    assert all(v["delta_frac"] > v["noise_band"] for v in verdicts)
-    # and a genuine regression (beyond the noise band) fails
-    with open(fresh_path, "w") as f:
-        json.dump({"mfu": base["mfu"] * 0.9, "value": base["value"] * 0.9},
-                  f)
-    assert gate.main(["--check", "fresh", "--fresh", fresh_path,
-                      "--repo-dir", repo_dir]) == 1
-
-
-def test_gate_noise_band_is_median_of_release_steps():
-    gate = _load_gate()
-    history = [(1, {"mfu": 1.00}), (2, {"mfu": 1.10}),
-               (3, {"mfu": 1.11}), (4, {"mfu": 1.12})]
-    # steps: 10%, 0.9%, 0.9% -> median 0.9% (the 10% outlier is ignored)
-    band = gate.noise_band(history, "mfu", floor=0.001)
-    assert band == pytest.approx(0.009, rel=0.05)
-    # the floor guards eerily-quiet histories
-    assert gate.noise_band([(1, {"mfu": 1.0}), (2, {"mfu": 1.0})],
-                           "mfu", floor=0.005) == 0.005
-
-
-def test_gate_phase_shift_names_the_guilty_phase(tmp_path):
-    gate = _load_gate()
-    base, fresh = tmp_path / "base.jsonl", tmp_path / "fresh.jsonl"
-    base.write_text(json.dumps(
-        {"kind": "decomposition", "window_s": 10.0,
-         "phases": {"compute": {"frac": 0.90}, "commit": {"frac": 0.05},
-                    "pull": {"frac": 0.05}}}) + "\n")
-    fresh.write_text(json.dumps(
-        {"kind": "decomposition", "window_s": 12.0,
-         "phases": {"compute": {"frac": 0.75}, "commit": {"frac": 0.20},
-                    "pull": {"frac": 0.05}}}) + "\n")
-    out = str(tmp_path / "verdicts.jsonl")
-    rc = gate.main(["--check", "phases",
-                    "--phases-baseline", str(base),
-                    "--phases-fresh", str(fresh), "--out", out])
-    assert rc == 1
-    verdicts = [json.loads(line) for line in open(out)]
-    failed = [v for v in verdicts if v["status"] == "fail"]
-    assert [v["metric"] for v in failed] == ["profile.phase.commit_s"]
-    assert "commit" in failed[0]["note"]
-
-
-def test_recorder_overhead_evidence_is_committed_and_within_budget():
-    """The paired off/on cost harness ran on this tree and its committed
-    evidence keeps the default-on recorder under the 2% budget."""
-    path = os.path.join(REPO, "benchmarks", "results",
-                        "pr11_recorder_overhead.jsonl")
-    rows = [json.loads(line) for line in open(path)]
-    meta = next(r for r in rows if r["kind"] == "meta")
-    assert meta["tool"] == "recorder_overhead"
-    overhead = next(r for r in rows if r["kind"] == "overhead")
-    assert overhead["overhead_frac"] <= 0.02
-    assert len(overhead["pair_ratios"]) == overhead["repeats"]
-    assert overhead["ring_events_per_run"] > 0

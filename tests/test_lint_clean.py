@@ -46,7 +46,7 @@ def test_repo_lints_clean(report):
 
 def test_scan_is_not_vacuous(modules, report):
     # the corpus floor protects against the walker silently matching
-    # nothing (the analogue of test_benchmarks_import's discovery floor)
+    # nothing (the analogue of test_perf_import's discovery floor)
     assert report.checked_files >= 100, report.checked_files
     rels = {m.relpath for m in modules}
     for must in ("distkeras_tpu/telemetry.py",
@@ -135,7 +135,7 @@ def test_analysis_discovery_found_the_checkers():
 
 @pytest.mark.parametrize("module", ANALYSIS_MODULES)
 def test_import_analysis_module(module):
-    # import-smoke (test_benchmarks_import.py pattern): the lint suite
+    # import-smoke (test_perf_import.py pattern): the lint suite
     # must import on a jax-less host — it only uses the stdlib
     assert importlib.import_module(module) is not None
 

@@ -70,8 +70,8 @@ def test_mfu_math():
 
 def test_calibrate_peak_off_tpu_returns_none():
     """On the CPU mesh there is no peak table entry — calibration must
-    decline rather than fabricate a ratio (bench.py's MFU gate treats None
-    as 'cannot check', not 'ok')."""
+    decline rather than fabricate a ratio (None means 'cannot check',
+    not 'ok')."""
     assert obs.calibrate_peak(size=64, chain=2, repeats=1) is None
 
 

@@ -5,7 +5,7 @@ several per-bucket variadic psums performs the same per-leaf reductions
 as the whole-tree psum, so every bucketed trajectory must be bitwise the
 unbucketed one (f32 models) — across the dp-sync substrate, the pjit
 explicit-DP mode, and their accum_steps compositions. Speed is the
-benchmark's problem (step_probe --buckets); correctness lives here.
+benchmark's problem (a cell of BENCHMARK.json); correctness lives here.
 """
 
 import jax

@@ -3,9 +3,10 @@
 The load-bearing guarantees, each a superset of the rectangular-pool
 contract test_generation.py pins:
 
-- the paged step's logits are BITWISE-equal to the full-prefix forward
-  at every position — including across page boundaries and through a
-  host swap-out/swap-in round trip;
+- the paged step's logits equal the full-prefix forward's at the
+  decode-step tolerance (test_generation.TOL) at every position,
+  including across page boundaries, and a host swap-out/swap-in round
+  trip returns the parked pages bit for bit;
 - a prefix-cache hit (full or partial) produces token-identical output
   to a cold engine, and a full hit runs ZERO prefill forwards;
 - speculative decoding emits exactly the plain greedy token sequence
@@ -42,6 +43,7 @@ from distkeras_tpu.serving.generation import (
     make_swap_out_fn,
 )
 from distkeras_tpu.utils import fault
+from test_generation import TOL, _prompt, _ref_fn
 
 
 @pytest.fixture(autouse=True)
@@ -61,22 +63,6 @@ def lm():
     return model, params
 
 
-def _prompt(n, seed=0):
-    return np.random.default_rng(seed).integers(1, 256, size=n,
-                                                dtype=np.int64).tolist()
-
-
-def _ref_fn(model, params):
-    full = jax.jit(lambda p, ids: model.apply({"params": p}, ids))
-
-    def ref(seq):
-        pad = np.zeros((1, model.max_len), np.int32)
-        pad[0, :len(seq)] = seq
-        return np.asarray(full(params, pad))[0, len(seq) - 1]
-
-    return ref
-
-
 def _greedy_ref(model, params, prompt, steps):
     ref = _ref_fn(model, params)
     seq, out = list(prompt), []
@@ -89,10 +75,11 @@ def _greedy_ref(model, params, prompt, steps):
 
 # ---------------------------------------------------------------- numerics
 
-def test_paged_step_bitwise_equals_full_forward_every_position(lm):
+def test_paged_step_equals_full_forward_every_position(lm):
     """Paged prefill + 40 decode steps on an interleaved (non-identity)
-    page table: every step's logits are bitwise the padded full
-    forward's, across the page boundaries at 16, 32 and beyond."""
+    page table: every step's logits are the padded full forward's at the
+    decode-step tolerance, across the page boundaries at 16, 32 and
+    beyond."""
     model, params = lm
     ref = _ref_fn(model, params)
     pool = PagedKVCachePool(model, num_slots=2, page_size=16)
@@ -113,7 +100,7 @@ def test_paged_step_bitwise_equals_full_forward_every_position(lm):
                             np.zeros(1, np.int32))
     pool.swap(new_pool)
     pool.lengths[a] = 5
-    np.testing.assert_array_equal(np.asarray(logits)[0, 4], ref(seq))
+    np.testing.assert_allclose(np.asarray(logits)[0, 4], ref(seq), **TOL)
     tok = int(np.argmax(np.asarray(logits)[0, 4]))
     for _ in range(40):
         feed = np.array([[tok, 0]], np.int32)  # token + ghost
@@ -123,14 +110,15 @@ def test_paged_step_bitwise_equals_full_forward_every_position(lm):
         pool.lengths[a] += 1
         seq.append(tok)
         row = np.asarray(logits)[0, 0]
-        np.testing.assert_array_equal(row, ref(seq))
+        np.testing.assert_allclose(row, ref(seq), **TOL)
         tok = int(np.argmax(row))
 
 
 def test_host_swap_roundtrip_is_bitwise_lossless(lm):
-    """swap_out -> clobber the device pages -> swap_in: decode resumes
-    with bitwise-identical logits, so parking KV in host RAM is free of
-    numerical consequence."""
+    """swap_out -> clobber the device pages -> swap_in: the pages come
+    back bit for bit (pure copies), so parking KV in host RAM is free of
+    numerical consequence, and decode resumes on the full forward's
+    logits."""
     model, params = lm
     ref = _ref_fn(model, params)
     pool = PagedKVCachePool(model, num_slots=1, page_size=16)
@@ -154,13 +142,17 @@ def test_host_swap_roundtrip_is_bitwise_lossless(lm):
     parked = jax.tree.map(np.asarray, swap_out(pool.pool, page_ids))
     pool.swap(jax.tree.map(jnp.zeros_like, pool.pool))  # clobber
     pool.swap(swap_in(pool.pool, page_ids, parked))     # restore
+    for was, now in zip(jax.tree.leaves(parked),
+                        jax.tree.leaves(swap_out(pool.pool, page_ids))):
+        assert np.any(was != 0)
+        np.testing.assert_array_equal(np.asarray(now), was)
 
     seq.append(tok)
     feed = np.array([[tok, 0]], np.int32)
     new_pool, logits = step(params, pool.pool, pts, feed,
                             np.array([20], np.int32))
     pool.swap(new_pool)
-    np.testing.assert_array_equal(np.asarray(logits)[0, 0], ref(seq))
+    np.testing.assert_allclose(np.asarray(logits)[0, 0], ref(seq), **TOL)
 
 
 def test_engine_paged_matches_rect_and_reference(lm):

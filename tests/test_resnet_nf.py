@@ -130,7 +130,7 @@ def test_space_to_depth_stem_shapes_and_grads():
 
 
 def test_resnet50_nf_is_the_bench_recipe():
-    """The public >=50%-MFU constructor (README quickstart / bench.py):
+    """The public norm-free constructor (README quickstart, chip_smoke.py):
     norm-free blocks + on-device uint8 normalization, overridable kwargs."""
     from distkeras_tpu.models import resnet50_nf
 

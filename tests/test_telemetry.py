@@ -273,15 +273,9 @@ def test_adag_host_async_leaves_artifact(tmp_path):
     assert {"trainer.init", "trainer.compile", "trainer.epoch",
             "trainer.stage", "trainer.finalize"} <= span_names
     # and the CLI renders it without error
-    import importlib.util
-    import os
-    spec = importlib.util.spec_from_file_location(
-        "telemetry_summary", os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "benchmarks", "telemetry_summary.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    report = mod.summarize(rows)
+    from distkeras_tpu.health import summary
+
+    report = summary.summarize(rows)
     assert "ps.commit.staleness" in report
     assert "staleness (commits folded between pull and fold)" in report
 

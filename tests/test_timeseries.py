@@ -10,7 +10,6 @@ Every detector test drives the store with an EXPLICIT clock (backdated
 of wall time without the test taking minutes.
 """
 
-import importlib.util
 import json
 import os
 import time
@@ -30,7 +29,6 @@ from distkeras_tpu.health.timeseries import (
     trend_specs,
 )
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 #: comfortably above the default 1 MiB/s HBM ceiling (a 1.0 MiB/s slope
 #: sits exactly ON the rail and must NOT fire — strict inequality)
@@ -382,11 +380,9 @@ def test_soak_smoke_all_authorities_and_invariants(tmp_path):
     once and hold the three flywheel invariants: zero lost windows (and
     data ranges), zero failed/wrong requests, strictly monotone
     model_version — plus catch-and-bundle the injected HBM leak."""
-    path = os.path.join(REPO, "benchmarks", "soak.py")
-    spec = importlib.util.spec_from_file_location("soak_smoke", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    rows, summary = mod.run_soak(budget_s=1.0, seed=0,
+    import soak_harness
+
+    rows, summary = soak_harness.run_soak(budget_s=1.0, seed=0,
                                  out_dir=str(tmp_path))
     assert summary["authorities_killed"] == 4
     assert min(summary["kills"].values()) >= 1

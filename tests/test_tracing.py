@@ -20,8 +20,6 @@ The load-bearing guarantees:
   (phase coverage >= 95%, tracing overhead <= 2%).
 """
 
-import importlib.util
-import json
 import os
 import subprocess
 import sys
@@ -355,11 +353,8 @@ def test_watch_table_renders_rates_and_fallback_rows():
 
 
 def test_merge_view_groups_rows_by_trace():
-    spec = importlib.util.spec_from_file_location(
-        "telemetry_summary", os.path.join(REPO, "benchmarks",
-                                          "telemetry_summary.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    from distkeras_tpu.health import summary as mod
+
     rows = [
         {"kind": "span", "name": "trace.window", "labels": {}, "t0": 1.0,
          "dur_s": 0.5, "trace_id": "t1", "span_id": "a", "parent_id": "r",
@@ -481,63 +476,3 @@ def test_flush_at_exit_writes_artifact(tmp_path):
                for r in rows)
     assert any(r.get("kind") == "span" and r.get("name") == "trace.window"
                for r in rows)
-
-
-# ------------------------------------------------------------ attribution
-
-def _load_attribution():
-    spec = importlib.util.spec_from_file_location(
-        "attribution", os.path.join(REPO, "benchmarks", "attribution.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _hist(name, sum_s, count=4, **labels):
-    return {"kind": "histogram", "name": name, "labels": labels,
-            "sum": sum_s, "count": count}
-
-
-def test_attribution_decomposition_and_residual():
-    mod = _load_attribution()
-    rows = [
-        _hist("profile.phase.window_s", 10.0, worker=0),
-        _hist("profile.phase.compute_s", 7.0, worker=0),
-        _hist("profile.phase.commit_s", 2.0, worker=0),
-        _hist("profile.phase.data_wait_s", 0.6, worker=0),
-        _hist("profile.phase.pull_s", 0.2, worker=0),
-        _hist("profile.phase.h2d_s", 0.1, worker=0),
-        _hist("profile.phase.bookkeep_s", 0.1, worker=0),
-        _hist("profile.phase.fold_s", 1.5, worker=0),  # nested: not summed
-    ]
-    d = mod.decompose(rows)
-    assert d["window_s"] == 10.0
-    assert d["coverage"] == 1.0  # partition phases only; fold is nested
-    assert d["phases"]["commit"]["frac"] == 0.2
-    text = mod.report(rows)
-    assert "top residual: commit" in text
-    assert "100.0% of window" in text
-    # labels aggregate: a second worker's histograms fold into the totals
-    d2 = mod.decompose(rows + [
-        _hist("profile.phase.window_s", 10.0, worker=1),
-        _hist("profile.phase.compute_s", 10.0, worker=1)])
-    assert d2["window_s"] == 20.0
-    assert d2["phases"]["compute"]["sum_s"] == 17.0
-
-
-def test_pr10_evidence_artifact_meets_acceptance():
-    """The committed evidence run: phase decomposition covers >= 95% of
-    window wall-time and tracing costs <= 2%."""
-    path = os.path.join(REPO, "benchmarks", "results",
-                        "pr10_attribution.jsonl")
-    rows = [json.loads(line) for line in open(path)]
-    by_kind = {}
-    for r in rows:
-        by_kind.setdefault(r["kind"], []).append(r)
-    (dec,) = by_kind["decomposition"]
-    (ov,) = by_kind["overhead"]
-    assert dec["coverage"] >= 0.95
-    assert ov["overhead_frac"] <= 0.02
-    assert ov["traced_spans"] > 0
-    top = {r["phase"] for r in by_kind["phase"] if r["level"] == "top"}
-    assert {"compute", "commit", "pull", "h2d"} <= top
