@@ -489,7 +489,8 @@ class GenerationResult:
 class _GenRequest:
     __slots__ = ("prompt", "max_new_tokens", "eos_id", "stream", "future",
                  "t_submit", "deadline", "generated", "last_token",
-                 "last_logits", "trace", "t_perf", "prefill_pos", "rng")
+                 "last_logits", "trace", "t_perf", "prefill_pos", "rng",
+                 "decode_t0", "decode_end", "decode_steps", "decode_step_s")
 
     def __init__(self, prompt, max_new_tokens, eos_id, stream,
                  t_submit, deadline, trace=None):
@@ -519,6 +520,22 @@ class _GenRequest:
         #: (perf_counter — t_submit stays monotonic for deadline math)
         self.trace = trace
         self.t_perf = time.perf_counter()
+        #: what the request's one ``trace.decode`` row needs: the start of
+        #: its first decode step, the end of its newest, how many it rode
+        #: and the sum of their intervals
+        self.decode_t0 = 0.0
+        self.decode_end = 0.0
+        self.decode_steps = 0
+        self.decode_step_s = 0.0
+
+    def rode_step(self, tp0: float, dt: float) -> None:
+        """Note one decode step ``[tp0, tp0 + dt]`` this request shared:
+        attribute writes only, the lane loop calls it once a lane."""
+        if not self.decode_steps:
+            self.decode_t0 = tp0
+        self.decode_end = tp0 + dt
+        self.decode_steps += 1
+        self.decode_step_s += dt
 
 
 class GenerationEngine:
@@ -661,6 +678,7 @@ class GenerationEngine:
         self._prefills_c = telemetry.counter("serving.decode.prefills")
         self._steps_c = telemetry.counter("serving.decode.steps")
         self._tokens_c = telemetry.counter("serving.decode.tokens")
+        self._trace_rows_c = telemetry.counter("serving.decode.trace_rows")
         self._device_picks_c = telemetry.counter(
             "serving.decode.device_picks")
         self._stream_err_c = telemetry.counter("serving.decode.stream_errors")
@@ -1114,11 +1132,12 @@ class GenerationEngine:
         as a device OOM) and :class:`EngineClosed` after shutdown.
 
         ``trace``: a :class:`~distkeras_tpu.telemetry.TraceContext` the
-        request's spans (queue-wait, prefill, each decode iteration, the
-        request total) chain under; defaults to the submitting thread's
-        current trace (DESIGN.md §15). The scheduler thread records the
-        spans with this explicit context — it serves many requests per
-        iteration, so no single thread-local trace can be "current" there.
+        request's spans (queue-wait, prefill, its decode steps as one
+        span, the request total) chain under; defaults to the submitting
+        thread's current trace (DESIGN.md §15). The scheduler thread
+        records the spans with this explicit context — it serves many
+        requests per iteration, so no single thread-local trace can be
+        "current" there.
         """
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size < 1:
@@ -1258,10 +1277,8 @@ class GenerationEngine:
                     f"deadline passed {1e3 * (now - req.deadline):.1f} ms "
                     f"before admission"))
                 continue
-            if req.trace is not None:
-                telemetry.record_trace_span(
-                    req.trace, "trace.queue_wait", req.t_perf,
-                    time.perf_counter() - req.t_perf)
+            self._trace_row(req, "trace.queue_wait", req.t_perf,
+                            time.perf_counter() - req.t_perf)
             slot = self.pool.allocate()
             if self._paged and not self.pool.reserve(
                     slot, min(req.prompt.size + req.max_new_tokens,
@@ -1320,11 +1337,9 @@ class GenerationEngine:
         self._prefills_c.inc()
         self._prefill_h.record(now - t0)
         self._ttft_h.record(now - req.t_submit)
-        if req.trace is not None:
-            telemetry.record_trace_span(
-                req.trace, "trace.prefill", tp0,
-                time.perf_counter() - tp0, bucket=lb, slot=slot,
-                model_version=self.model_version)
+        self._trace_row(req, "trace.prefill", tp0,
+                        time.perf_counter() - tp0, bucket=lb, slot=slot,
+                        model_version=self.model_version)
         req.generated.append(tok)
         req.last_token = tok
         if self._draft is not None:
@@ -1375,12 +1390,9 @@ class GenerationEngine:
             self._prefills_c.inc()
             self._prefill_h.record(now - t0)
         self._ttft_h.record(now - req.t_submit)
-        if req.trace is not None:
-            telemetry.record_trace_span(
-                req.trace, "trace.prefill", tp0,
-                time.perf_counter() - tp0, slot=slot,
-                prefix_hit=prefix_hit,
-                model_version=version)
+        self._trace_row(req, "trace.prefill", tp0,
+                        time.perf_counter() - tp0, slot=slot,
+                        prefix_hit=prefix_hit, model_version=version)
         req.generated.append(tok)
         req.last_token = tok
         if self._prefix is not None:
@@ -1657,14 +1669,7 @@ class GenerationEngine:
                 if self._draft is not None:
                     self._draft.observe(s, (tok,))
                 if req.trace is not None:
-                    # one decode iteration serves every lane at once, so
-                    # each traced request gets a child span with the SHARED
-                    # step interval — per-lane attribution of a batched
-                    # step would be an invention, not a measurement
-                    telemetry.record_trace_span(
-                        req.trace, "trace.decode", tp0, dt,
-                        step=len(req.generated), lanes=lane,
-                        model_version=version)
+                    req.rode_step(tp0, dt)
                 sched.lap("retire")
                 self._stream_token(req, tok)
                 sched.lap("stream")
@@ -1802,10 +1807,7 @@ class GenerationEngine:
                 self._draft.observe(slot, emit)
                 emitted_total += p
                 if req.trace is not None:
-                    telemetry.record_trace_span(
-                        req.trace, "trace.decode", tp0, dt,
-                        step=len(req.generated), lanes=lane, spec=p,
-                        model_version=version)
+                    req.rode_step(tp0, dt)
                 reason = self._emit(req, slot)
                 if reason is not None:
                     del active[slot]
@@ -1841,15 +1843,11 @@ class GenerationEngine:
                                 np.asarray(req.generated[:-1], np.int32)]),
                 req.last_logits)
         self.pool.free(slot)
-        self._slot_version.pop(slot, None)  # unpin: version may reclaim
+        version = self._slot_version.pop(slot, None)  # unpin: may reclaim
         if self._draft is not None:
             self._draft.release(slot)
         telemetry.counter("serving.decode.retired", reason=reason).inc()
-        if req.trace is not None:
-            telemetry.record_trace_span(
-                req.trace, "trace.request", req.t_perf,
-                time.perf_counter() - req.t_perf, reason=reason,
-                tokens=len(req.generated))
+        self._trace_leave(req, version, reason)
         req.future.set_result(
             GenerationResult(np.asarray(req.generated, np.int32), reason))
         return reason
@@ -1868,21 +1866,50 @@ class GenerationEngine:
                 if req.deadline is not None and now > req.deadline:
                     del grp[slot]
                     self.pool.free(slot)
-                    self._slot_version.pop(slot, None)
+                    version = self._slot_version.pop(slot, None)
                     if self._draft is not None:
                         self._draft.release(slot)
                     self._expired_c.inc()
                     telemetry.counter("serving.decode.retired",
                                       reason="deadline").inc()
-                    if req.trace is not None:
-                        telemetry.record_trace_span(
-                            req.trace, "trace.request", req.t_perf,
-                            time.perf_counter() - req.t_perf,
-                            reason="deadline", tokens=len(req.generated))
+                    self._trace_leave(req, version, "deadline")
                     req.future.set_exception(DeadlineExceeded(
                         f"deadline passed after {len(req.generated)} "
                         f"tokens"))
         self._active_g.set(len(active))
+
+    def _trace_row(self, req: _GenRequest, name: str, t0: float,
+                   dur_s: float, **labels) -> None:
+        """One ``trace.*`` row of a traced request, written from the
+        scheduler thread (each mints a span id with a system call, which
+        lets waiting handler threads in: never once a lane a step)."""
+        if req.trace is not None:
+            telemetry.record_trace_span(req.trace, name, t0, dur_s, **labels)
+            self._trace_rows_c.inc()
+
+    def _trace_leave(self, req: _GenRequest, version, reason: str) -> None:
+        """The rows a traced request leaves with: its decoding as ONE
+        ``trace.decode`` row, first step's start to last step's end (none
+        if it never decoded), then ``trace.request``. One decode step
+        serves every lane at once, so ``steps`` x ``step_ms`` is the time
+        of the steps this request rode, not a per-lane cost: attributing a
+        batched step to its lanes would be an invention, not a
+        measurement. Every step's own interval is in
+        ``serving.decode.step_s``. ``step_ms`` is their mean in whole
+        milliseconds: a label feeds ``span.trace.decode.duration_s``, and
+        the raw sum would mint one histogram a request."""
+        if req.trace is None:
+            return
+        steps = req.decode_steps
+        if steps:
+            self._trace_row(
+                req, "trace.decode", req.decode_t0,
+                req.decode_end - req.decode_t0, steps=steps,
+                step_ms=round(1e3 * req.decode_step_s / steps),
+                model_version=version)
+        self._trace_row(req, "trace.request", req.t_perf,
+                        time.perf_counter() - req.t_perf, reason=reason,
+                        tokens=len(req.generated))
 
     def _stream_token(self, req: _GenRequest, tok: int) -> None:
         if req.stream is None:

@@ -232,6 +232,7 @@ METRIC_NAMES = {
     "serving.decode.stream_errors": "counter",
     "serving.decode.tokens": "counter",
     "serving.decode.tokens_per_s": "gauge",
+    "serving.decode.trace_rows": "counter",
     "serving.decode.ttft_s": "histogram",
     # planet-scale decode layer (DESIGN.md §19): prefix cache, paged KV
     # with host swap, speculative decoding

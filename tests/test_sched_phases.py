@@ -19,6 +19,7 @@ The load-bearing guarantees:
 import glob
 import os
 import sys
+import threading
 import time
 
 import jax
@@ -237,6 +238,48 @@ def test_every_phase_has_one_sample_per_busy_iteration(lm, fake, flavour):
     assert telemetry.declared_kind("serving.sched.emit") == "annotation"
     assert fake.names("enter").count("serving.sched.wait") == \
         counters["serving.decode.steps"]
+
+
+def test_trace_rows_are_counted_by_the_request_not_by_the_token(monkeypatch):
+    """A traced request costs the scheduler thread a constant number of
+    ``trace.*`` rows (each mints its span id with ``os.urandom``, a system
+    call that lets waiting handler threads in), whatever it generates: none
+    in the lane loop, so the span ring keeps a long answer's other rows."""
+    model = gpt_tiny(max_len=256)
+    params = model.init(jax.random.key(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    real, calls = os.urandom, []
+
+    def urandom(n):
+        calls.append(threading.current_thread().name)
+        return real(n)
+
+    monkeypatch.setattr(os, "urandom", urandom)
+    answers = (40, 90, 200)     # the last one outlives the others
+    with GenerationEngine(model, params, num_slots=4, queue_capacity=8,
+                          prefill_buckets=(8,)) as eng:
+        roots = [telemetry.TraceContext.new_root() for _ in answers]
+        futs = [eng.generate(_prompt(5, n), max_new_tokens=n, trace=root)
+                for n, root in zip(answers, roots)]
+        for n, f in zip(answers, futs):
+            assert len(f.result(timeout=120).tokens) == n
+    on_scheduler = calls.count("generation-scheduler")
+    counters = telemetry.get_registry().snapshot()["counters"]
+    rows, tokens = (counters["serving.decode.trace_rows"],
+                    counters["serving.decode.tokens"])
+    # queue_wait, prefill, decode, request: four a request, none a token
+    assert on_scheduler == rows == 4 * len(answers)
+    assert tokens == sum(answers) - len(answers)
+    assert rows / tokens < 0.04
+    # the newest 100 spans still hold the 200-token answer's first rows
+    mine = {r["name"]: r for r in telemetry.get_registry().recent_spans()
+            if r.get("trace_id") == roots[-1].trace_id}
+    assert set(mine) == {"trace.queue_wait", "trace.prefill", "trace.decode",
+                         "trace.request"}
+    assert mine["trace.decode"]["labels"]["steps"] == answers[-1] - 1
+    # and no histogram per step number
+    assert not [k for k in telemetry.get_registry().snapshot()["histograms"]
+                if k.startswith("span.trace.decode.") and "step=" in k]
 
 
 class _UnawaitedLogits:

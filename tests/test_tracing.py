@@ -419,28 +419,110 @@ def test_trajectory_bitwise_identical_tracing_on_vs_off():
             assert r["parent_id"] in ids, r["name"]
 
 
-def test_generation_request_trace_covers_lifecycle():
+class _FixedDraft:
+    """A draft that proposes one token whatever the context: with a token
+    the model never emits, the verify step accepts nothing and every
+    speculative iteration yields its one free token."""
+
+    token = 0
+
+    def bind(self, engine):
+        pass
+
+    def begin(self, slot, prompt, first_token):
+        pass
+
+    def propose(self, slots, last_tokens, lengths, k):
+        return np.full((len(slots), k), self.token, np.int32)
+
+    def observe(self, slot, emitted):
+        pass
+
+    def release(self, slot):
+        pass
+
+
+def _trace_rows():
+    return [r for r in _span_rows() if r["name"].startswith("trace.")]
+
+
+@pytest.mark.parametrize("loop", ["plain", "speculative"])
+@pytest.mark.parametrize("end", ["length", "eos", "deadline",
+                                 "eos_at_prefill"])
+def test_generation_request_trace_covers_lifecycle(end, loop):
+    """A traced request leaves trace.queue_wait, trace.prefill, ONE
+    trace.decode spanning its decode steps and trace.request, however it
+    ends and whichever lane loop decoded it; one that finished at prefill
+    leaves no trace.decode."""
     from distkeras_tpu.models.gpt import gpt_tiny
-    from distkeras_tpu.serving import GenerationEngine
+    from distkeras_tpu.serving import DeadlineExceeded, GenerationEngine
 
     model = gpt_tiny()
     params = model.init(jax.random.key(0),
                         jnp.zeros((1, 8), jnp.int32))["params"]
-    root = telemetry.TraceContext.new_root()
-    with GenerationEngine(model, params, num_slots=2,
-                          queue_capacity=8) as eng:
+    prompt = [9, 10, 11]
+    draft = _FixedDraft()
+    kwargs = dict(draft=draft, spec_k=2) if loop == "speculative" else {}
+    with GenerationEngine(model, params, num_slots=2, queue_capacity=8,
+                          prefill_buckets=(8,), **kwargs) as eng:
+        # the same engine, untraced, says what greedy emits (no rows)
+        ref = eng.generate(prompt, max_new_tokens=8).result(
+            timeout=60).tokens.tolist()
+        assert not _trace_rows() and ref[3] not in ref[:3]
+        draft.token = next(t for t in range(256) if t not in ref)
+        steps0 = telemetry.counter("serving.decode.steps").value
+        ask = {"length": dict(max_new_tokens=4),
+               "eos": dict(max_new_tokens=8, eos_id=ref[3]),
+               "eos_at_prefill": dict(max_new_tokens=8, eos_id=ref[0]),
+               "deadline": dict(max_new_tokens=8, timeout_ms=1000.0)}[end]
+        seen = []
+        t_late = time.monotonic() + 1.05
+
+        def stream(tok):
+            seen.append(tok)
+            if end == "deadline" and len(seen) == 4:
+                # on the scheduler thread: the deadline passes here, so
+                # the next iteration expires the request after 3 steps
+                time.sleep(max(0.0, t_late - time.monotonic()))
+
+        root = telemetry.TraceContext.new_root()
         with telemetry.use_trace(root):
-            fut = eng.generate([1, 2, 3], max_new_tokens=4)
-        fut.result(timeout=60)
-    for name in ("trace.queue_wait", "trace.prefill", "trace.decode",
-                 "trace.request"):
-        rows = _span_rows(name)
-        assert rows, f"missing {name}"
-        assert all(r["trace_id"] == root.trace_id for r in rows)
-    # prefill emits token 1; each remaining token is one decode iteration
-    assert len(_span_rows("trace.decode")) == 3
-    assert len(_span_rows("trace.request")) == 1
+            fut = eng.generate(prompt, stream=stream, **ask)
+        reason = "eos" if end == "eos_at_prefill" else end
+        if end == "deadline":
+            with pytest.raises(DeadlineExceeded):
+                fut.result(timeout=60)
+        else:
+            assert fut.result(timeout=60).reason == reason
+        steps = telemetry.counter("serving.decode.steps").value - steps0
+    decoded = end != "eos_at_prefill"
+    assert seen == ref[:4 if decoded else 1]
+    assert steps == (3 if decoded else 0)
+    names = ["trace.queue_wait", "trace.prefill", "trace.request"]
+    if decoded:
+        names.insert(2, "trace.decode")
+    assert [r["name"] for r in _trace_rows()] == names
+    assert all(r["trace_id"] == root.trace_id for r in _trace_rows())
+    assert telemetry.counter("serving.decode.trace_rows").value == len(names)
     _assert_no_orphans(_span_rows(), [root])
+    (request,), (prefill,) = (_span_rows("trace.request"),
+                              _span_rows("trace.prefill"))
+    assert request["labels"]["reason"] == reason
+    assert request["labels"]["tokens"] == len(seen)
+    if decoded:
+        (decode,) = _span_rows("trace.decode")
+        assert decode["parent_id"] == root.span_id
+        # prefill emits token 1; each remaining token is one decode step
+        assert decode["labels"]["steps"] == 3
+        assert set(decode["labels"]) == {"steps", "step_ms", "model_version"}
+        # the steps it rode lie inside its decode interval (whole ms each)
+        assert 0 <= 3 * decode["labels"]["step_ms"] <= (
+            1e3 * decode["dur_s"] + 1.5)
+        eps = 1e-6
+        assert decode["t0"] >= prefill["t0"] + prefill["dur_s"] - eps
+        assert decode["t0"] + decode["dur_s"] <= (
+            request["t0"] + request["dur_s"] + eps)
+        assert request["t0"] <= decode["t0"]
 
 
 def test_serving_server_extracts_or_mints_request_trace():
