@@ -318,16 +318,17 @@ class CausalLM(nn.Module):
     # -- the cache protocol (module docstring): the model owns its
     # cache's leaves; the serving pool asks for them and for their cost
 
-    def init_cache(self, batch: int, dtype=None):
+    def init_cache(self, batch: int, dtype=None, positions=None):
         """Zeroed per-layer K/V cache for ``batch`` rows of ``max_len``
-        context: a tuple (one entry per layer) of ``{"k", "v"}`` arrays
+        context (``positions``, where a prefill asks for its fresh row):
+        a tuple (one entry per layer) of ``{"k", "v"}`` arrays
         shaped ``[batch, max_len, width]`` (a position's heads side by
         side, as the qkv projection emits them; module docstring) in the
         model's resolved compute dtype (K/V are produced by the qkv
         projection, which runs in that dtype)."""
         if dtype is None:
             dtype = precision_lib.resolve(self.precision, self.dtype)[0]
-        shape = (batch, self.max_len, self.width)
+        shape = (batch, positions or self.max_len, self.width)
         return tuple({"k": jnp.zeros(shape, dtype),
                       "v": jnp.zeros(shape, dtype)}
                      for _ in range(self.num_layers))

@@ -77,13 +77,25 @@ _ABSORB_MAX_BLOCK = 64
 _QUERY_BLOCK = 256
 
 #: most tokens the expert layer puts through every held expert (each
-#: token keeping its own gates' part) instead of sorting them into one
-#: grouped product a matrix. On the v5e at the published widths, 32 held
-#: experts, one layer: 256 tokens 3.49 ms against 5.99 grouped, 128 tokens
-#: 3.07 against 5.95; 1024 tokens 11.84 against 6.56 (my chip run, PR 27):
-#: the compiler's grouped kernel costs ~6 ms however few rows it has, the
+#: token keeping its own gates' part) instead of sorting them by expert.
+#: On the v5e at the published widths, 32 held experts, one layer: 256
+#: tokens 3.49 ms against 5.99 sorted, 128 tokens 3.07 against 5.95; 1024
+#: tokens 11.84 against 6.56 (my chip run, PR 27, the sorted rows then
+#: through ``jax.lax.ragged_dot``, ~6 ms however few they were): the
 #: masked product reads the same 1.6 GB and multiplies 32 x the rows
 _DENSE_MAX_TOKENS = 256
+
+#: rows of a block of the many-token expert path: a block is one expert's,
+#: so an expert with r tokens costs ceil(r / 256) passes over its matrices.
+#: At the published widths a pass reads 10 to 17 MB a matrix (12 to 20 us)
+#: and 256 rows multiply in about as long: smaller blocks wait for the
+#: weights, larger ones multiply padding. Why a loop and not
+#: ``jax.lax.ragged_dot`` (my chip runs, PR 32): that kernel took 17.5 ms a
+#: layer for 12 288 sorted rows over 64 groups of 2688 x 1856, 3.5 % of the
+#: MXU's peak, plus a 2 ms relayout of each weight stack a call, where the
+#: loop takes 3.75; at 32 groups of 4096 x 2048 a prefill of six layers took
+#: 75.7 ms with the kernel and 67.4 with the loop (PERF.md section 6)
+_EXPERT_BLOCK = 256
 
 #: lanes a short block attends at a time: a group's gathered rows (57 MB
 #: at 16 lanes of 4608 positions) are what has to stay in fast memory
@@ -173,14 +185,37 @@ def param_init(name: str):
     family's weights are drawn, for the modules below and for whoever
     makes the weights a leaf at a time (perf/builders/latent_moe.py).
     Norm vectors one, the embedding unit normal, every matrix and stack
-    of matrices :func:`_fan_in`; a name it does not know raises."""
+    of matrices :func:`_fan_in`, a router's selection bias normal of
+    deviation 0.1 (enough to move a choice, as a trained one does); a name
+    it does not know raises."""
     if name.endswith("norm") or "_norm_" in name:
         return nn.initializers.ones
     if name == "tok_embed":
         return nn.initializers.normal(1.0)
+    if name == "router_bias":
+        return nn.initializers.normal(0.1)
     if name in _MATRICES:
         return _fan_in
     raise KeyError(f"no initialiser for a parameter called {name!r}")
+
+
+def in_query_blocks(attend, args, block: int):
+    """``attend`` over ``args`` (arrays ``[b, t, ...]``, the queries and
+    what goes with each) ``block`` positions at a time: a long block's
+    scores are then ``[block, R]`` a head at once, not ``[t, R]``. A ragged
+    ``t`` is padded to whole blocks and cut after; a ``t`` within one
+    block is one call."""
+    b, t = args[0].shape[:2]
+    block = min(t, block)
+    if block == t:
+        return attend(args)
+    pad = -t % block
+    split = lambda a: jnp.moveaxis(
+        jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)).reshape(
+            (b, (t + pad) // block, block) + a.shape[2:]), 1, 0)
+    out = jax.lax.map(attend, tuple(split(a) for a in args))
+    return jnp.moveaxis(out, 0, 1).reshape(
+        (b, t + pad) + out.shape[3:])[:, :t]
 
 
 def _attend_expanded(q, rows, pos, w_kvb, dims, scale):
@@ -190,7 +225,7 @@ def _attend_expanded(q, rows, pos, w_kvb, dims, scale):
     ``R`` positions are up-projected per head once; the queries go
     through in blocks. Returns ``[b, t, h, v]``."""
     rank, nope, rope, v_dim, heads = dims
-    b, t = q.shape[:2]
+    b = q.shape[0]
     r = rows.shape[1]
     kv = jnp.einsum("brc,chd->brhd", rows[..., :rank],
                     w_kvb.reshape(rank, heads, nope + v_dim))
@@ -198,8 +233,6 @@ def _attend_expanded(q, rows, pos, w_kvb, dims, scale):
                               (b, r, heads, rope))
     k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)
     v = kv[..., nope:]
-    block = min(t, _QUERY_BLOCK)
-    pad = -t % block      # a ragged long block: whole blocks, cut after
 
     def attend(args):
         q_blk, pos_blk, scale_blk = args              # [b, block, ...]
@@ -209,13 +242,7 @@ def _attend_expanded(q, rows, pos, w_kvb, dims, scale):
         p = jax.nn.softmax(jnp.where(mask, s, MASK_VALUE), axis=-1)
         return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
-    if block == t:
-        return attend((q, pos, scale))
-    split = lambda a: jnp.moveaxis(
-        jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)).reshape(
-            (b, (t + pad) // block, block) + a.shape[2:]), 1, 0)
-    out = jax.lax.map(attend, (split(q), split(pos), split(scale)))
-    return jnp.moveaxis(out, 0, 1).reshape(b, t + pad, heads, v_dim)[:, :t]
+    return in_query_blocks(attend, (q, pos, scale), _QUERY_BLOCK)
 
 
 def _attend_absorbed(q, rows, pos, w_kvb, dims, scale):
@@ -331,16 +358,89 @@ def _swiglu(x, gate, up, down):
     return h.astype(x.dtype) @ down
 
 
+def _relu2(h):
+    """``relu(h)^2``, the ungated expert's activation, written ``relu(h) *
+    h``: the same number for every finite ``h``, and XLA:CPU does not then
+    fold the ``relu`` into the bfloat16 product before it, a form it cannot
+    run (``DotThunk``: BF16 x BF16 = F32 unimplemented)."""
+    return jax.nn.relu(h) * h
+
+
+@jax.jit
+def _in_expert_blocks(xb, gate, up, down, group):
+    """The many-token expert product. ``xb [n, d]`` tokens, ``gate`` (or
+    ``None``: the ungated form), ``up [held, d, width]``, ``down [held,
+    width, d]``, ``group [n * k]`` each assignment's held expert (``held``
+    for an absent one, which sorts behind every group so that no product
+    touches it). The assignments, sorted by expert, go into blocks of
+    :data:`_EXPERT_BLOCK` rows, each block one expert's (its last padded
+    with rows of zeros), and a loop runs over the blocks in use: two or
+    three products a block against that expert's matrices where they lie.
+    Back in their own order, ``[n * k, d]``; an absent expert's assignment
+    reads a row that is not its own. Jitted so that a model's expert layers
+    share one trace and one lowered function a shape."""
+    blk, dtype = _EXPERT_BLOCK, xb.dtype
+    held, d = up.shape[0], xb.shape[-1]
+    k = group.size // xb.shape[0]
+    sizes = jnp.sum(group[:, None] == jnp.arange(held)[None, :],
+                    axis=0, dtype=jnp.int32)
+    order = jnp.argsort(group, stable=True)
+    blocks_of = -(-sizes // blk)
+    ends = jnp.cumsum(blocks_of)
+    sorted_group = group[order]
+    of = jnp.minimum(sorted_group, held - 1)
+    rank = jnp.arange(order.size) - (jnp.cumsum(sizes) - sizes)[of]
+    n_blocks = order.size // blk + held   # sum of ceil(size / blk)
+    dest = jnp.where(sorted_group < held,
+                     (ends - blocks_of)[of] * blk + rank,
+                     n_blocks * blk)
+    rows = jnp.zeros((n_blocks * blk, d), dtype).at[dest].set(
+        xb[order // k], mode="drop")
+    expert_of = jnp.minimum(jnp.searchsorted(
+        ends, jnp.arange(n_blocks), side="right"), held - 1)
+
+    def one_block(b, y):
+        mine = lambda w: jax.lax.dynamic_index_in_dim(
+            w, expert_of[b], keepdims=False)
+        x_b = jax.lax.dynamic_slice_in_dim(rows, b * blk, blk)
+        wide = lambda w: jnp.dot(
+            x_b, mine(w), preferred_element_type=jnp.float32)
+        h = jax.nn.silu(wide(gate)) * wide(up) if gate is not None \
+            else _relu2(wide(up))
+        out = jnp.dot(h.astype(dtype), mine(down),
+                      preferred_element_type=jnp.float32)
+        return jax.lax.dynamic_update_slice_in_dim(
+            y, out.astype(dtype), b * blk, axis=0)
+
+    y = jax.lax.fori_loop(0, ends[-1], one_block, jnp.zeros_like(rows))
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.size, dtype=order.dtype))
+    return y[jnp.minimum(dest[back], n_blocks * blk - 1)]
+
+
 class ExpertShare(nn.Module):
     """The expert layer as one of ``of`` chips computes it: the shared
     expert, and for each token the part of its top-k mixture that the
-    experts held here give."""
+    experts held here give.
+
+    Two scoring rules: ``"softmax"`` over all the router's outputs, the k
+    largest renormalised; ``"sigmoid"``, the k largest of ``sigmoid(logit)
+    + b`` (``b`` a selection bias a router output, which chooses and does
+    not weigh), weighted by their sigmoids renormalised. Two expert forms:
+    ``"swiglu"``, ``down(silu(gate x) * up x)``; ``"relu2"``,
+    ``down(relu(up x)^2)`` with no gate matrix. ``shared_width`` is the
+    shared expert's width where it is not a routed expert's. The default
+    of each field is what :class:`LatentMoELM` had before there was a
+    choice."""
     width: int
     num_experts: int
     experts_per_token: int
     expert_share: Tuple[int, int]
     routed_scaling: float = 1.0
     dtype: jnp.dtype = jnp.bfloat16
+    scoring: str = "softmax"
+    activation: str = "swiglu"
+    shared_width: int = 0
 
     @nn.compact
     def __call__(self, x):
@@ -355,10 +455,17 @@ class ExpertShare(nn.Module):
         with jax.named_scope("moe.route"):
             w_r = self.param("router", param_init("router"),
                              (d, self.num_experts), jnp.float32)
-            gates = jax.nn.softmax(
-                jnp.dot(x.astype(jnp.float32), w_r,
-                        precision=jax.lax.Precision.HIGHEST), axis=-1)
-            top, chosen = jax.lax.top_k(gates, k)                  # [n, k]
+            logits = jnp.dot(x.astype(jnp.float32), w_r,
+                             precision=jax.lax.Precision.HIGHEST)
+            if self.scoring == "softmax":
+                top, chosen = jax.lax.top_k(
+                    jax.nn.softmax(logits, axis=-1), k)            # [n, k]
+            else:
+                scores = jax.nn.sigmoid(logits)
+                _, chosen = jax.lax.top_k(scores + self.param(
+                    "router_bias", param_init("router_bias"),
+                    (self.num_experts,), jnp.float32), k)
+                top = jnp.take_along_axis(scores, chosen, axis=-1)
             top = top / jnp.sum(top, axis=-1, keepdims=True) \
                 * self.routed_scaling
             local = chosen - index * held
@@ -366,45 +473,41 @@ class ExpertShare(nn.Module):
             sent = local[..., None] == jnp.arange(held)      # [n, k, held]
             routed = jnp.any(sent, axis=1)
         xb = x.astype(dtype)
-        gate, up = (mat(name, held, d, self.width) for name in ("gate", "up"))
+        gated = self.activation == "swiglu"
+        gate = mat("gate", held, d, self.width) if gated else None
+        up = mat("up", held, d, self.width)
         down = mat("down", held, self.width, d)
         with jax.named_scope("moe.experts"):
             if n <= _DENSE_MAX_TOKENS:
                 # few tokens: every held expert on every token, and each
                 # token keeps its own gates' part (zero for the rest)
                 f32 = dict(preferred_element_type=jnp.float32)
-                h = jax.nn.silu(jnp.einsum("nd,edf->enf", xb, gate, **f32)) \
-                    * jnp.einsum("nd,edf->enf", xb, up, **f32)
+                every = lambda w: jnp.einsum("nd,edf->enf", xb, w, **f32)
+                h = jax.nn.silu(every(gate)) * every(up) if gated \
+                    else _relu2(every(up))
                 y = jnp.einsum("enf,efd->end", h.astype(dtype), down, **f32)
                 mine = jnp.sum(jnp.where(sent, top[..., None], 0.0),
                                axis=1)                             # [n, held]
                 y = jnp.sum(y * mine.T[:, :, None], axis=0)
             else:
-                # many tokens: sorted by held expert, one grouped product
-                # a matrix; the assignments to absent experts sort behind
-                # every group and no product touches them
+                # many tokens: each assignment through its own expert
                 group = jnp.where(here, local, held).reshape(-1)   # [n * k]
-                sizes = jnp.sum(group[:, None] == jnp.arange(held)[None, :],
-                                axis=0, dtype=jnp.int32)
-                order = jnp.argsort(group, stable=True)
-                back = jnp.zeros_like(order).at[order].set(
-                    jnp.arange(n * k, dtype=order.dtype))
-                rows = xb[order // k]
-                grouped = lambda a, w: jax.lax.ragged_dot(
-                    a, w, sizes, preferred_element_type=dtype)
-                h = jax.nn.silu(grouped(rows, gate).astype(jnp.float32)) \
-                    * grouped(rows, up).astype(jnp.float32)
-                y = grouped(h.astype(dtype), down)
-                y = y[back].reshape(n, k, d).astype(jnp.float32)
-                # a select, not a product: rows outside every group hold
-                # whatever the grouped product left there
+                y = _in_expert_blocks(xb, gate, up, down, group)
+                y = y.reshape(n, k, d).astype(jnp.float32)
+                # a select, not a product: an absent expert's assignment
+                # reads a row that is not its own
                 y = jnp.sum(jnp.where(here[..., None], y * top[..., None],
                                       0.0), axis=1)
+        width = self.shared_width or self.width
         with jax.named_scope("moe.shared"):
-            y = y + _swiglu(xb, mat("shared_gate", d, self.width),
-                            mat("shared_up", d, self.width),
-                            mat("shared_down", self.width, d)
-                            ).astype(jnp.float32)
+            if gated:
+                shared = _swiglu(xb, mat("shared_gate", d, width),
+                                 mat("shared_up", d, width),
+                                 mat("shared_down", width, d))
+            else:
+                shared = _relu2((xb @ mat("shared_up", d, width)).astype(
+                    jnp.float32)).astype(dtype) @ mat("shared_down", width, d)
+            y = y + shared.astype(jnp.float32)
         return y, routed
 
 
@@ -449,11 +552,12 @@ class LatentMoELM(nn.Module):
         TPU stores as written; tests/test_decode_layout.py)."""
         return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
 
-    def init_cache(self, batch: int, dtype=None):
+    def init_cache(self, batch: int, dtype=None, positions=None):
         """Zeroed cache for ``batch`` rows: a tuple, one ``{"kv":
-        [batch, max_len, cache_line]}`` a layer, in ``dtype`` (the
-        model's own by default)."""
-        shape = (batch, self.max_len, self.cache_line)
+        [batch, max_len, cache_line]}`` a layer (``positions`` in
+        ``max_len``'s place, where a prefill asks for its fresh row), in
+        ``dtype`` (the model's own by default)."""
+        shape = (batch, positions or self.max_len, self.cache_line)
         return tuple({"kv": jnp.zeros(shape, dtype or self.dtype)}
                      for _ in range(self.num_layers))
 

@@ -121,7 +121,7 @@ from distkeras_tpu.serving.batching import (DeadlineExceeded, EngineClosed,
                                             QueueFull)
 from distkeras_tpu.serving.buckets import BucketSpec
 from distkeras_tpu.serving.kv_cache import (KVCachePool, PagedKVCachePool,
-                                            PrefixCache)
+                                            PrefixCache, state_leaves)
 from distkeras_tpu.utils import fault
 
 #: token id fed at the decode step's ghost position (its output is
@@ -141,30 +141,36 @@ def _default_ladder(num_slots: int) -> Tuple[int, ...]:
     return tuple(sorted(sizes))
 
 
-def make_prefill_fn(model):
+def make_prefill_fn(model, dtype=None):
     """Pure ``(params, pool, ids[1, Lb], slot, length) -> (pool',
-    last_logits[V])``: run the prompt through a fresh one-row cache of
-    the pool's leaves (``model.prefill_row_len(Lb)`` positions: the whole
-    ``max_len`` for ``CausalLM``, the bucket for a family that attends
-    only what it wrote), put the row into pool row ``slot`` from position
-    0 (the donated pool, one ``dynamic_update_slice`` a leaf) and return
-    the logits at position ``length - 1`` (the first-token distribution).
-    Bucket padding beyond ``length`` writes cells the length mask hides
-    until real tokens overwrite them."""
+    last_logits[V])``: run the prompt through a fresh one-row cache the
+    model builds (``model.init_cache(1, dtype, positions=
+    model.prefill_row_len(Lb))``: the whole ``max_len`` for ``CausalLM``,
+    the bucket for a family that attends only what it wrote; ``dtype`` the
+    pool's), put the row into pool row ``slot`` (the donated pool, one
+    ``dynamic_update_slice`` a leaf: a leaf without a position axis is
+    overwritten whole) and return the logits at position ``length - 1``
+    (the first-token distribution). Bucket padding beyond ``length`` writes
+    cells the length mask hides until real tokens overwrite them; a model
+    that declares state leaves is told ``length`` (``real_len``), commits
+    its state after position ``length - 1`` and hands back that position's
+    logits alone."""
     import jax
     import jax.numpy as jnp
 
+    stateful = bool(state_leaves(model))
+
     def prefill(params, pool, ids, slot, length):
-        positions = model.prefill_row_len(ids.shape[1])
-        row = jax.tree.map(
-            lambda a: jnp.zeros((1, positions) + a.shape[2:], a.dtype), pool)
+        row = model.init_cache(
+            1, dtype, positions=model.prefill_row_len(ids.shape[1]))
+        told = {"real_len": jnp.reshape(length, (1,))} if stateful else {}
         logits, new_row, *_ = model.apply(
             {"params": params}, ids, cache=row,
-            cache_index=jnp.zeros((1,), jnp.int32))
+            cache_index=jnp.zeros((1,), jnp.int32), **told)
         pool = jax.tree.map(
             lambda p, c: jax.lax.dynamic_update_slice_in_dim(
                 p, c, slot, axis=0), pool, new_row)
-        return pool, logits[0, length - 1]
+        return pool, logits[0, 0 if stateful else length - 1]
 
     return prefill
 
@@ -180,7 +186,10 @@ def make_decode_fn(model):
     The ghost's line sits past the lane's new length, masked until the
     next token overwrites it, and is dropped at ``max_len``. Padded
     lanes point at the pool's scratch row with length 0; their writes
-    land in scratch and their outputs are discarded by the caller.
+    land in scratch and their outputs are discarded by the caller. A
+    model that declares state leaves (:func:`state_leaves`) is fed
+    ``[token]`` alone: its state advances by the real token, and the
+    scratch row's state goes the way of the scratch row's lines.
 
     A model with routed experts hands back, token by token, which of the
     experts it holds each was sent to; the step then returns a third
@@ -194,9 +203,14 @@ def make_decode_fn(model):
     import jax
     import jax.numpy as jnp
 
+    ghost = not state_leaves(model)
+
     def decode(params, pool, slot_ids, tokens, lengths):
+        # a state a row would be advanced by the ghost too: such a model
+        # is fed its real token alone
         ids = jnp.stack(
-            [tokens, jnp.full_like(tokens, GHOST_TOKEN)], axis=1)
+            [tokens, jnp.full_like(tokens, GHOST_TOKEN)], axis=1) \
+            if ghost else tokens[:, None]
         logits, pool, *routed = model.apply(
             {"params": params}, ids, cache=pool, cache_index=lengths,
             cache_rows=slot_ids)
@@ -390,7 +404,7 @@ class ModelDraft:
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
         p_sds, c_sds = sds(self.params), sds(self._cache)
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)
-        prefill = make_prefill_fn(self.model)
+        prefill = make_prefill_fn(self.model, self._dtype)
         decode = make_decode_fn(self.model)
         self._prefill_exec = {}
         self._decode_exec = {}
@@ -594,6 +608,18 @@ class GenerationEngine:
                 f"num_slots={num_slots} so every in-flight count has a "
                 f"compiled lane width")
         self._paged = page_size is not None
+        asked = [name for name, on in (
+            ("page_size", self._paged),
+            ("prefix_cache_bytes", prefix_cache_bytes),
+            ("draft", draft is not None), ("spec_k", spec_k),
+            ("prefill_chunk", prefill_chunk is not None)) if on]
+        if state_leaves(model) and asked:
+            raise ValueError(
+                f"{type(model).__name__} keeps a state a row (cache leaf "
+                f"{state_leaves(model)[0]!r}: no position axis, so no "
+                f"length mask hides what a step writes there); it is "
+                f"served from the rectangular pool, whole prompts, one "
+                f"token a step: no {', '.join(asked)}")
         if prefix_cache_bytes and not self._paged:
             raise ValueError(
                 "prefix_cache_bytes requires page_size: the prefix cache "
@@ -644,6 +670,7 @@ class GenerationEngine:
         else:
             self.pool = KVCachePool(model, num_slots, device=device,
                                     dtype=dtype, hbm_fraction=hbm_fraction)
+        self._pool_dtype = dtype
         self._prefix = (PrefixCache(prefix_cache_bytes)
                         if prefix_cache_bytes else None)
         if device is not None:
@@ -676,6 +703,12 @@ class GenerationEngine:
         self._rejected_c = telemetry.counter("serving.decode.rejected")
         self._expired_c = telemetry.counter("serving.decode.deadline_exceeded")
         self._prefills_c = telemetry.counter("serving.decode.prefills")
+        # real prompt tokens, and the bucket or chunk positions computed
+        # for them (what padding costs: a padded position is as dear as a
+        # real one to a layer that scans)
+        self._prefill_tokens_c = telemetry.counter("serving.prefill.tokens")
+        self._prefill_positions_c = telemetry.counter(
+            "serving.prefill.positions")
         self._steps_c = telemetry.counter("serving.decode.steps")
         self._tokens_c = telemetry.counter("serving.decode.tokens")
         self._trace_rows_c = telemetry.counter("serving.decode.trace_rows")
@@ -829,7 +862,7 @@ class GenerationEngine:
                             pool_sds, i32(pmax), data_sds).compile()
                 compiles.inc()
             return
-        prefill = make_prefill_fn(self.model)
+        prefill = make_prefill_fn(self.model, self._pool_dtype)
         decode = pick(make_decode_fn(self.model))
         for lb in self._buckets:
             with telemetry.span("serving.decode.compile", prefill=lb):
@@ -1335,6 +1368,8 @@ class GenerationEngine:
         tok = self._pick_token(req, logits)
         now = time.monotonic()
         self._prefills_c.inc()
+        self._prefill_tokens_c.inc(n)
+        self._prefill_positions_c.inc(lb)
         self._prefill_h.record(now - t0)
         self._ttft_h.record(now - req.t_submit)
         self._trace_row(req, "trace.prefill", tp0,
@@ -1427,6 +1462,8 @@ class GenerationEngine:
                     np.full(1, start, np.int32))
                 self.pool.swap(new_pool)
                 logits_row = np.asarray(logits)[0, n - start - 1]
+            self._prefill_tokens_c.inc(int(suffix.size))
+            self._prefill_positions_c.inc(lb)
         self._finish_prefill(req, slot, logits_row, ran_prefill, t0, tp0,
                              hit)
 
@@ -1488,6 +1525,8 @@ class GenerationEngine:
                     np.full(1, pos, np.int32))
                 self.pool.swap(new_pool)
             self._chunk_steps_c.inc()
+            self._prefill_tokens_c.inc(int(chunk.size))
+            self._prefill_positions_c.inc(self._chunk)
             req.prefill_pos = pos + chunk.size
             self.pool.lengths[slot] = req.prefill_pos
             if req.prefill_pos >= n:

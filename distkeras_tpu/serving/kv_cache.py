@@ -57,12 +57,20 @@ prefix match, skipping prefill for the cached span.
 from __future__ import annotations
 
 import collections
-from typing import Optional
+from typing import Optional, Tuple
 
 import numpy as np
 
 from distkeras_tpu import observability, telemetry
 from distkeras_tpu.models import gpt as gpt_lib
+
+
+def state_leaves(model) -> Tuple[str, ...]:
+    """Names of the cache leaves ``model`` declares as a state a row: no
+    position axis, so no length mask hides what a step writes there
+    (``cache_state_leaves``; DESIGN.md section 14). Empty for a family
+    whose every leaf is rows x positions."""
+    return tuple(getattr(model, "cache_state_leaves", ()))
 
 
 class KVCachePool:
@@ -114,7 +122,16 @@ class KVCachePool:
         self.lengths = np.zeros(self.num_slots + 1, np.int32)
         self._free = list(range(self.num_slots - 1, -1, -1))
         self._active = set()
+        #: the part of ``cache_bytes`` in leaves the model declares as a
+        #: state a row (``cache_state_leaves``): no position axis, never
+        #: grows, whatever the context length
+        state = set(state_leaves(model))
+        self.state_bytes = sum(
+            leaf.nbytes for path, leaf in
+            jax.tree_util.tree_flatten_with_path(pool)[0]
+            if path[-1].key in state)
         telemetry.gauge("serving.decode.cache_bytes").set(self.cache_bytes)
+        telemetry.gauge("serving.decode.state_bytes").set(self.state_bytes)
         self._occupancy_g = telemetry.gauge("serving.decode.slot_occupancy")
         self._occupancy_g.set(0.0)
 

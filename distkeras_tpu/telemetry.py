@@ -215,6 +215,9 @@ METRIC_NAMES = {
     # generative serving (KV-cache decode loop, DESIGN.md §14)
     "serving.decode.admitted": "counter",
     "serving.decode.cache_bytes": "gauge",
+    # the part of it in leaves without a position axis (a recurrent state
+    # a row: models/hybrid.py); 0 for a family that keeps none
+    "serving.decode.state_bytes": "gauge",
     "serving.decode.compiles": "counter",
     "serving.decode.deadline_exceeded": "counter",
     "serving.decode.device_picks": "counter",
@@ -285,6 +288,10 @@ METRIC_NAMES = {
     "serving.moe.assignments_held": "counter",
     "serving.moe.experts_active": "histogram",
     "serving.moe.load_max_over_mean": "histogram",
+    # prefill's padding: real prompt tokens, and the bucket (or chunk)
+    # positions computed for them (GenerationEngine, every family)
+    "serving.prefill.positions": "counter",
+    "serving.prefill.tokens": "counter",
     # live rollout / canary / rollback plane (serving/rollout.py,
     # DESIGN.md §18)
     "rollout.canary.agreement": "gauge",
