@@ -564,6 +564,21 @@ class GenerationEngine:
     all host-side accounting; every loop iteration admits queued
     requests into free slots (prefill), advances all active lanes one
     token (decode), and retires finished sequences.
+
+    **One iteration** (DESIGN.md §14 "What a client sees, and when"):
+    bookkeeping, dispatch, delivery, wait, copy. Everything the next
+    launch needs (who retires, each lane's last token and length, which
+    slots are free) is settled in pure Python as soon as a step's tokens
+    are on the host; what a client or a handler thread can see (a stream
+    callback, a ``trace.*`` row, a future's result or error) is only
+    noted in ``self._owed``, and :meth:`_deliver` walks that queue right
+    after the next executable's call returns, so the threads it wakes
+    take the interpreter lock while the device works and never between
+    "ready to launch" and "launched". A request's tokens still arrive in
+    order and ahead of its result. Nothing stays owed: an iteration that
+    ends with no lane left flushes before the scheduler waits or returns,
+    and expiry, a scheduler error and a non-draining shutdown flush
+    before they fail a request.
     """
 
     def __init__(self, model, params, *, num_slots: int = 4,
@@ -698,6 +713,10 @@ class GenerationEngine:
         self._cv = threading.Condition()
         self._closed = False
         self._drain = True
+        # what the scheduler owes its clients, in order: (phase, call,
+        # arguments), handed over by _deliver once the next dispatch is
+        # with the device (class docstring, "One iteration")
+        self._owed: collections.deque = collections.deque()
 
         self._admitted_c = telemetry.counter("serving.decode.admitted")
         self._rejected_c = telemetry.counter("serving.decode.rejected")
@@ -730,6 +749,9 @@ class GenerationEngine:
             "serving.sched.",
             ("control", "admit", "prefill_wait", "launch", "wait", "copy",
              "pick", "stream", "retire"), whole="iter")
+        self._delivered_c = telemetry.counter("serving.sched.delivered")
+        self._delivered_after_c = telemetry.counter(
+            "serving.sched.delivered_after_dispatch")
         self._spec_proposed_c = telemetry.counter(
             "serving.decode.spec.proposed")
         self._spec_accepted_c = telemetry.counter(
@@ -1258,6 +1280,10 @@ class GenerationEngine:
                         self._chunk_step(active, prefilling)
                 if active:
                     self._decode_step(active)
+                if not active and not prefilling:
+                    # no lane left, so no dispatch is sure to come:
+                    # nothing stays owed over a wait or a return
+                    self._deliver(dispatched=False)
                 if busy:
                     sched.commit()
         except BaseException as e:  # scheduler must never die silently
@@ -1273,6 +1299,7 @@ class GenerationEngine:
             err = EngineClosed(f"generation scheduler failed: {e!r}")
             self._fail_pending_swap(err)
             self._fail_host_ops()
+            self._deliver(dispatched=False)  # then fail the rest
             for req in (pending + list(active.values())
                         + list(prefilling.values())):
                 req.future.set_exception(err)
@@ -1284,6 +1311,7 @@ class GenerationEngine:
         err = EngineClosed("engine shut down without draining")
         self._fail_pending_swap(err)
         self._fail_host_ops()
+        self._deliver(dispatched=False)  # then fail the rest
         for req in (pending + list(active.values())
                     + list(prefilling.values())):
             req.future.set_exception(err)
@@ -1306,12 +1334,13 @@ class GenerationEngine:
             now = time.monotonic()
             if req.deadline is not None and now > req.deadline:
                 self._expired_c.inc()
-                req.future.set_exception(DeadlineExceeded(
-                    f"deadline passed {1e3 * (now - req.deadline):.1f} ms "
-                    f"before admission"))
+                self._owed.append(("retire", req.future.set_exception, (
+                    DeadlineExceeded(
+                        f"deadline passed {1e3 * (now - req.deadline):.1f} "
+                        f"ms before admission"),)))
                 continue
-            self._trace_row(req, "trace.queue_wait", req.t_perf,
-                            time.perf_counter() - req.t_perf)
+            self._owe_row(req, "trace.queue_wait", req.t_perf,
+                          time.perf_counter() - req.t_perf)
             slot = self.pool.allocate()
             if self._paged and not self.pool.reserve(
                     slot, min(req.prompt.size + req.max_new_tokens,
@@ -1364,6 +1393,7 @@ class GenerationEngine:
             self._slot_version[slot] = self.model_version
             self.pool.swap(new_pool)
             self.pool.lengths[slot] = n
+            self._deliver(dispatched=True)
             logits = np.asarray(logits)
         tok = self._pick_token(req, logits)
         now = time.monotonic()
@@ -1372,14 +1402,14 @@ class GenerationEngine:
         self._prefill_positions_c.inc(lb)
         self._prefill_h.record(now - t0)
         self._ttft_h.record(now - req.t_submit)
-        self._trace_row(req, "trace.prefill", tp0,
-                        time.perf_counter() - tp0, bucket=lb, slot=slot,
-                        model_version=self.model_version)
+        self._owe_row(req, "trace.prefill", tp0,
+                      time.perf_counter() - tp0, bucket=lb, slot=slot,
+                      model_version=self.model_version)
         req.generated.append(tok)
         req.last_token = tok
         if self._draft is not None:
             self._draft.begin(slot, req.prompt, tok)
-        self._stream_token(req, tok)
+        self._owe_token(req, tok)
 
     def _prefix_start(self, req: _GenRequest, slot: int):
         """Prefix-cache half of paged admission: lookup + page swap-in.
@@ -1425,9 +1455,9 @@ class GenerationEngine:
             self._prefills_c.inc()
             self._prefill_h.record(now - t0)
         self._ttft_h.record(now - req.t_submit)
-        self._trace_row(req, "trace.prefill", tp0,
-                        time.perf_counter() - tp0, slot=slot,
-                        prefix_hit=prefix_hit, model_version=version)
+        self._owe_row(req, "trace.prefill", tp0,
+                      time.perf_counter() - tp0, slot=slot,
+                      prefix_hit=prefix_hit, model_version=version)
         req.generated.append(tok)
         req.last_token = tok
         if self._prefix is not None:
@@ -1437,7 +1467,7 @@ class GenerationEngine:
             self._capture_prefix(slot, req.prompt, req.last_logits)
         if self._draft is not None:
             self._draft.begin(slot, req.prompt, tok)
-        self._stream_token(req, tok)
+        self._owe_token(req, tok)
 
     def _prefill_paged(self, req: _GenRequest, slot: int) -> None:
         """Paged admission: prefix-cache lookup, page swap-in, then a
@@ -1461,6 +1491,7 @@ class GenerationEngine:
                     self._params, self.pool.pool, pts, ids,
                     np.full(1, start, np.int32))
                 self.pool.swap(new_pool)
+                self._deliver(dispatched=True)
                 logits_row = np.asarray(logits)[0, n - start - 1]
             self._prefill_tokens_c.inc(int(suffix.size))
             self._prefill_positions_c.inc(lb)
@@ -1524,6 +1555,7 @@ class GenerationEngine:
                     params, self.pool.pool, pts, ids,
                     np.full(1, pos, np.int32))
                 self.pool.swap(new_pool)
+                self._deliver(dispatched=True)
             self._chunk_steps_c.inc()
             self._prefill_tokens_c.inc(int(chunk.size))
             self._prefill_positions_c.inc(self._chunk)
@@ -1669,6 +1701,7 @@ class GenerationEngine:
             else:
                 new_pool, out, *routed = self._decode_exec[lane](
                     params, self.pool.pool, slot_ids, tokens[:, 0], lengths)
+        self._deliver(dispatched=True)  # the last step's, under this one
         with sched.phase("wait"):
             out.block_until_ready()  # the step lands
         with sched.phase("copy"):
@@ -1691,8 +1724,10 @@ class GenerationEngine:
             self._tps_g.set(n / dt)
         if routed:
             self._record_routing(routed[0], n)
-        # the lane loop keeps its order lane by lane (what a client sees):
-        # one annotation around it, its three phases summed lap by lap
+        # the lane loop is bookkeeping alone: it wakes nobody, and notes
+        # lane by lane (the order a client sees) what _deliver hands over
+        # once the next dispatch is with the device. One annotation around
+        # it, its two phases summed lap by lap
         with telemetry.annotation("serving.sched.emit"):
             sched.lap()
             for i, s in enumerate(slots):
@@ -1709,11 +1744,8 @@ class GenerationEngine:
                     self._draft.observe(s, (tok,))
                 if req.trace is not None:
                     req.rode_step(tp0, dt)
-                sched.lap("retire")
-                self._stream_token(req, tok)
-                sched.lap("stream")
-                reason = self._emit(req, s)
-                if reason is not None:
+                self._owe_token(req, tok)
+                if self._emit(req, s) is not None:
                     del active[s]
                 sched.lap("retire")
 
@@ -1797,6 +1829,7 @@ class GenerationEngine:
                 new_pool, logits = self._verify_exec[lane](
                     params, self.pool.pool, slot_ids, tokens, lengths)
             self.pool.swap(new_pool)
+        self._deliver(dispatched=True)
         with sched.phase("wait"):
             logits.block_until_ready()
         with sched.phase("copy"):
@@ -1835,20 +1868,17 @@ class GenerationEngine:
                     if resampled:
                         self._spec_s_resamples_c.inc()
                 self.pool.lengths[slot] += p  # cells L..L+p-1 are now true
-                sched.lap("retire")
                 for tok in emit:
                     req.generated.append(tok)
                     req.last_token = tok
-                    self._stream_token(req, tok)
-                sched.lap("stream")
+                    self._owe_token(req, tok)
                 if self._prefix is not None:
                     req.last_logits = logits[i, p - 1].copy()
                 self._draft.observe(slot, emit)
                 emitted_total += p
                 if req.trace is not None:
                     req.rode_step(tp0, dt)
-                reason = self._emit(req, slot)
-                if reason is not None:
+                if self._emit(req, slot) is not None:
                     del active[slot]
                 sched.lap("retire")
         self._tokens_c.inc(emitted_total)
@@ -1860,7 +1890,8 @@ class GenerationEngine:
 
     def _emit(self, req: _GenRequest, slot: int) -> Optional[str]:
         """After a token lands, decide retirement. Returns the reason
-        when the sequence finished (slot already freed), else None."""
+        when the sequence finished (slot already freed, the result owed
+        behind its last token), else None."""
         tok = req.last_token
         if req.eos_id is not None and tok == req.eos_id:
             reason = "eos"
@@ -1885,10 +1916,7 @@ class GenerationEngine:
         version = self._slot_version.pop(slot, None)  # unpin: may reclaim
         if self._draft is not None:
             self._draft.release(slot)
-        telemetry.counter("serving.decode.retired", reason=reason).inc()
-        self._trace_leave(req, version, reason)
-        req.future.set_result(
-            GenerationResult(np.asarray(req.generated, np.int32), reason))
+        self._owed.append(("retire", self._leave, (req, version, reason)))
         return reason
 
     def _expire(self, active, prefilling=None) -> None:
@@ -1896,6 +1924,7 @@ class GenerationEngine:
         (or mid-chunked-prefill); their slots free immediately (the
         mid-flight retirement path)."""
         now = time.monotonic()
+        expired = False
         groups = [active]
         if prefilling:
             groups.append(prefilling)
@@ -1909,22 +1938,71 @@ class GenerationEngine:
                     if self._draft is not None:
                         self._draft.release(slot)
                     self._expired_c.inc()
-                    telemetry.counter("serving.decode.retired",
-                                      reason="deadline").inc()
-                    self._trace_leave(req, version, "deadline")
-                    req.future.set_exception(DeadlineExceeded(
-                        f"deadline passed after {len(req.generated)} "
-                        f"tokens"))
+                    self._owed.append(("retire", self._leave, (
+                        req, version, "deadline", DeadlineExceeded(
+                            f"deadline passed after {len(req.generated)} "
+                            f"tokens"))))
+                    expired = True
+        if expired:
+            # the token a request is still owed reaches it first
+            self._deliver(dispatched=False)
         self._active_g.set(len(active))
 
     def _trace_row(self, req: _GenRequest, name: str, t0: float,
-                   dur_s: float, **labels) -> None:
+                   dur_s: float, labels: dict) -> None:
         """One ``trace.*`` row of a traced request, written from the
         scheduler thread (each mints a span id with a system call, which
-        lets waiting handler threads in: never once a lane a step)."""
+        lets waiting handler threads in: never once a lane a step, and
+        only from :meth:`_deliver`)."""
+        telemetry.record_trace_span(req.trace, name, t0, dur_s, **labels)
+        self._trace_rows_c.inc()
+
+    def _owe_row(self, req: _GenRequest, name: str, t0: float,
+                 dur_s: float, **labels) -> None:
         if req.trace is not None:
-            telemetry.record_trace_span(req.trace, name, t0, dur_s, **labels)
-            self._trace_rows_c.inc()
+            self._owed.append(
+                ("retire", self._trace_row, (req, name, t0, dur_s, labels)))
+
+    def _owe_token(self, req: _GenRequest, tok: int) -> None:
+        if req.stream is not None:
+            self._owed.append(("stream", self._stream_token, (req, tok)))
+
+    def _deliver(self, dispatched: bool) -> None:
+        """Hand the clients what the scheduler owes them, oldest first: a
+        request's tokens in order, then its result. ``dispatched`` says
+        that an executable's call has just returned, so the handler
+        threads this wakes run while the device works; False is a flush,
+        where no dispatch is sure to come (no lane left) or a request is
+        about to be failed (expiry, a scheduler error, a shutdown that
+        does not drain). Never called under ``self._cv``: a future's
+        callback may call :meth:`generate`. An item leaves the queue
+        before its call, so a call that raises loses no other."""
+        owed = self._owed
+        if not owed and not dispatched:
+            return
+        sched = self._sched
+        n = len(owed)
+        with telemetry.annotation("serving.sched.deliver"):
+            sched.lap()
+            while owed:
+                phase, call, args = owed.popleft()
+                call(*args)
+                sched.lap(phase)
+        self._delivered_c.inc(n)
+        if dispatched:
+            self._delivered_after_c.inc(n)
+
+    def _leave(self, req: _GenRequest, version, reason: str,
+               error: Optional[Exception] = None) -> None:
+        """A request's last act: its leaving rows, then its result, or
+        ``error`` for one that expired in flight."""
+        telemetry.counter("serving.decode.retired", reason=reason).inc()
+        self._trace_leave(req, version, reason)
+        if error is not None:
+            req.future.set_exception(error)
+        else:
+            req.future.set_result(GenerationResult(
+                np.asarray(req.generated, np.int32), reason))
 
     def _trace_leave(self, req: _GenRequest, version, reason: str) -> None:
         """The rows a traced request leaves with: its decoding as ONE
@@ -1943,12 +2021,13 @@ class GenerationEngine:
         if steps:
             self._trace_row(
                 req, "trace.decode", req.decode_t0,
-                req.decode_end - req.decode_t0, steps=steps,
-                step_ms=round(1e3 * req.decode_step_s / steps),
-                model_version=version)
+                req.decode_end - req.decode_t0, dict(
+                    steps=steps,
+                    step_ms=round(1e3 * req.decode_step_s / steps),
+                    model_version=version))
         self._trace_row(req, "trace.request", req.t_perf,
-                        time.perf_counter() - req.t_perf, reason=reason,
-                        tokens=len(req.generated))
+                        time.perf_counter() - req.t_perf,
+                        dict(reason=reason, tokens=len(req.generated)))
 
     def _stream_token(self, req: _GenRequest, tok: int) -> None:
         if req.stream is None:

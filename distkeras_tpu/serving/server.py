@@ -355,9 +355,12 @@ class ServingServer:
             try:
                 chunk = [q.get(timeout=0.05)]
             except queue.Empty:
-                # done implies every stream put already happened (the
-                # scheduler streams before completing the future), so
-                # done-then-empty means no frame can still arrive
+                # done implies every stream put already happened: the
+                # scheduler hands over what it owes from one queue in
+                # order (GenerationEngine._deliver), a request's result or
+                # error behind its last token, and flushes that queue
+                # before it fails a request. So done-then-empty means no
+                # frame can still arrive
                 if fut.done() and q.empty():
                     break
                 continue

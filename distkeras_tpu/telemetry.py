@@ -278,8 +278,16 @@ METRIC_NAMES = {
     "serving.sched.retire_s": "histogram",
     "serving.sched.stream_s": "histogram",
     "serving.sched.wait_s": "histogram",
-    # profiler annotation only (no instrument): the lane loop that
-    # pick_s + stream_s + retire_s split on the host clock
+    # what the scheduler owed its clients and handed over (tokens, trace
+    # rows, results), and those of them handed over with a device
+    # dispatch just returned, the device at work, as against a flush
+    # with nothing to dispatch or on an expiry or error path
+    "serving.sched.delivered": "counter",
+    "serving.sched.delivered_after_dispatch": "counter",
+    # profiler annotations only (no instrument): the lane loop's
+    # bookkeeping (pick_s + retire_s) and the walk that hands over what
+    # it owes (stream_s + retire_s), both split on the host clock
+    "serving.sched.deliver": "annotation",
     "serving.sched.emit": "annotation",
     # routed experts (models/latent_moe.py): the decode step's tokens per
     # held expert, [layers, experts_held], fed in once a step
@@ -1108,9 +1116,13 @@ class PhaseTimer:
 
     def lap(self, name: Optional[str] = None) -> None:
         """Read the clock and add the seconds since this timer's last
-        read to ``name``'s sum (to nothing when None: the first read of a
-        run of laps). For a loop whose phases interleave item by item."""
+        read to ``name``'s sum (when None, the first read of a run of
+        laps: to the phase that is open, to nothing if none is). For a
+        loop whose phases interleave item by item; inside a ``phase``
+        block the laps' seconds are theirs and not the block's."""
         now = time.perf_counter()
+        if name is None:
+            name = self._open
         if name is not None:
             self._sums[name] += now - self._t
         self._t = now

@@ -14,9 +14,14 @@ The load-bearing guarantees:
   finishes first, and a freed slot is reused mid-flight;
 - slot exhaustion surfaces as QueueFull backpressure, never an OOM;
 - a deadline expiring mid-generation fails that request and frees its
-  slot for the next one.
+  slot for the next one;
+- what a client sees of step *k* (its token on the stream, a result)
+  is handed over after step *k + 1* has been dispatched, in the order it
+  always had, and nothing stays owed when the engine idles, expires a
+  request, fails or shuts down (ISSUE 33).
 """
 
+import threading
 import time
 
 import jax
@@ -36,6 +41,7 @@ from distkeras_tpu.serving import (
 from distkeras_tpu.serving.generation import (make_decode_fn,
                                               make_prefill_fn,
                                               make_verify_fn)
+from test_sched_phases import ENGINES
 
 
 @pytest.fixture(autouse=True)
@@ -476,3 +482,189 @@ def test_health_status_shape(lm):
         assert h["decode_ladder"] == [1, 2]
         assert h["compiled"] == {"prefill": [8], "decode": [1, 2]}
         assert h["cache_bytes"] == eng.pool.cache_bytes
+
+
+# ------------------------------------------- delivery after the dispatch
+
+def _note_dispatches(eng, log):
+    """Wrap every executable the scheduler dispatches (prefill buckets,
+    the chunk step, the decode and verify ladders) so that its call
+    appends ``"D"`` to ``log`` first. Called before the first request:
+    the scheduler is waiting and reads the tables at each call."""
+    def noting(ex):
+        def call(*args):
+            log.append("D")
+            return ex(*args)
+        return call
+
+    for table in (eng._prefill_exec, eng._decode_exec, eng._verify_exec):
+        for key in list(table):
+            table[key] = noting(table[key])
+    if eng._chunk_exec is not None:
+        eng._chunk_exec = noting(eng._chunk_exec)
+
+
+def _counters():
+    return telemetry.get_registry().snapshot()["counters"]
+
+
+@pytest.mark.parametrize("flavour", sorted(ENGINES))
+def test_a_steps_tokens_follow_the_next_steps_dispatch(lm, flavour):
+    """One request on one lane, its executables and its stream writing
+    one log: step k + 1's call comes before the stream callback of step
+    k's tokens, every dispatch once decoding began hands something over,
+    and the last step's tokens and the result arrive with no further
+    dispatch, because the engine idles with nothing owed."""
+    model, params = lm
+    log, streamed = [], []
+    with GenerationEngine(model, params, num_slots=1,
+                          prefill_buckets=(8, 32), **ENGINES[flavour]()
+                          ) as eng:
+        _note_dispatches(eng, log)
+        fut = eng.generate(_prompt(20, 3), max_new_tokens=9,
+                           stream=lambda t: (streamed.append(t),
+                                             log.append("t")))
+        fut.add_done_callback(lambda f: log.append("R"))
+        res = fut.result(timeout=60)
+        assert not eng._owed
+        counters, seen = _counters(), list(log)
+        assert eng.generate(_prompt(4), max_new_tokens=1).result(
+            timeout=60).tokens.size == 1    # one token: no decode step
+    assert streamed == res.tokens.tolist() and len(streamed) == 9
+    steps = counters["serving.decode.steps"]
+    prefill_calls = counters.get("serving.decode.chunk.steps", 1)
+    assert seen.count("D") == prefill_calls + steps
+    # nothing reaches the client before the first decode step is with the
+    # device, the prefill's own token included
+    first = seen.index("t")
+    assert seen[:first] == ["D"] * (prefill_calls + 1)
+    rest = "".join(seen[first:])
+    # then runs of tokens, one dispatch between two runs, and the last
+    # two runs together: the flush follows the last delivery directly
+    assert rest.endswith("tR") and "DD" not in rest and "Dt" in rest
+    assert rest.count("D") == steps - 1 and rest.count("t") == 9
+    if flavour != "speculative":    # one token a step
+        assert rest == "tD" * (steps - 1) + "ttR" and steps == 8
+    assert counters["serving.sched.delivered"] == 9 + 1
+    after = counters["serving.sched.delivered_after_dispatch"]
+    assert 1 <= counters["serving.sched.delivered"] - after <= 1 + 3 + 1
+
+
+def test_expiry_hands_over_the_owed_token_before_the_error(lm):
+    model, params = lm
+    seen = []
+
+    def stream(tok):
+        seen.append(tok)
+        if len(seen) == 2:
+            # handed over with step 2 launched: the deadline passes here,
+            # step 2 lands, and its token is owed when the request expires
+            time.sleep(max(0.0, t_late - time.monotonic()))
+
+    with GenerationEngine(model, params, num_slots=1,
+                          prefill_buckets=(8,)) as eng:
+        t_late = time.monotonic() + 1.05
+        fut = eng.generate(_prompt(4), max_new_tokens=50, timeout_ms=1000.0,
+                           stream=stream)
+        fut.add_done_callback(lambda f: seen.append("failed"))
+        with pytest.raises(DeadlineExceeded, match="after 3 tokens"):
+            fut.result(timeout=60)
+        assert not eng._owed and eng.pool.num_free == 1
+    assert len(seen) == 4 and seen[-1] == "failed"
+    counters = _counters()
+    assert counters["serving.sched.delivered"] == 3 + 1
+    assert counters["serving.sched.delivered_after_dispatch"] == 2
+
+
+@pytest.mark.filterwarnings(     # the scheduler re-raises what killed it
+    "ignore::pytest.PytestUnhandledThreadExceptionWarning")
+@pytest.mark.parametrize("how", ["no_drain", "failure"])
+def test_what_is_owed_is_flushed_before_the_rest_is_failed(lm, how):
+    """A shutdown that does not drain and a scheduler failure both find a
+    token owed (step 2's): it reaches the stream, then the future fails."""
+    model, params = lm
+    seen, closing = [], threading.Event()
+    eng = GenerationEngine(model, params, num_slots=1, prefill_buckets=(8,))
+    try:
+        if how == "failure":
+            real, calls = eng._decode_exec[1], []
+
+            def third_call_fails(*args):
+                calls.append(1)
+                if len(calls) == 3:
+                    raise RuntimeError("injected")
+                return real(*args)
+
+            eng._decode_exec[1] = third_call_fails
+
+        def stream(tok):
+            seen.append(tok)
+            if how == "no_drain" and len(seen) == 2:
+                closing.set()       # step 2 is launched; wait for the close
+                while not eng._closed:
+                    time.sleep(0.001)
+
+        fut = eng.generate(_prompt(4), max_new_tokens=50, stream=stream)
+        fut.add_done_callback(lambda f: seen.append("failed"))
+        if how == "no_drain":
+            assert closing.wait(timeout=60)
+            eng.shutdown(drain=False, timeout=30.0)
+        with pytest.raises(EngineClosed):
+            fut.result(timeout=60)
+    finally:
+        eng.shutdown(drain=False, timeout=30.0)
+    assert not eng._thread.is_alive() and not eng._owed
+    assert len(seen) == 4 and seen[-1] == "failed"
+    counters = _counters()
+    assert counters["serving.sched.delivered"] == 3
+    assert counters["serving.sched.delivered_after_dispatch"] == 2
+    if how == "failure":
+        assert counters["serving.decode.loop_errors"] == 1
+
+
+@pytest.mark.parametrize("lanes_left", [0, 1])
+def test_a_done_callback_may_call_generate(lm, lanes_left):
+    """The result is set from the delivery walk, outside the engine's
+    condition: a callback that submits the next request does not deadlock,
+    whether the walk is a flush (no lane left) or follows a dispatch."""
+    model, params = lm
+    follow = []
+    with GenerationEngine(model, params, num_slots=2,
+                          prefill_buckets=(8,)) as eng:
+        if lanes_left:
+            other = eng.generate(_prompt(5, 1), max_new_tokens=60)
+        fut = eng.generate(_prompt(4, 2), max_new_tokens=3)
+        fut.add_done_callback(lambda f: follow.append(
+            eng.generate(_prompt(6, 3), max_new_tokens=3)))
+        assert fut.result(timeout=60).tokens.size == 3
+        t_end = time.monotonic() + 60
+        while not follow and time.monotonic() < t_end:
+            time.sleep(0.001)
+        assert follow[0].result(timeout=60).tokens.size == 3
+        if lanes_left:
+            assert other.result(timeout=60).tokens.size == 60
+
+
+def test_delivered_counts_what_was_owed_and_most_follows_a_dispatch(lm):
+    """A queue that is never empty until the end: every traced request is
+    owed its tokens, two rows through the queue (queue_wait, prefill) and
+    one result; only the last retirement's items are flushed."""
+    model, params = lm
+    n, answer = 8, 24
+    streams = [[] for _ in range(n)]
+    with GenerationEngine(model, params, num_slots=2, queue_capacity=16,
+                          prefill_buckets=(8,)) as eng:
+        futs = [eng.generate(_prompt(4 + k % 3, k), max_new_tokens=answer,
+                             stream=streams[k].append,
+                             trace=telemetry.TraceContext.new_root())
+                for k in range(n)]
+        results = [f.result(timeout=120) for f in futs]
+        silent = eng.generate(_prompt(4), max_new_tokens=5).result(
+            timeout=60)     # no stream, no trace: its result alone is owed
+    assert [r.tokens.tolist() for r in results] == streams
+    counters = _counters()
+    delivered = counters["serving.sched.delivered"]
+    assert delivered == n * (answer + 2 + 1) + 1 and silent.tokens.size == 5
+    assert counters["serving.sched.delivered_after_dispatch"] >= \
+        0.9 * delivered
+    assert counters["serving.decode.trace_rows"] == 4 * n

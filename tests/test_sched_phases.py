@@ -11,6 +11,8 @@ The load-bearing guarantees:
 - every phase histogram holds one sample per scheduler iteration that held
   a lane, the phases account for the iteration, and decode steps grow
   neither the span ring nor the flight recorder;
+- the walk that hands the clients what they are owed (ISSUE 33) is an
+  annotation of its own, entered once a dispatch, right behind it;
 - the phases are events of the profiler's own trace beside the trainer's
   spans (the clock the device trace is on);
 - splitting the logits fetch into a wait and a copy serves the same tokens.
@@ -221,23 +223,46 @@ def test_every_phase_has_one_sample_per_busy_iteration(lm, fake, flavour):
     parts = sum(hist[f"serving.sched.{p}_s"]["sum"] for p in PHASES)
     whole = hist["serving.sched.iter_s"]["sum"]
     assert 0.9 * whole <= parts <= whole, (parts, whole)
-    # the step's own histogram is the launch-to-host interval it always was
+    # the step's own histogram is the launch-to-host interval it always
+    # was; the walk that delivers the step before now lies inside it
     assert hist["serving.decode.step_s"]["sum"] <= sum(
         hist[f"serving.sched.{p}_s"]["sum"]
-        for p in ("launch", "wait", "copy", "pick")) + 1e-3 * iters
+        for p in ("launch", "wait", "copy", "pick", "stream", "retire")
+    ) + 1e-3 * iters
     for p in ("launch", "wait", "copy", "pick", "retire", "prefill_wait"):
         assert hist[f"serving.sched.{p}_s"]["sum"] > 0, p
     # annotations: leaves named like the histograms, one `emit` around the
-    # lane loop, none around the whole iteration
+    # lane loop's bookkeeping, one `deliver` around the walk, none around
+    # the whole iteration
     entered = set(fake.names("enter"))
     assert {"serving.sched." + p for p in
             ("control", "admit", "prefill_wait", "launch", "wait", "copy",
-             "emit")} <= entered
+             "emit", "deliver")} <= entered
     assert not {"serving.sched.iter", "serving.sched.stream",
                 "serving.sched.retire"} & entered
-    assert telemetry.declared_kind("serving.sched.emit") == "annotation"
-    assert fake.names("enter").count("serving.sched.wait") == \
-        counters["serving.decode.steps"]
+    for name in ("emit", "deliver"):
+        assert telemetry.declared_kind(
+            "serving.sched." + name) == "annotation"
+    steps = counters["serving.decode.steps"]
+    assert fake.names("enter").count("serving.sched.wait") == steps
+    # one walk a dispatch, right behind it: between a launch's exit and
+    # the wait's entry, and inside a prefill's call-to-logits interval;
+    # besides those, only flushes (at most one an iteration)
+    events = [(w, n.removeprefix("serving.sched."))
+              for w, n, _ in fake.events]
+    for i, event in enumerate(events):
+        if event == ("exit", "launch"):
+            assert events[i + 1:i + 4] == [
+                ("enter", "deliver"), ("exit", "deliver"),
+                ("enter", "wait")], events[i:i + 4]
+    calls = counters.get("serving.decode.chunk.steps",
+                         counters["serving.decode.prefills"])
+    inside = sum(1 for i, event in enumerate(events)
+                 if event == ("enter", "deliver")
+                 and events[i - 1] == ("enter", "prefill_wait"))
+    assert inside == calls
+    walks = fake.names("enter").count("serving.sched.deliver")
+    assert steps + calls <= walks <= steps + calls + iters
 
 
 def test_trace_rows_are_counted_by_the_request_not_by_the_token(monkeypatch):
@@ -357,6 +382,9 @@ def test_phases_and_trainer_spans_are_events_of_the_profilers_trace(
     for name in ("serving.sched.wait", "serving.sched.copy",
                  "serving.sched.emit", "serving.sched.launch"):
         assert seen.get(name) == 5, (name, seen.get(name))
+    # a walk behind each of the five launches and the prefill's call, and
+    # the flush that hands over the last token and the result
+    assert seen.get("serving.sched.deliver") == 5 + 1 + 1
     assert hist["serving.sched.wait_s"]["count"] == 5 + 1
     assert "serving.sched.iter" not in seen
 
@@ -364,14 +392,19 @@ def test_phases_and_trainer_spans_are_events_of_the_profilers_trace(
 # -- the benchmark's readers --------------------------------------------------
 
 @pytest.fixture
-def readers():
+def perf_path():
     perf = os.path.join(REPO, "perf")
     sys.path.append(perf)
     try:
-        from readers import registry_hist, registry_hist_share
-        yield registry_hist, registry_hist_share
+        yield perf
     finally:
         sys.path.remove(perf)
+
+
+@pytest.fixture
+def readers(perf_path):
+    from readers import registry_hist, registry_hist_share
+    return registry_hist, registry_hist_share
 
 
 def test_registry_readers_on_a_hand_filled_registry(readers):
@@ -405,3 +438,34 @@ def test_registry_readers_on_a_hand_filled_registry(readers):
                       under=["unit.sched.iter_s"]) is None
     telemetry.uninstall()
     assert read("p50") is None and share.read(None, None, **args) is None
+
+
+def test_the_delivery_metrics_read_through_the_harness(perf_path):
+    """The two metric files of ISSUE 33, loaded the way ``perf/run.py``
+    loads them (``perf/selftest.py`` holds them to ``BENCHMARK.json``):
+    listed in the four serving cells, and on a program without the two
+    counters (the parent) the share's reader returns None, so the metric
+    is left out of the line."""
+    import harness
+
+    names = ("sched_launch_p50_s", "sched_deliver_after_dispatch_share")
+    listed = harness.listed_metrics
+    assert not set(names) & set(listed("gpt2m_train_s1024")["per_layer"])
+    for cell in ("gpt2m_serve_batch_closed", "gpt2m_serve_label_closed",
+                 "mistral4_serve_doc_closed", "nemotron3_serve_rag_closed"):
+        assert set(names) <= set(listed(cell)["per_layer"]), cell
+    specs = {n: harness.load_json("metrics", n + ".json") for n in names}
+    read = lambda name: harness.load_module(
+        "readers", specs[name]["reader"]).read(
+            None, None, **specs[name]["args"])
+    assert read(names[0]) is None and read(names[1]) is None
+    for v in (0.0016, 0.0014, 0.0015):
+        telemetry.histogram("serving.sched.launch_s").record(v)
+    assert read(names[0]) == 0.0015
+    # the parent counts neither; a run that delivered nothing has no
+    # share either
+    telemetry.counter("serving.sched.delivered")
+    assert read(names[1]) is None
+    telemetry.counter("serving.sched.delivered").inc(200)
+    telemetry.counter("serving.sched.delivered_after_dispatch").inc(197)
+    assert read(names[1]) == pytest.approx(98.5)

@@ -201,6 +201,45 @@ def test_generate_streams_and_matches_local_engine(served, lm):
         gen.shutdown()
 
 
+def test_concurrent_streams_carry_every_token_before_the_result(served, lm):
+    """Eight clients on four lanes: the engine hands a step's tokens to
+    the handler threads after the next dispatch and a result behind its
+    last token, so each client's frames, put together, are its final
+    tokens: none is lost to the handler's done-then-empty exit."""
+    from distkeras_tpu.serving import GenerationEngine
+
+    model, params = lm
+    gen = GenerationEngine(model, params, num_slots=4, queue_capacity=16,
+                           prefill_buckets=(8,))
+    eng, srv = _stack_with_generator(served, generator=gen)
+    prompts = [np.arange(1 + k, 5 + k + k % 3, dtype=np.int32)
+               for k in range(8)]
+    streamed = [[] for _ in prompts]
+    results = [None] * len(prompts)
+    try:
+        def client(k):
+            cli = ServingClient(f"127.0.0.1:{srv.port}")
+            results[k] = cli.generate(prompts[k], max_new_tokens=6 + 3 * k,
+                                      on_token=streamed[k].append)
+            cli.close()
+
+        threads = [threading.Thread(target=client, args=(k,))
+                   for k in range(len(prompts))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+        for k, res in enumerate(results):
+            assert res.reason == "length" and res.tokens.size == 6 + 3 * k
+            assert streamed[k] == res.tokens.tolist(), k
+    finally:
+        srv.stop()
+        eng.shutdown()
+        gen.shutdown()
+    assert not gen._owed
+
+
 def test_generate_requires_auth(served, lm):
     from distkeras_tpu.serving import GenerationEngine
 

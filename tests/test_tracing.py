@@ -480,9 +480,11 @@ def test_generation_request_trace_covers_lifecycle(end, loop):
 
         def stream(tok):
             seen.append(tok)
-            if end == "deadline" and len(seen) == 4:
-                # on the scheduler thread: the deadline passes here, so
-                # the next iteration expires the request after 3 steps
+            if end == "deadline" and len(seen) == 3:
+                # on the scheduler thread, which hands token 3 over with
+                # step 3 already launched: the deadline passes here, so
+                # the next iteration expires the request after 3 steps,
+                # and its 4th token, still owed, reaches it first
                 time.sleep(max(0.0, t_late - time.monotonic()))
 
         root = telemetry.TraceContext.new_root()
