@@ -121,7 +121,8 @@ from distkeras_tpu.serving.batching import (DeadlineExceeded, EngineClosed,
                                             QueueFull)
 from distkeras_tpu.serving.buckets import BucketSpec
 from distkeras_tpu.serving.kv_cache import (KVCachePool, PagedKVCachePool,
-                                            PrefixCache, state_leaves)
+                                            PrefixCache, select_leaves,
+                                            state_leaves)
 from distkeras_tpu.utils import fault
 
 #: token id fed at the decode step's ghost position (its output is
@@ -195,7 +196,12 @@ def make_decode_fn(model):
     experts it holds each was sent to; the step then returns a third
     value, int32 ``[layers, experts_held]``: tokens per held expert,
     counted over the real position of the lanes that are not padding.
-    A model without experts returns two values and pays nothing.
+    A model without experts returns two values and pays nothing. A model
+    whose attention selects what it reads (:func:`select_leaves`) hands
+    back the positions each query attended, and the step a fourth value, an
+    int32 scalar: those of the same lanes' real position, summed over lanes
+    and layers. It is fed ``[token]`` alone as well: a ghost would cost
+    every lane a second selection and gather.
 
     This is the step's contract whoever compiles it. A greedy
     :class:`GenerationEngine` compiles it inside :func:`pick_on_device`,
@@ -203,11 +209,11 @@ def make_decode_fn(model):
     import jax
     import jax.numpy as jnp
 
-    ghost = not state_leaves(model)
+    ghost = not (state_leaves(model) or select_leaves(model))
 
     def decode(params, pool, slot_ids, tokens, lengths):
-        # a state a row would be advanced by the ghost too: such a model
-        # is fed its real token alone
+        # a state a row would be advanced by the ghost too, and a
+        # selection made for it: such a model is fed its real token alone
         ids = jnp.stack(
             [tokens, jnp.full_like(tokens, GHOST_TOKEN)], axis=1) \
             if ghost else tokens[:, None]
@@ -218,8 +224,10 @@ def make_decode_fn(model):
             return pool, logits[:, 0, :]
         scratch = jax.tree.leaves(pool)[0].shape[0] - 1
         live = (slot_ids != scratch)[None, :, None]
-        return pool, logits[:, 0, :], jnp.sum(
-            routed[0][:, :, 0, :] & live, axis=1, dtype=jnp.int32)
+        return (pool, logits[:, 0, :], jnp.sum(
+            routed[0][:, :, 0, :] & live, axis=1, dtype=jnp.int32),
+            *(jnp.sum(jnp.where(live[..., 0], attended[:, :, 0], 0))
+              for attended in routed[1:]))
 
     return decode
 
@@ -579,6 +587,21 @@ class GenerationEngine:
     ends with no lane left flushes before the scheduler waits or returns,
     and expiry, a scheduler error and a non-draining shutdown flush
     before they fail a request.
+
+    **Admission spacing.** A whole-prompt prefill stalls every decoding
+    lane for as long as it runs, and by default every free slot is filled
+    as soon as a request waits: a burst of arrivals (or lanes admitted
+    together retiring together) is then a run of prefills back to back,
+    during which no lane emits. A request holds one of ``num_slots`` lanes
+    for ``max_new_tokens`` steps, so in a full, steady pool one retires
+    every ``max_new_tokens / num_slots`` steps: its share of the pool's
+    steps. ``admit_spacing=c`` keeps ``c`` such shares of the request just
+    admitted between its admission and the next while any lane decodes (an
+    idle engine admits at once): a lane waits out one prefill in that many
+    steps, never a run of them, and a burst is served as a steady stream
+    of about ``num_slots / c`` lanes whatever the answers' lengths, since
+    a long answer buys the steps it will hold its lane for. ``c`` a little
+    over 1 leaves the few lanes empty that make admissions regular.
     """
 
     def __init__(self, model, params, *, num_slots: int = 4,
@@ -596,7 +619,7 @@ class GenerationEngine:
                  prefill_chunk: Optional[int] = None,
                  kv_dtype: Optional[str] = None,
                  sampling: bool = False, temperature: float = 1.0,
-                 seed: int = 0):
+                 seed: int = 0, admit_spacing: float = 0.0):
         import jax
 
         from distkeras_tpu.utils.jax_compat import enable_compilation_cache
@@ -635,6 +658,12 @@ class GenerationEngine:
                 f"length mask hides what a step writes there); it is "
                 f"served from the rectangular pool, whole prompts, one "
                 f"token a step: no {', '.join(asked)}")
+        if select_leaves(model) and asked:
+            raise ValueError(
+                f"{type(model).__name__} attends a selection of its cache "
+                f"(cache leaf {select_leaves(model)[0]!r} is read before "
+                f"any mask); it is served from the rectangular pool, whole "
+                f"prompts, one token a step: no {', '.join(asked)}")
         if prefix_cache_bytes and not self._paged:
             raise ValueError(
                 "prefix_cache_bytes requires page_size: the prefix cache "
@@ -673,6 +702,15 @@ class GenerationEngine:
             raise ValueError(
                 f"temperature must be > 0, got {temperature}")
         self._seed = int(seed)
+        if admit_spacing < 0:
+            raise ValueError(
+                f"admit_spacing must be >= 0, got {admit_spacing}")
+        # decode steps between two admissions while a lane decodes, in
+        # shares of the admitted request (class docstring, "Admission
+        # spacing"); 0: every free slot at once
+        self._admit_spacing = float(admit_spacing)
+        self._admit_wait = 0.0          # steps the last admission bought
+        self._steps_since_admit = 0
         self._req_seq = 0  # submission index: per-request stream ids
         # a greedy engine that parks no logits wants an index from its
         # decode step: the token is chosen inside it (pick_on_device)
@@ -791,6 +829,13 @@ class GenerationEngine:
                 "serving.moe.experts_active")
             self._moe_load_h = telemetry.histogram(
                 "serving.moe.load_max_over_mean")
+        if select_leaves(model):
+            # its decode step hands back the positions attended
+            # (make_decode_fn's fourth value)
+            self._sparse_cached_c = telemetry.counter(
+                "serving.sparse.positions_cached")
+            self._sparse_attended_c = telemetry.counter(
+                "serving.sparse.positions_attended")
         if self._sampling and self._spec_k:
             self._spec_s_accepts_c = telemetry.counter(
                 "serving.decode.spec.sampled_accepts")
@@ -1280,6 +1325,7 @@ class GenerationEngine:
                         self._chunk_step(active, prefilling)
                 if active:
                     self._decode_step(active)
+                    self._steps_since_admit += 1
                 if not active and not prefilling:
                     # no lane left, so no dispatch is sure to come:
                     # nothing stays owed over a wait or a return
@@ -1324,8 +1370,12 @@ class GenerationEngine:
         """Move queued requests into free slots (prefill each). Runs
         every iteration — admission interleaves with in-flight decode.
         Under chunked prefill a request parks in ``prefilling`` with a
-        cursor instead of paying its whole prefill here."""
+        cursor instead of paying its whole prefill here. Under
+        ``admit_spacing`` a request waits, queued, until the lanes have
+        decoded the steps that the last admission bought."""
         while self.pool.num_free > 0:
+            if active and self._steps_since_admit < self._admit_wait:
+                return
             with self._cv:
                 if not self._dq:
                     return
@@ -1353,6 +1403,9 @@ class GenerationEngine:
                     self._dq.appendleft(req)
                     self._depth_g.set(len(self._dq))
                 return
+            self._steps_since_admit = 0
+            self._admit_wait = self._admit_spacing * req.max_new_tokens \
+                / self.pool.num_slots
             if self._chunk is not None:
                 parked = self._start_chunked(req, slot, prefilling)
                 self._admitted_c.inc()
@@ -1724,6 +1777,8 @@ class GenerationEngine:
             self._tps_g.set(n / dt)
         if routed:
             self._record_routing(routed[0], n)
+        if len(routed) > 1:
+            self._record_attended(int(routed[1]), lengths[:n] + 1)
         # the lane loop is bookkeeping alone: it wakes nobody, and notes
         # lane by lane (the order a client sees) what _deliver hands over
         # once the next dispatch is with the device. One annotation around
@@ -1766,6 +1821,16 @@ class GenerationEngine:
         if mean.any():
             self._moe_load_h.record(
                 float((held.max(axis=1)[mean > 0] / mean[mean > 0]).mean()))
+
+    def _record_attended(self, attended: int, contexts: np.ndarray) -> None:
+        """A decode step's reads into ``serving.sparse.*``: the positions
+        its lanes' queries attended, summed over lanes and layers on the
+        device (make_decode_fn), beside those their contexts have
+        (``contexts``, a lane's length with the token the step wrote, times
+        the attention layers)."""
+        self._sparse_attended_c.inc(attended)
+        self._sparse_cached_c.inc(
+            int(contexts.sum()) * self.model.num_layers)
 
     def _sampled_accept_walk(self, req: _GenRequest, props_i, logits_i):
         """Host side of sampling-capable speculative verification
@@ -2056,6 +2121,7 @@ class GenerationEngine:
             "cache_bytes": self.pool.cache_bytes,
             "prefill_buckets": list(self._buckets.sizes),
             "decode_ladder": list(self._ladder.sizes),
+            "admit_spacing": self._admit_spacing,
             "compiled": {k: list(v) for k, v in
                          self.compiled_executables.items()},
             "model_version": self.model_version,

@@ -27,7 +27,11 @@ The model says what its cache is: the pool builds its leaves with
 ``model.cache_bytes_per_row(dtype)`` (the cache protocol of
 models/gpt.py; ``CausalLM`` answers ``{"k", "v"}`` lines of ``width``,
 models/latent_moe.py one latent line a position), and never looks inside
-a leaf beyond its leading rows.
+a leaf beyond its leading rows. A family may keep more than one rows x
+positions leaf a layer (models/latent_moe.py under an indexer: the latent
+line and the index key), and leaves of different kinds and widths layer by
+layer (its window layers keep a ring of a fixed number of positions, a
+state leaf, beside the full layers' ``max_len`` rows).
 
 Capacity is budgeted *before* allocation: ``cache_bytes`` multiplies
 ``model.cache_bytes_per_row`` by the row count, and on devices
@@ -71,6 +75,16 @@ def state_leaves(model) -> Tuple[str, ...]:
     (``cache_state_leaves``; DESIGN.md section 14). Empty for a family
     whose every leaf is rows x positions."""
     return tuple(getattr(model, "cache_state_leaves", ()))
+
+
+def select_leaves(model) -> Tuple[str, ...]:
+    """Names of the rows x positions cache leaves ``model`` declares as
+    read by a selection (``cache_select_leaves``; DESIGN.md section 14):
+    a top-k chooses before any mask, so the model itself keeps what lies
+    past a lane's length from being chosen, and the engine serves it from
+    the rectangular pool alone. Empty for a family that attends all it
+    holds."""
+    return tuple(getattr(model, "cache_select_leaves", ()))
 
 
 class KVCachePool:

@@ -296,6 +296,14 @@ METRIC_NAMES = {
     "serving.moe.assignments_held": "counter",
     "serving.moe.experts_active": "histogram",
     "serving.moe.load_max_over_mean": "histogram",
+    # attention that reads less than a lane holds (models/latent_moe.py
+    # under an indexer or with window layers): the positions the decode
+    # step's queries attended, reduced on the device (make_decode_fn's
+    # fourth value), beside the positions their contexts have, both summed
+    # over lanes and attention layers
+    # (GenerationEngine._record_attended); only such a model
+    "serving.sparse.positions_attended": "counter",
+    "serving.sparse.positions_cached": "counter",
     # prefill's padding: real prompt tokens, and the bucket (or chunk)
     # positions computed for them (GenerationEngine, every family)
     "serving.prefill.positions": "counter",

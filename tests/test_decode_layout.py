@@ -203,3 +203,68 @@ def test_greedy_step_hands_back_tokens_and_keeps_the_pool(
     assert len(_pool_layouts(text)) == 2 * leaves
     least = QUARTER if line is None else 8 * 4608 * 384 // 4
     assert _rectangle_moves(text, least=least, line=line) == []
+
+
+# ------------- two position leaves and a ring, layers of two kinds (PR 35)
+
+@pytest.fixture(scope="module")
+def two_kind_shapes(one_chip):
+    """dots3-note-prev's widths (a full layer: latent 512 + rotary 64, 128
+    heads, 64 index heads of 128; a window layer: latent 1024 + rotary 64,
+    64 heads, window 513 in a ring of 640; 32 of 256 experts held), a
+    dense full layer and a window layer with experts, a 32-slot pool of
+    11 264 positions: three leaves of three widths."""
+    from distkeras_tpu.models.latent_moe import LatentMoELM, WindowSizes
+
+    model = LatentMoELM(
+        vocab_size=19008, max_len=11264, num_layers=2, width=5120,
+        num_heads=128, q_lora_rank=1024, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        moe_width=1536, num_experts=256, experts_per_token=8,
+        expert_share=(0, 8), rms_eps=1e-5, rope_theta=8e7, dense_layers=1,
+        dense_width=13824, scoring="sigmoid", index_heads=64, index_dim=128,
+        index_topk=2048, layer_kinds=("F", "S"),
+        window_sizes=WindowSizes(
+            window=513, ring=640, num_heads=64, q_lora_rank=1024,
+            kv_lora_rank=1024, qk_nope_head_dim=192, qk_rope_head_dim=64,
+            v_head_dim=128, rope_theta=5e4),
+        head_gate=True, rank_rescale=True)
+    put = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = put(jax.eval_shape(
+        lambda: model.init(jax.random.key(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"]))
+    pool = put(jax.eval_shape(lambda: model.init_cache(NUM_SLOTS + 1)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32,
+                                              sharding=one_chip)
+    return model, params, pool, i32
+
+
+@pytest.mark.parametrize("step,size", [("decode", 32), ("prefill", 6144)])
+def test_compiled_two_kind_step_keeps_all_three_leaves_as_stored(
+        two_kind_shapes, step, size):
+    """The latent line (576 -> 640), the index key (128, one whole tile)
+    and the ring's wider line (1088 -> 1152) are each stored as written,
+    and neither the score over the lanes' keys, nor the gather of the
+    chosen lines, nor the read of the lanes' rings copies a leaf's
+    rectangle (a narrow last dimension turned the pool round once:
+    PERF.md, PR 25). The four scopes name the compiled instructions."""
+    model = two_kind_shapes[0]
+    assert model.cache_line == 640      # 512 + 64, padded to whole tiles
+    text = _compile(two_kind_shapes, step, size)
+    rows = NUM_SLOTS + 1
+    assert sorted(_pool_layouts(text)) == sorted(
+        [(f"{rows},11264,128", "2,1,0"), (f"{rows},11264,640", "2,1,0"),
+         (f"{rows},640,1152", "2,1,0")] * 2)    # parameters and results
+    for scope in ("attn.index", "attn.select", "attn.window", "attn.sparse"
+                  if step == "decode" else "attn.latent"):
+        assert f"/{scope}/" in text, scope
+    # a leaf's positions on an axis: a prefill turns its own block's lines
+    # round ([6144, 640], [6144, 1152]: the long-block forms' cost), which
+    # is no leaf
+    for line, positions in ((128, 11264), (640, 11264), (1152, 640)):
+        moves = _rectangle_moves(text, least=8 * positions * line // 4,
+                                 line=line)
+        assert [m for m in moves
+                if re.search(rf"[\[,]{positions},{line}\]", m)] == [], moves

@@ -668,3 +668,95 @@ def test_delivered_counts_what_was_owed_and_most_follows_a_dispatch(lm):
     assert counters["serving.sched.delivered_after_dispatch"] >= \
         0.9 * delivered
     assert counters["serving.decode.trace_rows"] == 4 * n
+
+
+# ------------------------------------------------------ admission spacing
+
+def _log_dispatches(eng, log, gate):
+    """``"P"`` / ``"S"`` into ``log`` at every prefill / decode dispatch;
+    the first prefill waits for ``gate``, so that a burst is queued whole
+    before the scheduler admits its first request."""
+    def noting(ex, mark):
+        def call(*args):
+            gate.wait(timeout=60)
+            log.append(mark)
+            return ex(*args)
+        return call
+
+    for table, mark in ((eng._prefill_exec, "P"), (eng._decode_exec, "S")):
+        for key in list(table):
+            table[key] = noting(table[key], mark)
+
+
+def _burst(lm, n=4, answer=12, **kw):
+    """``n`` requests queued together on ``n`` slots: the dispatch log and
+    the answers."""
+    model, params = lm
+    log, gate = [], threading.Event()
+    with GenerationEngine(model, params, num_slots=n, queue_capacity=16,
+                          prefill_buckets=(8,), **kw) as eng:
+        _log_dispatches(eng, log, gate)
+        futs = [eng.generate(_prompt(4 + k % 3, k), max_new_tokens=answer)
+                for k in range(n)]
+        gate.set()
+        answers = [f.result(timeout=120).tokens.tolist() for f in futs]
+    return "".join(log), answers
+
+
+def test_by_default_a_burst_is_prefilled_back_to_back(lm):
+    log, answers = _burst(lm)
+    assert log.startswith("PPPPS") and log.count("P") == 4
+    assert all(len(a) == 12 for a in answers)
+
+
+@pytest.mark.parametrize("shares, steps", [(0.5, 2), (1.0, 3), (2.0, 6)])
+def test_admit_spacing_puts_decode_steps_between_a_bursts_prefills(
+        lm, shares, steps):
+    """One admission, then ``shares`` times the request's share of the
+    pool's steps (12 tokens on 4 slots: 3 steps, a part of a step counting
+    whole), then the next: no lane waits out two prefills in a row. The
+    answers are the unspaced engine's: when a request is admitted changes
+    no token of it."""
+    log, answers = _burst(lm, admit_spacing=shares)
+    assert log.startswith(("P" + "S" * steps) * 3 + "P")
+    assert log.count("P") == 4
+    assert answers == _burst(lm)[1]
+
+
+def test_a_longer_answer_buys_more_steps_before_the_next_admission(lm):
+    """The spacing is the admitted request's own: 24 tokens on 3 slots
+    keep 8 steps to themselves, the 6 tokens admitted next keep 2."""
+    model, params = lm
+    log, gate = [], threading.Event()
+    with GenerationEngine(model, params, num_slots=3, queue_capacity=16,
+                          prefill_buckets=(8,), admit_spacing=1.0) as eng:
+        _log_dispatches(eng, log, gate)
+        futs = [eng.generate(_prompt(5, k), max_new_tokens=n)
+                for k, n in enumerate((24, 6, 6))]
+        gate.set()
+        assert [f.result(timeout=60).tokens.size for f in futs] == [24, 6, 6]
+    assert "".join(log).startswith("P" + "S" * 8 + "P" + "SS" + "P")
+
+
+def test_admit_spacing_counts_only_while_a_lane_decodes(lm):
+    """An engine whose lanes have all retired admits the next request at
+    once, whatever the spacing: with one slot every admission finds no
+    lane decoding."""
+    model, params = lm
+    log, gate = [], threading.Event()
+    gate.set()
+    with GenerationEngine(model, params, num_slots=1, queue_capacity=16,
+                          prefill_buckets=(8,), admit_spacing=50.0) as eng:
+        _log_dispatches(eng, log, gate)
+        futs = [eng.generate(_prompt(5, k), max_new_tokens=3)
+                for k in range(3)]
+        assert all(f.result(timeout=60).tokens.size == 3 for f in futs)
+        assert eng.health_status()["admit_spacing"] == 50
+    assert "".join(log) == "PSS" * 3
+
+
+def test_admit_spacing_is_not_negative(lm):
+    model, params = lm
+    with pytest.raises(ValueError, match="admit_spacing"):
+        GenerationEngine(model, params, num_slots=1, prefill_buckets=(8,),
+                         admit_spacing=-1)

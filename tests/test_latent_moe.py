@@ -446,6 +446,9 @@ def test_engine_serves_the_family_and_its_counters_add_up(tiny):
     assert active["count"] == steps and load["count"] == steps
     assert 0 < active["max"] <= model.experts_held
     assert load["min"] >= 1.0
+    # a family that attends all it holds counts no positions attended
+    assert not any(name.startswith("serving.sparse.")
+                   for name in snap["counters"])
 
 
 def test_decode_step_counts_sum_to_the_assignments_held(tiny):
