@@ -11,6 +11,7 @@ the same bfloat16 weights, see ``BF16_TOLERANCE`` below.
 """
 
 import functools
+import hashlib
 import os
 import sys
 
@@ -29,7 +30,9 @@ from distkeras_tpu.serving.kv_cache import PagedKVCachePool
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "perf"))
+from reference import dots3_note as ref_dots3  # noqa: E402
 from reference import latent_moe as ref  # noqa: E402
+from reference import nemotron_h as ref_nemotron  # noqa: E402
 
 F32_TOLERANCE = 1e-5
 #: the program in bfloat16 (parameters and products; float32 router, norms,
@@ -290,6 +293,101 @@ def test_router_ties_and_an_expert_without_a_token(tokens):
     assert not (routed[:, 1] & ~routed[:, 0]).any()   # the lower index first
     assert bool(jnp.isfinite(out).all())
     assert rel(out, _ref_moe(params, x, (0, 1))) < F32_TOLERANCE
+
+
+#: scoring rule, expert form and the reference that writes them down
+FAMILIES = {"softmax_swiglu": ("softmax", "swiglu", ref),
+            "sigmoid_relu2": ("sigmoid", "relu2", ref_nemotron),
+            "sigmoid_swiglu": ("sigmoid", "swiglu", ref_dots3)}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+@pytest.mark.parametrize("tokens,k", [(300, 2), (515, 8)],
+                         ids=["300x2", "515x8"])
+@pytest.mark.parametrize("routing",
+                         ["every_held", "none_held", "one_takes_all"])
+def test_many_token_path_is_dropless_for_any_routing(routing, tokens, k,
+                                                     family):
+    """The loop over blocks computes every assignment to a held expert and
+    nothing else, whatever the router does: every choice held (share 0 of
+    1: a token's k parts all add into its row); none held (this share's
+    router outputs far below the rest: no block in use, the shared expert
+    alone, nothing read from a row that is not the token's); one held
+    expert taking every token and the other three none (two or three
+    blocks of one expert, the last ragged). Neither ``tokens`` nor
+    ``tokens * k`` is a multiple of the block."""
+    scoring, activation, reference = FAMILIES[family]
+    assert tokens > lm._DENSE_MAX_TOKENS and tokens % lm._EXPERT_BLOCK \
+        and (tokens * k) % lm._EXPERT_BLOCK
+    share = (0, 1) if routing == "every_held" else (1, 4)
+    layer = lm.ExpertShare(
+        width=16, num_experts=16, experts_per_token=k, expert_share=share,
+        routed_scaling=2.5, dtype=jnp.float32, scoring=scoring,
+        activation=activation)
+    x = jax.random.normal(jax.random.key(tokens), (tokens, 32)
+                          ).at[:, 0].set(1.0)
+    params = jax.jit(layer.init)(jax.random.key(k), x)["params"]
+    # router outputs 4..7 are this share's (the second of four)
+    column = lambda r, e, logit: r.at[:, e].set(0.0).at[0, e].set(logit)
+    router = params["router"]
+    if routing == "none_held":
+        for e in range(4, 8):
+            router = column(router, e, -50.0)
+    elif routing == "one_takes_all":
+        for e in (4, 6, 7):
+            router = column(router, e, -50.0)
+        router = column(router, 5, 50.0)
+    params = dict(params, router=router)
+    if scoring == "sigmoid" and routing != "every_held":
+        bias = params["router_bias"].at[4:8].set(-9.0)
+        if routing == "one_takes_all":
+            bias = bias.at[5].set(9.0)
+        params = dict(params, router_bias=bias)
+    held = 16 // share[1]
+    part = dict(params, **{
+        name: params[name][:held] for name in ("gate", "up", "down")
+        if name in params})
+    out, routed = _apply(layer, part, x)
+    z = {"k": k, "index": share[0], "routed_scaling": 2.5}
+    want = jax.jit(lambda p, x: reference.moe(p, x, z))(part, x)
+    assert bool(jnp.isfinite(out).all())
+    assert rel(out, want) < F32_TOLERANCE
+    sent = np.asarray(routed).sum(axis=0)
+    if routing == "every_held":
+        assert sent.sum() == tokens * k
+    elif routing == "none_held":
+        assert sent.sum() == 0      # and what is wanted is the shared alone
+        assert not np.asarray(reference.routed_part(part, x, z)).any()
+    else:
+        assert sent.tolist() == [0, tokens, 0, 0]
+
+
+#: sha256 (first 16 digits) of what the layer below lowers to at 200
+#: tokens, the few-token branch, at the commit before the many-token path
+#: stopped laying out rows (PR 35)
+PARENT_DENSE_TEXT = "45d91866c0bcdc0c"
+
+
+def test_many_token_path_lays_out_indices_and_not_rows():
+    """512 tokens, top 8 of 32 experts, 4 of them held, rows of 64: the
+    lowered layer has no array of ``n * k`` or ``n * k + held * 256`` rows
+    of the model's width and no ``[n, k, d]`` pass (the parent's text had
+    all three); at 200 tokens, the other branch, the text is the
+    parent's."""
+    layer = lm.ExpertShare(width=16, num_experts=32, experts_per_token=8,
+                           expert_share=(0, 8))
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    params = jax.eval_shape(lambda: layer.init(
+        jax.random.key(0), jnp.zeros((8, 64)))["params"])
+    lowered = lambda n: jax.jit(
+        lambda p, x: layer.apply({"params": p}, x)).lower(
+            params, f32(n, 64)).as_text()
+    text = lowered(512)
+    assert "tensor<256x64x" in text and "tensor<768x64xf32>" in text
+    for rows in ("tensor<4096x64x", "tensor<5120x64x", "tensor<512x8x64x"):
+        assert rows not in text, rows
+    assert hashlib.sha256(lowered(200).encode()).hexdigest()[:16] == \
+        PARENT_DENSE_TEXT
 
 
 # ------------------------------------------------------ rotary embedding
