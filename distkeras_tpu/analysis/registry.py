@@ -47,7 +47,6 @@ _CONSUMER_PATHS = (
     "distkeras_tpu/health/summary.py",
     "distkeras_tpu/profiling/cost_model.py",
     "distkeras_tpu/profiling/roofline.py",
-    "distkeras_tpu/profiling/capture.py",
     "distkeras_tpu/health/export.py",
     "distkeras_tpu/health/endpoints.py",
     "distkeras_tpu/health/slo.py",

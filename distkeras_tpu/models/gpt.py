@@ -97,6 +97,15 @@ from distkeras_tpu.ops.attention import MASK_VALUE, dot_product_attention
 from distkeras_tpu.ops.cache_rows import gather_rows
 from distkeras_tpu.ops.ring_attention import ring_attention
 
+#: the ``jax.named_scope`` names this file's forward declares, serve and
+#: train: every operation it traces lies under one, and
+#: ``profiling/scopes.py`` gives an executable's instruction to the
+#: innermost one on its ``op_name`` path. ``attn.cache`` is the lanes' K and
+#: V rows read out of the pool (or a row's pages), ``cache.write`` the
+#: block's lines written into it
+SCOPES = ("embed", "norm", "attn.qkv", "attn.cache", "attn.scores",
+          "attn.out", "cache.write", "mlp", "head")
+
 #: most query rows (block positions x heads) the cache attention spreads
 #: over the width: up to one pass of the MXU's rows the spread costs no
 #: more than streaming K and V once, past it `heads` times the matmul
@@ -155,18 +164,29 @@ class CausalSelfAttention(nn.Module):
                                                       self.dtype)
         width = x.shape[-1]
         head_dim = width // self.num_heads
-        qkv = nn.Dense(3 * width, dtype=dtype, name="qkv", **dense_kw)(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        split = lambda t: t.reshape(t.shape[:2] + (self.num_heads, head_dim))
-        q, k, v = split(q), split(k), split(v)
+        with jax.named_scope("attn.qkv"):
+            qkv = nn.Dense(3 * width, dtype=dtype, name="qkv", **dense_kw)(x)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            split = lambda t: t.reshape(
+                t.shape[:2] + (self.num_heads, head_dim))
+            q, k, v = split(q), split(k), split(v)
+
+        def project(out):
+            """The heads' outputs side by side through ``out``."""
+            with jax.named_scope("attn.out"):
+                out = out.reshape(out.shape[:2] + (width,))
+                return nn.Dense(width, dtype=dtype, name="out",
+                                **dense_kw)(out)
+
         if cache is not None:
             if self.attention != "full":
                 raise ValueError(
                     f"KV-cache decode requires attention='full', got "
                     f"{self.attention!r}")
             b, t = x.shape[:2]
-            rows = jnp.arange(b)[:, None]
-            pos = cache_index[:, None] + jnp.arange(t)[None, :]  # [b, t]
+            with jax.named_scope("cache.write"):
+                rows = jnp.arange(b)[:, None]
+                pos = cache_index[:, None] + jnp.arange(t)[None, :]  # [b, t]
             if page_table is not None:
                 from distkeras_tpu.ops.pallas import flash_attention as _fa
 
@@ -177,10 +197,7 @@ class CausalSelfAttention(nn.Module):
                     out, new_cache = _paged_int8_attention(
                         q, k, v, cache, page_table, pos, cache_index,
                         _fa)
-                    out = out.reshape(out.shape[:2] + (width,))
-                    out = nn.Dense(width, dtype=dtype, name="out",
-                                   **dense_kw)(out)
-                    return out, new_cache
+                    return project(out), new_cache
                 ps = cache["k"].shape[1]
                 pmax = page_table.shape[1]
                 max_len = pmax * ps
@@ -197,21 +214,23 @@ class CausalSelfAttention(nn.Module):
                 # bitwise unchanged — and it lets the paged kernel read
                 # pages[page_table] directly.
                 scratch_page = cache["k"].shape[0] - 1
-                page_idx = jnp.clip(pos // ps, 0, pmax - 1)
-                phys = jnp.take_along_axis(page_table, page_idx, axis=1)
-                phys = jnp.where(pos < max_len, phys, scratch_page)
-                off = jnp.where(pos < max_len, pos % ps, 0)
-                new_cache = {"k": cache["k"].at[phys, off].set(k),
-                             "v": cache["v"].at[phys, off].set(v)}
+                with jax.named_scope("cache.write"):
+                    page_idx = jnp.clip(pos // ps, 0, pmax - 1)
+                    phys = jnp.take_along_axis(page_table, page_idx, axis=1)
+                    phys = jnp.where(pos < max_len, phys, scratch_page)
+                    off = jnp.where(pos < max_len, pos % ps, 0)
+                    new_cache = {"k": cache["k"].at[phys, off].set(k),
+                                 "v": cache["v"].at[phys, off].set(v)}
                 if _fa.paged_dispatch(q.shape, cache["k"].shape,
                                       page_table.shape, q.dtype):
                     # fused paged kernel (DESIGN.md §23): the page DMAs
                     # are indexed by page_table INSIDE the kernel grid —
                     # the dense [b, max_len] HBM view below is never
                     # materialized (DESIGN.md §19's honest limit)
-                    out = _fa.paged_flash_attention(
-                        q, new_cache["k"], new_cache["v"], page_table,
-                        cache_index, interpret=_fa.PAGED_INTERPRET)
+                    with jax.named_scope("attn.scores"):
+                        out = _fa.paged_flash_attention(
+                            q, new_cache["k"], new_cache["v"], page_table,
+                            cache_index, interpret=_fa.PAGED_INTERPRET)
                 else:
                     # XLA fallback: gather each row's pages into the
                     # SAME dense [b, max_len, heads, head_dim] view the
@@ -219,17 +238,16 @@ class CausalSelfAttention(nn.Module):
                     # value-identical — bitwise parity)
                     gather = lambda pages: pages[page_table].reshape(
                         b, max_len, self.num_heads, head_dim)
-                    k_cache = gather(new_cache["k"])
-                    v_cache = gather(new_cache["v"])
-                    key_pos = jnp.arange(max_len)
-                    mask = (key_pos[None, None, None, :]
-                            <= pos[:, None, :, None])
-                    out = dot_product_attention(q, k_cache, v_cache,
-                                                mask=mask)
-                out = out.reshape(out.shape[:2] + (width,))
-                out = nn.Dense(width, dtype=dtype, name="out",
-                               **dense_kw)(out)
-                return out, new_cache
+                    with jax.named_scope("attn.cache"):
+                        k_cache = gather(new_cache["k"])
+                        v_cache = gather(new_cache["v"])
+                    with jax.named_scope("attn.scores"):
+                        key_pos = jnp.arange(max_len)
+                        mask = (key_pos[None, None, None, :]
+                                <= pos[:, None, :, None])
+                        out = dot_product_attention(q, k_cache, v_cache,
+                                                    mask=mask)
+                return project(out), new_cache
             # rectangular cache, leaves [rows, max_len, width]: write the
             # block's lines into their rows in place FIRST (the paged
             # branch's argument: every cell this changes is an in-call
@@ -237,36 +255,39 @@ class CausalSelfAttention(nn.Module):
             # they lie. mode="drop": a position past max_len-1 (the
             # decode step's ghost, DESIGN.md §14) must not clamp onto
             # the last real cell
-            if cache_rows is not None:
-                rows = cache_rows[:, None]
             lines = lambda a: a.reshape(b, t, width)
-            new_cache = {
-                "k": cache["k"].at[rows, pos].set(lines(k), mode="drop"),
-                "v": cache["v"].at[rows, pos].set(lines(v), mode="drop")}
-            out = _attend_rows(lines(q),
-                               gather_rows(new_cache["k"], cache_rows),
-                               gather_rows(new_cache["v"], cache_rows),
-                               pos, self.num_heads)
-            out = nn.Dense(width, dtype=dtype, name="out", **dense_kw)(out)
-            return out, new_cache
-        if self.attention == "ring":
-            out = ring_attention(q, k, v, axis_name=self.axis_name,
-                                 causal=True)
-        elif self.attention == "flash":
-            # resolve()-style dispatch (ops/attention.py): in-repo fused
-            # kernel when enabled+fits, else the upstream pallas kernel;
-            # off-TPU it raises rather than run XLA under this name
-            from distkeras_tpu.ops.attention import apply_attention
+            with jax.named_scope("cache.write"):
+                if cache_rows is not None:
+                    rows = cache_rows[:, None]
+                new_cache = {
+                    "k": cache["k"].at[rows, pos].set(lines(k), mode="drop"),
+                    "v": cache["v"].at[rows, pos].set(lines(v), mode="drop")}
+            with jax.named_scope("attn.cache"):
+                k_rows = gather_rows(new_cache["k"], cache_rows)
+                v_rows = gather_rows(new_cache["v"], cache_rows)
+            with jax.named_scope("attn.scores"):
+                out = _attend_rows(lines(q), k_rows, v_rows, pos,
+                                   self.num_heads)
+            return project(out), new_cache
+        with jax.named_scope("attn.scores"):
+            if self.attention == "ring":
+                out = ring_attention(q, k, v, axis_name=self.axis_name,
+                                     causal=True)
+            elif self.attention == "flash":
+                # resolve()-style dispatch (ops/attention.py): in-repo fused
+                # kernel when enabled+fits, else the upstream pallas kernel;
+                # off-TPU it raises rather than run XLA under this name
+                from distkeras_tpu.ops.attention import apply_attention
 
-            out = apply_attention(q, k, v, causal=True, attention="flash")
-        elif self.attention == "full":
-            out = dot_product_attention(q, k, v, causal=True)
-        else:
-            raise ValueError(
-                f"Unknown attention {self.attention!r}; "
-                "expected 'full', 'flash', or 'ring'")
-        out = out.reshape(out.shape[:2] + (width,))
-        return nn.Dense(width, dtype=dtype, name="out", **dense_kw)(out)
+                out = apply_attention(q, k, v, causal=True,
+                                      attention="flash")
+            elif self.attention == "full":
+                out = dot_product_attention(q, k, v, causal=True)
+            else:
+                raise ValueError(
+                    f"Unknown attention {self.attention!r}; "
+                    "expected 'full', 'flash', or 'ring'")
+        return project(out)
 
 
 class DecoderBlock(nn.Module):
@@ -281,7 +302,8 @@ class DecoderBlock(nn.Module):
     def __call__(self, x, train: bool = False, cache=None, cache_index=None,
                  page_table=None, cache_rows=None):
         dtype = precision_lib.resolve(self.precision, self.dtype)[0]
-        y = nn.LayerNorm(dtype=jnp.float32, name="ln1")(x).astype(dtype)
+        with jax.named_scope("norm"):
+            y = nn.LayerNorm(dtype=jnp.float32, name="ln1")(x).astype(dtype)
         attn = CausalSelfAttention(self.num_heads, self.dtype, self.attention,
                                    self.axis_name, precision=self.precision,
                                    name="attn")
@@ -290,11 +312,16 @@ class DecoderBlock(nn.Module):
                                 cache_rows)
         else:
             y, new_cache = attn(y), None
-        x = x + y
-        y = nn.LayerNorm(dtype=jnp.float32, name="ln2")(x).astype(dtype)
-        y = MlpBlock(self.mlp_dim, 0.0, self.dtype,
-                     precision=self.precision, name="mlp")(y, train=train)
-        x = x + y
+        # a residual sum goes with the sub-layer whose result it takes: the
+        # compiler fuses it into that product
+        with jax.named_scope("attn.out"):
+            x = x + y
+        with jax.named_scope("norm"):
+            y = nn.LayerNorm(dtype=jnp.float32, name="ln2")(x).astype(dtype)
+        with jax.named_scope("mlp"):
+            y = MlpBlock(self.mlp_dim, 0.0, self.dtype,
+                         precision=self.precision, name="mlp")(y, train=train)
+            x = x + y
         return x if new_cache is None else (x, new_cache)
 
 
@@ -357,16 +384,28 @@ class CausalLM(nn.Module):
         ids = input_ids.astype(jnp.int32)
         b, t = ids.shape  # t = LOCAL block length under sequence parallelism
         embed_cls = remat_wrap(nn.Embed, self.remat, stem=True)
-        x = embed_cls(self.vocab_size, self.width, dtype=dtype,
-                      name="tok_embed")(ids)
+        with jax.named_scope("embed"):
+            x = embed_cls(self.vocab_size, self.width, dtype=dtype,
+                          name="tok_embed")(ids)
         pos_table = self.param("pos_embed", nn.initializers.normal(0.02),
                                (self.max_len, self.width))
+
+        def head(x):
+            with jax.named_scope("norm"):
+                x = nn.LayerNorm(dtype=jnp.float32, name="ln_final")(x)
+            with jax.named_scope("head"):
+                logits = nn.Dense(self.vocab_size, dtype=jnp.float32,
+                                  name="lm_head")(x)
+                return logits.astype(jnp.float32)
+
         if cache is not None:
             # decode mode: positions come from each row's cache cursor;
             # blocks run un-rematted (inference) but with identical param
             # structure, so trained checkpoints serve as-is
-            pos = pos_table[cache_index[:, None] + jnp.arange(t)[None, :]]
-            x = x + pos.astype(dtype)
+            with jax.named_scope("embed"):
+                pos = pos_table[
+                    cache_index[:, None] + jnp.arange(t)[None, :]]
+                x = x + pos.astype(dtype)
             new_cache = []
             for i in range(self.num_layers):
                 x, layer_cache = DecoderBlock(
@@ -376,10 +415,7 @@ class CausalLM(nn.Module):
                         x, train, cache=cache[i], cache_index=cache_index,
                         page_table=page_table, cache_rows=cache_rows)
                 new_cache.append(layer_cache)
-            x = nn.LayerNorm(dtype=jnp.float32, name="ln_final")(x)
-            logits = nn.Dense(self.vocab_size, dtype=jnp.float32,
-                              name="lm_head")(x)
-            return logits.astype(jnp.float32), tuple(new_cache)
+            return head(x), tuple(new_cache)
         if self.attention == "ring":
             # global positions of this device's block. psum(1) over the mesh
             # axis is concrete at trace time, so this bound check is static —
@@ -390,11 +426,13 @@ class CausalLM(nn.Module):
                 raise ValueError(
                     f"global sequence {t}*{num_blocks} exceeds max_len "
                     f"{self.max_len}")
-            offset = jax.lax.axis_index(self.axis_name) * t
-            pos = jax.lax.dynamic_slice_in_dim(pos_table, offset, t)
+            with jax.named_scope("embed"):
+                offset = jax.lax.axis_index(self.axis_name) * t
+                pos = jax.lax.dynamic_slice_in_dim(pos_table, offset, t)
         else:
-            pos = pos_table[:t]
-        x = x + pos.astype(dtype)
+            pos = pos_table[:t]     # a static slice: no operation of its own
+        with jax.named_scope("embed"):
+            x = x + pos.astype(dtype)
         # positional call, train static at index 2 (models/remat.py rules)
         block_cls = remat_wrap(DecoderBlock, self.remat, static_argnums=(2,))
         for i in range(self.num_layers):
@@ -402,10 +440,7 @@ class CausalLM(nn.Module):
                           self.attention, self.axis_name,
                           precision=self.precision,
                           name=f"layer_{i}")(x, train)
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_final")(x)
-        logits = nn.Dense(self.vocab_size, dtype=jnp.float32,
-                          name="lm_head")(x)
-        return logits.astype(jnp.float32)
+        return head(x)
 
 
 def init_cache(model, batch: int, dtype=None):
@@ -519,54 +554,58 @@ def _paged_int8_attention(q, k, v, cache, page_table, pos, cache_index,
         # clamp onto the last real cell, same rule as the native path
         return view.at[rows, pos].set(block.astype(jnp.float32),
                                       mode="drop")
-    k_dense = dense_view(cache["k"], cache["k_scale"], k)
-    v_dense = dense_view(cache["v"], cache["v_scale"], v)
+    with jax.named_scope("attn.cache"):
+        k_dense = dense_view(cache["k"], cache["k_scale"], k)
+        v_dense = dense_view(cache["v"], cache["v_scale"], v)
     # requantize the touched window BEFORE attending so the optional
     # kernel path can read a complete pool. Positions [cache_index,
     # cache_index + t) span at most ceil(t/ps) + 1 logical pages
     # starting at cache_index // ps (the cursor may sit mid-page).
     n_touch = -(-t // ps) + 1
-    first = jnp.clip(cache_index // ps, 0, pmax - 1)
-    win = first[:, None] + jnp.arange(n_touch)[None, :]  # [b, n_touch]
-    last = jnp.clip((cache_index + t - 1) // ps, 0, pmax - 1)
-    ok_w = (win <= last[:, None]) & (win < pmax)
-    win_c = jnp.clip(win, 0, pmax - 1)
-    phys_w = jnp.where(ok_w,
-                       jnp.take_along_axis(page_table, win_c, axis=1),
-                       scratch_page)
-    cell = win_c[..., None] * ps + jnp.arange(ps)[None, None, :]
-    bidx = jnp.arange(b)[:, None, None]
-    # cells past the row's post-call length are zeroed before amax so a
-    # page's scale only reflects real tokens (incl. this call's block
-    # and its padding, which the native path also writes)
-    valid = cell < (cache_index + t)[:, None, None]
-    kq, ksc = quantize_kv_page(k_dense[bidx, cell], valid)
-    vq, vsc = quantize_kv_page(v_dense[bidx, cell], valid)
-    new_cache = {"k": cache["k"].at[phys_w].set(kq),
-                 "v": cache["v"].at[phys_w].set(vq),
-                 "k_scale": cache["k_scale"].at[phys_w].set(ksc),
-                 "v_scale": cache["v_scale"].at[phys_w].set(vsc)}
-    if _fa.PAGED_INT8_KERNEL and _fa.paged_dispatch(
-            q.shape, (scratch_page + 1, ps, heads, head_dim),
-            page_table.shape, q.dtype):
-        # follow-up flag (default OFF, the groupnorm lesson): feed the
-        # fused kernel a dequantized f32 pool so the page DMAs stay
-        # kernel-side. The pool already holds this call's block, so the
-        # kernel sees ROUND-TRIPPED in-call values where the XLA path
-        # overlays them exactly — a stepping stone, not a win, until
-        # the dequant moves inside the kernel grid (DESIGN.md §19).
-        k_pool = dequantize_kv_page(new_cache["k"], new_cache["k_scale"],
-                                    q.dtype)
-        v_pool = dequantize_kv_page(new_cache["v"], new_cache["v_scale"],
-                                    q.dtype)
-        out = _fa.paged_flash_attention(q, k_pool, v_pool, page_table,
-                                        cache_index,
-                                        interpret=_fa.PAGED_INTERPRET)
-    else:
-        key_pos = jnp.arange(max_len)
-        mask = key_pos[None, None, None, :] <= pos[:, None, :, None]
-        out = dot_product_attention(q, k_dense.astype(q.dtype),
-                                    v_dense.astype(q.dtype), mask=mask)
+    with jax.named_scope("cache.write"):
+        first = jnp.clip(cache_index // ps, 0, pmax - 1)
+        win = first[:, None] + jnp.arange(n_touch)[None, :]  # [b, n_touch]
+        last = jnp.clip((cache_index + t - 1) // ps, 0, pmax - 1)
+        ok_w = (win <= last[:, None]) & (win < pmax)
+        win_c = jnp.clip(win, 0, pmax - 1)
+        phys_w = jnp.where(ok_w,
+                           jnp.take_along_axis(page_table, win_c, axis=1),
+                           scratch_page)
+        cell = win_c[..., None] * ps + jnp.arange(ps)[None, None, :]
+        bidx = jnp.arange(b)[:, None, None]
+        # cells past the row's post-call length are zeroed before amax so a
+        # page's scale only reflects real tokens (incl. this call's block
+        # and its padding, which the native path also writes)
+        valid = cell < (cache_index + t)[:, None, None]
+        kq, ksc = quantize_kv_page(k_dense[bidx, cell], valid)
+        vq, vsc = quantize_kv_page(v_dense[bidx, cell], valid)
+        new_cache = {"k": cache["k"].at[phys_w].set(kq),
+                     "v": cache["v"].at[phys_w].set(vq),
+                     "k_scale": cache["k_scale"].at[phys_w].set(ksc),
+                     "v_scale": cache["v_scale"].at[phys_w].set(vsc)}
+    with jax.named_scope("attn.scores"):
+        if _fa.PAGED_INT8_KERNEL and _fa.paged_dispatch(
+                q.shape, (scratch_page + 1, ps, heads, head_dim),
+                page_table.shape, q.dtype):
+            # follow-up flag (default OFF, the groupnorm lesson): feed the
+            # fused kernel a dequantized f32 pool so the page DMAs stay
+            # kernel-side. The pool already holds this call's block, so
+            # the kernel sees ROUND-TRIPPED in-call values where the XLA
+            # path overlays them exactly — a stepping stone, not a win,
+            # until the dequant moves inside the kernel grid (DESIGN.md
+            # §19).
+            k_pool = dequantize_kv_page(new_cache["k"],
+                                        new_cache["k_scale"], q.dtype)
+            v_pool = dequantize_kv_page(new_cache["v"],
+                                        new_cache["v_scale"], q.dtype)
+            out = _fa.paged_flash_attention(q, k_pool, v_pool, page_table,
+                                            cache_index,
+                                            interpret=_fa.PAGED_INTERPRET)
+        else:
+            key_pos = jnp.arange(max_len)
+            mask = key_pos[None, None, None, :] <= pos[:, None, :, None]
+            out = dot_product_attention(q, k_dense.astype(q.dtype),
+                                        v_dense.astype(q.dtype), mask=mask)
     return out, new_cache
 
 

@@ -65,6 +65,17 @@ from distkeras_tpu.ops.cache_rows import gather_rows
 #: one takes the chunked scan
 _STEP_MAX_BLOCK = 4
 
+#: the ``jax.named_scope`` names this file's forward declares, the experts'
+#: (models/latent_moe.py) among them: every operation it traces lies under
+#: one (``profiling/scopes.py``). ``ssm.step`` holds a decode step's pass
+#: over the pooled states, which is their write as well; ``cache.write``
+#: the other writes: a prefill's state and tail into their rows, K and V
+#: lines; a residual sum goes with the sub-layer whose result it takes
+SCOPES = ("embed", "norm", "ssm.in", "ssm.conv", "ssm.scan", "ssm.step",
+          "ssm.out", "attn.gqa", "cache.write") + tuple(
+              name for name in latent_moe.SCOPES if name.startswith("moe.")
+          ) + ("head",)
+
 #: queries a long block attends at a time, lanes a short block attends at
 #: a time: latent_moe's, for its reasons
 _QUERY_BLOCK = latent_moe._QUERY_BLOCK
@@ -223,11 +234,11 @@ class Mamba2Mixer(nn.Module):
                                               dtype)
         vec = lambda name, *shape: self.param(name, param_init(name), shape,
                                               f32)
-        rows = None if cache is None else (
-            jnp.arange(b) if cache_rows is None else cache_rows)
-        if real_len is None:
-            real_len = jnp.full((b,), t, jnp.int32)
         with jax.named_scope("ssm.in"):
+            rows = None if cache is None else (
+                jnp.arange(b) if cache_rows is None else cache_rows)
+            if real_len is None:
+                real_len = jnp.full((b,), t, jnp.int32)
             proj = jnp.dot(u.astype(dtype),
                            mat("in_proj", width, inner + conv_dim + heads),
                            preferred_element_type=f32)
@@ -249,11 +260,12 @@ class Mamba2Mixer(nn.Module):
             new_tail = jnp.take_along_axis(
                 window, (real_len[:, None]
                          + jnp.arange(taps - 1)[None, :])[:, :, None], axis=1)
-        x = xbc[..., :inner].reshape(b, t, groups, per, p_dim)
-        b_mat = xbc[..., inner:inner + groups * n].reshape(b, t, groups, n)
-        c_mat = xbc[..., inner + groups * n:].reshape(b, t, groups, n)
-        a = -jnp.exp(vec("A_log", heads)).reshape(groups, per)
-        dt = dt.reshape(b, t, groups, per)
+            x = xbc[..., :inner].reshape(b, t, groups, per, p_dim)
+            b_mat = xbc[..., inner:inner + groups * n].reshape(
+                b, t, groups, n)
+            c_mat = xbc[..., inner + groups * n:].reshape(b, t, groups, n)
+            a = -jnp.exp(vec("A_log", heads)).reshape(groups, per)
+            dt = dt.reshape(b, t, groups, per)
         grouped = (groups, per, p_dim, n)
         new_ssm = None
         if cache is not None and t == 1 \
@@ -264,17 +276,18 @@ class Mamba2Mixer(nn.Module):
                     cache["ssm"].reshape((-1,) + grouped), rows)
                 y = y[:, None]
         else:
-            h0 = jnp.zeros((b,) + grouped, f32) if cache is None \
-                else cache["ssm"][rows].reshape((b,) + grouped)
-            if t <= _STEP_MAX_BLOCK:
-                with jax.named_scope("ssm.step"):
+            with jax.named_scope(
+                    "ssm.step" if t <= _STEP_MAX_BLOCK else "ssm.scan"):
+                h0 = jnp.zeros((b,) + grouped, f32) if cache is None \
+                    else cache["ssm"][rows].reshape((b,) + grouped)
+                if t <= _STEP_MAX_BLOCK:
                     y, h = ssm_steps(x, dt, a, b_mat, c_mat, h0)
-            else:
-                with jax.named_scope("ssm.scan"):
+                else:
                     y, h = ssd_scan(x, dt, a, b_mat, c_mat, h0, self.chunk)
             if cache is not None:
-                new_ssm = cache["ssm"].at[rows].set(
-                    h.reshape(b, heads, p_dim, n))
+                with jax.named_scope("cache.write"):
+                    new_ssm = cache["ssm"].at[rows].set(
+                        h.reshape(b, heads, p_dim, n))
         with jax.named_scope("ssm.out"):
             y = y + vec("D", heads).reshape(groups, per)[..., None] \
                 * x.astype(f32)
@@ -287,10 +300,11 @@ class Mamba2Mixer(nn.Module):
                           preferred_element_type=f32)
         if cache is None:
             return out, None
-        return out, {
-            "ssm": new_ssm.reshape(cache["ssm"].shape),
-            "conv": cache["conv"].at[rows].set(
-                new_tail.astype(cache["conv"].dtype))}
+        with jax.named_scope("cache.write"):
+            return out, {
+                "ssm": new_ssm.reshape(cache["ssm"].shape),
+                "conv": cache["conv"].at[rows].set(
+                    new_tail.astype(cache["conv"].dtype))}
 
 
 def _attend_grouped(q, k_rows, v_rows, pos):
@@ -343,13 +357,14 @@ class GroupedQueryAttention(nn.Module):
         if cache is None:
             out = _attend_grouped(q, k, v, pos)
         else:
-            rows = jnp.arange(b) if cache_rows is None else cache_rows
             # in place, first; mode="drop": a position past the row's end
             # must not clamp onto its last cell
-            new_cache = {
-                name: cache[name].at[rows[:, None], pos].set(
-                    lines.astype(cache[name].dtype), mode="drop")
-                for name, lines in (("k", k), ("v", v))}
+            with jax.named_scope("cache.write"):
+                rows = jnp.arange(b) if cache_rows is None else cache_rows
+                new_cache = {
+                    name: cache[name].at[rows[:, None], pos].set(
+                        lines.astype(cache[name].dtype), mode="drop")
+                    for name, lines in (("k", k), ("v", v))}
             lanes = b
             if t <= _STEP_MAX_BLOCK and cache_rows is not None \
                     and b % _LANE_GROUP == 0:
@@ -364,6 +379,10 @@ class GroupedQueryAttention(nn.Module):
         out = jnp.dot(out.reshape(b, t, self.num_heads * hd).astype(dtype),
                       w_o, preferred_element_type=jnp.float32)
         return out, new_cache
+
+
+#: the scope a block's residual sum goes under, by the block's kind
+_RESIDUAL_SCOPE = {"M": "ssm.out", "*": "attn.gqa", "E": "moe.shared"}
 
 
 class HybridLM(nn.Module):
@@ -444,18 +463,23 @@ class HybridLM(nn.Module):
             raise ValueError(
                 "HybridLM keeps a recurrent state a row and has no paged "
                 "form; serve it from the rectangular KVCachePool")
-        ids = input_ids.astype(jnp.int32)
-        b, t = ids.shape
-        if cache is None:
-            pos = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
-        else:
-            pos = cache_index[:, None] + jnp.arange(t)[None, :]
         embed = self.param("tok_embed", param_init("tok_embed"),
                            (self.vocab_size, self.width), self.dtype)
-        x = embed[ids].astype(jnp.float32)
-        norm = lambda name, a: rms_norm(
-            a, self.param(name, param_init(name), (self.width,),
-                          jnp.float32), self.rms_eps)
+        with jax.named_scope("embed"):
+            ids = input_ids.astype(jnp.int32)
+            b, t = ids.shape
+            if cache is None:
+                pos = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
+            else:
+                pos = cache_index[:, None] + jnp.arange(t)[None, :]
+            x = embed[ids].astype(jnp.float32)
+
+        @jax.named_scope("norm")
+        def norm(name, a):
+            return rms_norm(
+                a, self.param(name, param_init(name), (self.width,),
+                              jnp.float32), self.rms_eps)
+
         new_cache, routed = [], []
         for i, kind in enumerate(self.pattern):
             y = norm(f"mixer_norm_{i}", x)
@@ -479,12 +503,13 @@ class HybridLM(nn.Module):
                     scoring="sigmoid", activation="relu2",
                     shared_width=self.shared_width, name=f"mixer_{i}")(
                         y.reshape(b * t, self.width))
-                y = y.reshape(b, t, self.width)
-                routed.append(sent.reshape(b, t, -1))
+                with jax.named_scope("moe.route"):
+                    routed.append(sent.reshape(b, t, -1))
             else:
                 raise ValueError(f"block {i} of pattern {self.pattern!r} is "
                                  f"{kind!r}: not one of 'M', '*', 'E'")
-            x = x + y
+            with jax.named_scope(_RESIDUAL_SCOPE[kind]):
+                x = x + y.reshape(b, t, self.width)
             new_cache.append(layer_cache)
         with jax.named_scope("head"):
             if real_len is not None:    # the one row a prefill returns
@@ -496,7 +521,8 @@ class HybridLM(nn.Module):
                              preferred_element_type=jnp.float32)
         if cache is None:
             return logits
-        return logits, tuple(new_cache), jnp.stack(routed)
+        with jax.named_scope("moe.route"):
+            return logits, tuple(new_cache), jnp.stack(routed)
 
 
 def hybrid_tiny(**kw) -> HybridLM:

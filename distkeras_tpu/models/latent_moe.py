@@ -121,6 +121,22 @@ import numpy as np
 from distkeras_tpu.ops.attention import MASK_VALUE
 from distkeras_tpu.ops.cache_rows import gather_rows
 
+#: the ``jax.named_scope`` names this file's forward declares (and
+#: models/hybrid.py's experts with it): every operation it traces lies
+#: under one, and ``profiling/scopes.py`` gives an executable's instruction
+#: to the innermost one on its ``op_name`` path. ``attn.latent`` is a
+#: layer's attention outside the finer names: its projections, the rotary
+#: turn, the absorbed products, the long block's up-projection of the rows
+#: it attends; ``attn.latent.square`` the long block's scores, mask,
+#: softmax and weighted sum a query block; ``cache.write`` the block's
+#: lines, index keys and ring written into the cache; a residual sum goes
+#: with the sub-layer whose result it takes
+SCOPES = ("embed", "norm", "attn.latent", "attn.latent.square", "attn.index",
+          "attn.select", "attn.sparse", "attn.window", "cache.write",
+          "moe.route", "moe.experts", "moe.experts.gather",
+          "moe.experts.products", "moe.experts.add", "moe.shared",
+          "mlp.dense", "head")
+
 #: longest block that takes the absorbed form. By operations the two
 #: forms meet near t = 150 (absorbed pays 2.25 x the score and value
 #: products, expanded pays the up-projection of every key it attends);
@@ -435,9 +451,10 @@ def _attend_expanded(q, rows, pos, w_kvb, dims, scale, index=None,
         p = jax.nn.softmax(jnp.where(mask, s, MASK_VALUE), axis=-1)
         return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
-    return in_query_blocks(
-        attend, (q, pos, scale) + (() if index is None else index[:2]),
-        _QUERY_BLOCK)
+    with jax.named_scope("attn.latent.square"):
+        return in_query_blocks(
+            attend, (q, pos, scale) + (() if index is None else index[:2]),
+            _QUERY_BLOCK)
 
 
 def _absorb_query(q, w, dims, line: int, dtype):
@@ -628,15 +645,18 @@ def _attend_window(q, lines, pos, w_kvb, dims, scale, window: int,
     if not short:
         out = _attend_band(q, lines, pos, w_kvb, dims, scale, window)
         real = t if real_len is None else real_len
-        held = ring_holds(pos[:, 0] + real - 1, cells)
-        ring = jnp.take_along_axis(
-            lines, jnp.clip(held - pos[:, :1], 0, t - 1)[..., None], axis=1)
-        return out, {"ring": leaf.at[rows].set(ring)}
+        with jax.named_scope("cache.write"):
+            held = ring_holds(pos[:, 0] + real - 1, cells)
+            ring = jnp.take_along_axis(
+                lines, jnp.clip(held - pos[:, :1], 0, t - 1)[..., None],
+                axis=1)
+            return out, {"ring": leaf.at[rows].set(ring)}
     if cells < window + t - 1:
         raise ValueError(
             f"a ring of {cells} cells cannot hold a window of {window} "
             f"under a block of {t} positions written at once")
-    leaf = leaf.at[rows[:, None], pos % cells].set(lines)
+    with jax.named_scope("cache.write"):
+        leaf = leaf.at[rows[:, None], pos % cells].set(lines)
     mask = window_mask(ring_holds(pos[:, -1], cells), pos, window)
     lanes = _lanes_at_a_time(b, True, cache_rows)
     out = jnp.concatenate([
@@ -744,13 +764,14 @@ class LatentAttention(nn.Module):
             rows = jnp.arange(b) if cache_rows is None else cache_rows
             # in place, first; mode="drop": the decode step's ghost
             # position past the row's end must not clamp onto its last cell
-            leaf = leaf.at[rows[:, None], pos].set(lines.astype(leaf.dtype),
-                                                   mode="drop")
-            new_cache = {"kv": leaf}
-            if index is not None:
-                key_leaf = cache["ik"].at[rows[:, None], pos].set(
-                    keys.astype(cache["ik"].dtype), mode="drop")
-                new_cache["ik"] = key_leaf
+            with jax.named_scope("cache.write"):
+                leaf = leaf.at[rows[:, None], pos].set(
+                    lines.astype(leaf.dtype), mode="drop")
+                new_cache = {"kv": leaf}
+                if index is not None:
+                    key_leaf = cache["ik"].at[rows[:, None], pos].set(
+                        keys.astype(cache["ik"].dtype), mode="drop")
+                    new_cache["ik"] = key_leaf
             lanes = _lanes_at_a_time(b, short, cache_rows)
             group = lambda a, g: None if a is None else a[g:g + lanes]
             if index is None:
@@ -871,18 +892,21 @@ def _in_expert_blocks(xb, gate, up, down, group, top):
     def one_block(b, total):
         mine = lambda w: jax.lax.dynamic_index_in_dim(
             w, expert_of[b], keepdims=False)
-        x_b = xb.at[token[b]].get(mode="clip", indices_are_sorted=True)
-        wide = lambda w: jnp.dot(
-            x_b, mine(w), preferred_element_type=jnp.float32)
-        h = jax.nn.silu(wide(gate)) * wide(up) if gate is not None \
-            else _relu2(wide(up))
-        out = jnp.dot(h.astype(dtype), mine(down),
-                      preferred_element_type=jnp.float32).astype(dtype)
+        with jax.named_scope("moe.experts.gather"):
+            x_b = xb.at[token[b]].get(mode="clip", indices_are_sorted=True)
+        with jax.named_scope("moe.experts.products"):
+            wide = lambda w: jnp.dot(
+                x_b, mine(w), preferred_element_type=jnp.float32)
+            h = jax.nn.silu(wide(gate)) * wide(up) if gate is not None \
+                else _relu2(wide(up))
+            out = jnp.dot(h.astype(dtype), mine(down),
+                          preferred_element_type=jnp.float32).astype(dtype)
         # distinct is promised, ascending is not: told that its indices are
         # sorted, the chip's scatter took 5 times as long (docstring)
-        return total.at[token[b]].add(
-            out.astype(jnp.float32) * weight[b][:, None],
-            unique_indices=True)
+        with jax.named_scope("moe.experts.add"):
+            return total.at[token[b]].add(
+                out.astype(jnp.float32) * weight[b][:, None],
+                unique_indices=True)
 
     total = jnp.zeros((n + blk, xb.shape[1]), jnp.float32)
     return jax.lax.fori_loop(0, ends[-1], one_block, total)[:n]
@@ -942,12 +966,12 @@ class ExpertShare(nn.Module):
             here = (local >= 0) & (local < held)
             sent = local[..., None] == jnp.arange(held)      # [n, k, held]
             routed = jnp.any(sent, axis=1)
-        xb = x.astype(dtype)
         gated = self.activation == "swiglu"
         gate = mat("gate", held, d, self.width) if gated else None
         up = mat("up", held, d, self.width)
         down = mat("down", held, self.width, d)
         with jax.named_scope("moe.experts"):
+            xb = x.astype(dtype)
             if n <= _DENSE_MAX_TOKENS:
                 # few tokens: every held expert on every token, and each
                 # token keeps its own gates' part (zero for the rest)
@@ -1163,18 +1187,23 @@ class LatentMoELM(nn.Module):
             raise ValueError(
                 "LatentMoELM keeps one latent line a position and has no "
                 "paged form; serve it from the rectangular KVCachePool")
-        ids = input_ids.astype(jnp.int32)
-        b, t = ids.shape
-        if cache is None:
-            pos = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
-        else:
-            pos = cache_index[:, None] + jnp.arange(t)[None, :]
         embed = self.param("tok_embed", param_init("tok_embed"),
                            (self.vocab_size, self.width), self.dtype)
-        x = embed[ids].astype(jnp.float32)
-        norm = lambda name, a: rms_norm(
-            a, self.param(name, param_init(name), (self.width,),
-                          jnp.float32), self.rms_eps)
+        with jax.named_scope("embed"):
+            ids = input_ids.astype(jnp.int32)
+            b, t = ids.shape
+            if cache is None:
+                pos = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
+            else:
+                pos = cache_index[:, None] + jnp.arange(t)[None, :]
+            x = embed[ids].astype(jnp.float32)
+
+        @jax.named_scope("norm")
+        def norm(name, a):
+            return rms_norm(
+                a, self.param(name, param_init(name), (self.width,),
+                              jnp.float32), self.rms_eps)
+
         new_cache, routed, attended = [], [], []
         for i, kind in enumerate(self.kinds):
             with jax.named_scope("attn.latent"):
@@ -1182,7 +1211,7 @@ class LatentMoELM(nn.Module):
                     norm(f"attn_norm_{i}", x), pos,
                     None if cache is None else cache[i], cache_rows,
                     real_len)
-            x = x + y.astype(jnp.float32)
+                x = x + y.astype(jnp.float32)
             new_cache.append(layer_cache)
             attended.append(chosen)
             if i < self.dense_layers:
@@ -1196,21 +1225,26 @@ class LatentMoELM(nn.Module):
                 self.expert_share, self.routed_scaling, self.dtype,
                 self.scoring, name=f"moe_{i}")(
                     norm(f"moe_norm_{i}", x).reshape(b * t, self.width))
-            x = x + y.reshape(b, t, self.width)
-            routed.append(sent.reshape(b, t, -1))
-        if real_len is not None:        # the one position a prefill returns
-            x = jnp.take_along_axis(x, (real_len - 1)[:, None, None], axis=1)
+            with jax.named_scope("moe.shared"):
+                x = x + y.reshape(b, t, self.width)
+            with jax.named_scope("moe.route"):
+                routed.append(sent.reshape(b, t, -1))
         with jax.named_scope("head"):
+            if real_len is not None:    # the one position a prefill returns
+                x = jnp.take_along_axis(
+                    x, (real_len - 1)[:, None, None], axis=1)
             head = self.param("head", param_init("head"),
                               (self.width, self.vocab_size), self.dtype)
             logits = jnp.dot(norm("final_norm", x).astype(self.dtype), head,
                              preferred_element_type=jnp.float32)
         if cache is None:
             return logits
+        with jax.named_scope("moe.route"):
+            routed = jnp.stack(routed)
         if not self.cache_select_leaves:
-            return logits, tuple(new_cache), jnp.stack(routed)
-        return logits, tuple(new_cache), jnp.stack(routed), \
-            jnp.stack(attended)
+            return logits, tuple(new_cache), routed
+        with jax.named_scope("attn.select"):
+            return logits, tuple(new_cache), routed, jnp.stack(attended)
 
 
 def latent_moe_tiny(**kw) -> LatentMoELM:

@@ -60,8 +60,11 @@ _EXPAND_CALLS = frozenset({"call", "while", "conditional", "fusion"})
 
 _instr_re = re.compile(
     r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*"
-    r"(?P<type>\([^=]*?\)|[\w\[\]{},:#*\s]+?)\s+"
+    r"(?P<type>\([^=]*?\)|[\w\[\]{},:#*\s()]+?)\s+"
     r"(?P<opcode>[\w\-]+)\(")
+# (the parentheses in the type's class are the TPU's tiled layouts,
+# ``bf16[8,128]{1,0:T(8,128)(2,1)S(1)}``: without them only tuple-typed
+# instructions of a TPU executable parsed)
 _comp_re = re.compile(
     r"^(?:ENTRY\s+)?%?(?P<name>[\w.\-]+)\s*(?:\(.*\)\s*->|\{)")
 _shape_re = re.compile(r"(?P<dtype>[a-z]\w*)\[(?P<dims>[\d,]*)\]")
@@ -148,6 +151,94 @@ def _resolve_operands(operands: str, types: Dict[str, str]) -> str:
     return ", ".join(parts)
 
 
+_scope_token_re = re.compile(r"[\w.\-]+")
+
+
+def _scope(attrs: str, declared) -> str:
+    """The innermost of the ``declared`` scope names on the instruction's
+    ``op_name`` path (``jit(prefill)/.../attn.latent/attn.index/dot_general``
+    -> ``attn.index``), ``""`` where the path holds none or there is no
+    path. A segment a transformation wrapped (``transpose(jvp(attn.qkv))``)
+    counts as the name inside it."""
+    m = _opname_re.search(attrs) if declared else None
+    if not m:
+        return ""
+    for seg in reversed(m.group(1).split("/")):
+        if seg in declared:
+            return seg
+        if "(" in seg:
+            for token in reversed(_scope_token_re.findall(seg)):
+                if token in declared:
+                    return token
+    return ""
+
+
+_ref_re = re.compile(r"%([\w.\-]+)")
+#: bookkeeping: no device event, no row
+_PLUMBING = frozenset({"parameter", "constant", "get-tuple-element", "tuple"})
+#: of those, what hands no scope on either: a tuple gathers results of
+#: every scope (a ``get-tuple-element`` is looked through)
+_CARRIES_NONE = _PLUMBING - {"get-tuple-element"}
+
+
+def _scopes_of(comps: Dict[str, List["_Instr"]], declared
+               ) -> Dict[str, Tuple[str, bool]]:
+    """``name -> (scope, inferred)`` for every instruction of the module.
+
+    An instruction's scope is :func:`_scope` of its own ``op_name`` path;
+    a fusion without one takes that of the instruction nearest its root
+    that names one. What is left are the instructions the compiler made
+    and gave no path at all (a relayout copy or convert of a weight, a
+    prefetch's ``copy-start``/``copy-done``, a rewritten ``dot``): those
+    take, within their computation, the scope of the nearest instruction
+    that produces one of their operands and, failing that, of the nearest
+    that uses their result, and are marked ``inferred``. An instruction
+    that has a path with no declared name on it stays ``""``: it was
+    traced outside every scope, and that is the finding."""
+    out: Dict[str, Tuple[str, bool]] = {}
+    if not declared:
+        return out
+    for instrs in comps.values():
+        producers: Dict[str, List[str]] = {}
+        users: Dict[str, List[str]] = {}
+        local = {ins.name for ins in instrs}
+        pathless = []
+        for ins in instrs:
+            refs = [r for r in _ref_re.findall(ins.operands) if r in local]
+            producers[ins.name] = refs
+            for r in refs:
+                users.setdefault(r, []).append(ins.name)
+            scope = _scope(ins.attrs, declared)
+            has_path = bool(_opname_re.search(ins.attrs))
+            if ins.opcode == "fusion" and not scope:
+                inside = [i for c in _calls_re.findall(ins.attrs)
+                          for i in comps.get(c, [])]
+                scope = next(filter(None, (_scope(i.attrs, declared)
+                                           for i in reversed(inside))), "")
+                has_path = has_path or any(
+                    _opname_re.search(i.attrs) for i in inside
+                    if i.opcode not in _PLUMBING)
+            if scope or has_path:
+                out[ins.name] = (scope, False)
+            elif ins.opcode not in _CARRIES_NONE:
+                pathless.append(ins.name)
+        for near in (producers, users, producers):
+            changed = True
+            while changed:
+                changed = False
+                for name in pathless:
+                    if name in out:
+                        continue
+                    found = next((out[n][0] for n in near.get(name, ())
+                                  if out.get(n, ("",))[0]), "")
+                    if found:
+                        out[name] = (found, True)
+                        changed = True
+        for name in pathless:
+            out.setdefault(name, ("", False))
+    return out
+
+
 def _source(attrs: str) -> str:
     """Model-source annotation: trailing segments of the op_name metadata
     path (``jit(window_fn)/.../transpose(jvp(conv))/conv_general``)."""
@@ -170,6 +261,16 @@ class OpCost:
     source: str = ""
     fusion_ops: Tuple[str, ...] = ()
     count: int = 1  # >1 after by-source grouping
+    #: the output type as the text has it, layout included
+    #: (``bf16[33,1024]{1,0:T(8,128)}``, ``(f32[8]{0}, s32[]) ``): with
+    #: ``name`` it is what a profiler's device event calls the instruction
+    out_type: str = ""
+    #: innermost declared ``jax.named_scope`` on the ``op_name`` path
+    #: (:func:`_scope`); ``""`` where none, or none were declared
+    scope: str = ""
+    #: the compiler made this instruction and gave it no path: ``scope`` is
+    #: its neighbour's (:func:`_scopes_of`)
+    scope_inferred: bool = False
 
     @property
     def intensity(self) -> Optional[float]:
@@ -383,16 +484,29 @@ def _instr_flops(ins: _Instr, comp_flops: Dict[str, float],
 
 
 def parse_hlo_ops(hlo_text: str,
-                  while_trips: Optional[float] = None
-                  ) -> Tuple[List[OpCost], bool]:
-    """Walk post-optimization HLO text into costed rows.
+                  while_trips: Optional[float] = None,
+                  declared=()) -> Tuple[List[OpCost], bool]:
+    """Walk post-optimization HLO text into costed rows: one for every
+    instruction the device runs as an event of its own (entry computation,
+    loop and conditional bodies, called computations; a fusion whole, not
+    its inside; no parameter, constant or tuple plumbing, and no row for a
+    ``while``, ``call`` or ``conditional`` itself, whose event spans its
+    body's).
 
     Returns ``(rows, while_floor)``; ``while_floor`` is True when a while
     body was counted once for lack of a trip count (the caller may know it
     — attribution passes the window length, since the window scan is the
-    only loop in the training step).
+    only loop in the training step). ``declared`` is the set of
+    ``jax.named_scope`` names a row's ``scope`` is chosen from
+    (:func:`_scopes_of`; ``profiling/scopes.py``).
     """
+    declared = frozenset(declared)
     entry, comps, types = _parse_computations(hlo_text)
+    scopes = _scopes_of(comps, declared)
+
+    def scope_of(name: str) -> dict:
+        scope, inferred = scopes.get(name, ("", False))
+        return {"scope": scope, "scope_inferred": inferred}
     if entry is None:
         return [], False
     comp_flops: Dict[str, float] = {}
@@ -436,7 +550,8 @@ def parse_hlo_ops(hlo_text: str,
                     bytes_accessed=(in_b + out_b) * scale,
                     output_bytes=out_b * scale,
                     dtype=_out_dtype(ins.out_type),
-                    source=_source(ins.attrs), fusion_ops=fused))
+                    source=_source(ins.attrs), fusion_ops=fused,
+                    out_type=ins.out_type, **scope_of(ins.name)))
                 continue
             if ins.opcode == "while":
                 trips = while_trips
@@ -456,16 +571,15 @@ def parse_hlo_ops(hlo_text: str,
                     walk(callee, scale, seen + (comp,))
                 continue
             flops = _instr_flops(ins, comp_flops, types)
-            if flops <= 0 and ins.opcode in _ZERO_FLOP and \
-                    ins.opcode in ("parameter", "constant",
-                                   "get-tuple-element", "tuple"):
+            if ins.opcode in _PLUMBING:
                 continue  # bookkeeping ops: not worth a row
             rows.append(OpCost(
                 name=ins.name, opcode=ins.opcode, flops=flops * scale,
                 bytes_accessed=(in_b + out_b) * scale,
                 output_bytes=out_b * scale,
                 dtype=_out_dtype(ins.out_type),
-                source=_source(ins.attrs)))
+                source=_source(ins.attrs), out_type=ins.out_type,
+                **scope_of(ins.name)))
     walk(entry, 1.0)
     return rows, while_floor
 

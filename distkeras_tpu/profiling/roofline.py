@@ -216,9 +216,11 @@ def build_report(inventory: OpInventory,
     ``peak_flops``/``hbm_bandwidth`` default to the local device's table
     entries; on hosts without either (CPU) the caller must supply explicit
     reference ceilings or the report declines (``available=False``) rather
-    than classifying against invented numbers. ``measured`` maps HLO op
-    names to profiled seconds (from ``profiling.capture``); matching rows
-    rank by measured time, the rest by modeled time. ``modeled_flops`` is
+    than classifying against invented numbers. ``measured`` maps HLO
+    instruction names to profiled seconds (``profiling.scopes.join(xplane,
+    tables).instructions[(kind, key)]``: the exact join of a profiler
+    trace with that executable's table, seconds summed over the traced
+    runs); matching rows rank by measured time, the rest by modeled time. ``modeled_flops`` is
     the analytic compute-phase total (``observability.count_flops``) the
     coverage fraction is taken against.
     """

@@ -125,6 +125,15 @@ from distkeras_tpu.serving.kv_cache import (KVCachePool, PagedKVCachePool,
                                             state_leaves)
 from distkeras_tpu.utils import fault
 
+#: the ``jax.named_scope`` names the step functions below declare for what
+#: they add around the model's forward (profiling/scopes.py): the ids a
+#: decode step stacks (``embed``), a prefill's row put into the pool
+#: (``cache.write``), the one logits row a prefill hands back (``head``),
+#: the on-device argmax (``pick``), the counts a step sums for the host's
+#: counters (``step.count``), pages parked on the host and brought back
+#: (``cache.swap``)
+SCOPES = ("embed", "cache.write", "head", "pick", "step.count", "cache.swap")
+
 #: token id fed at the decode step's ghost position (its output is
 #: discarded and its cache line masked, so any valid id works)
 GHOST_TOKEN = 0
@@ -140,6 +149,28 @@ def _default_ladder(num_slots: int) -> Tuple[int, ...]:
         n *= 2
     sizes.add(num_slots)
     return tuple(sorted(sizes))
+
+
+def _compile(fn, donate, args, **which):
+    """One executable, ahead of time: ``fn`` traced, lowered for ``args``
+    (shape structs) and compiled under a ``serving.decode.compile`` span
+    that says ``which`` (``prefill=512``), counted, and handed to
+    ``profiling/scopes.py`` for its scope table: an insert there, the
+    executable's text is read only when somebody asks for tables. The
+    compile's persistent-cache key holds the metadata (``own_metadata``):
+    a hit on an entry that a program without these scopes wrote would give
+    back an executable whose instructions name none."""
+    import jax
+
+    from distkeras_tpu.profiling import scopes
+
+    with telemetry.span("serving.decode.compile", **which), \
+            scopes.own_metadata():
+        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+    telemetry.counter("serving.decode.compiles").inc()
+    scopes.register("jit_" + fn.__name__, ",".join(
+        f"{k}={v}" for k, v in which.items()), compiled)
+    return compiled
 
 
 def make_prefill_fn(model, dtype=None):
@@ -168,10 +199,12 @@ def make_prefill_fn(model, dtype=None):
         logits, new_row, *_ = model.apply(
             {"params": params}, ids, cache=row,
             cache_index=jnp.zeros((1,), jnp.int32), **told)
-        pool = jax.tree.map(
-            lambda p, c: jax.lax.dynamic_update_slice_in_dim(
-                p, c, slot, axis=0), pool, new_row)
-        return pool, logits[0, 0 if stateful else length - 1]
+        with jax.named_scope("cache.write"):
+            pool = jax.tree.map(
+                lambda p, c: jax.lax.dynamic_update_slice_in_dim(
+                    p, c, slot, axis=0), pool, new_row)
+        with jax.named_scope("head"):
+            return pool, logits[0, 0 if stateful else length - 1]
 
     return prefill
 
@@ -214,20 +247,24 @@ def make_decode_fn(model):
     def decode(params, pool, slot_ids, tokens, lengths):
         # a state a row would be advanced by the ghost too, and a
         # selection made for it: such a model is fed its real token alone
-        ids = jnp.stack(
-            [tokens, jnp.full_like(tokens, GHOST_TOKEN)], axis=1) \
-            if ghost else tokens[:, None]
+        with jax.named_scope("embed"):
+            ids = jnp.stack(
+                [tokens, jnp.full_like(tokens, GHOST_TOKEN)], axis=1) \
+                if ghost else tokens[:, None]
         logits, pool, *routed = model.apply(
             {"params": params}, ids, cache=pool, cache_index=lengths,
             cache_rows=slot_ids)
+        with jax.named_scope("head"):
+            logits = logits[:, 0, :]
         if not routed:
-            return pool, logits[:, 0, :]
-        scratch = jax.tree.leaves(pool)[0].shape[0] - 1
-        live = (slot_ids != scratch)[None, :, None]
-        return (pool, logits[:, 0, :], jnp.sum(
-            routed[0][:, :, 0, :] & live, axis=1, dtype=jnp.int32),
-            *(jnp.sum(jnp.where(live[..., 0], attended[:, :, 0], 0))
-              for attended in routed[1:]))
+            return pool, logits
+        with jax.named_scope("step.count"):
+            scratch = jax.tree.leaves(pool)[0].shape[0] - 1
+            live = (slot_ids != scratch)[None, :, None]
+            return (pool, logits, jnp.sum(
+                routed[0][:, :, 0, :] & live, axis=1, dtype=jnp.int32),
+                *(jnp.sum(jnp.where(live[..., 0], attended[:, :, 0], 0))
+                  for attended in routed[1:]))
 
     return decode
 
@@ -281,15 +318,17 @@ def pick_on_device(step):
     ``np.argmax``, so a greedy stream is the host argmax's. The wrapper
     keeps ``step``'s name: the executable is ``jit_decode`` (``jit_step``
     for the paged one) either way."""
+    import jax
     import jax.numpy as jnp
 
     @functools.wraps(step)
     def picked(*args):
         pool, logits, *routed = step(*args)
-        if logits.ndim == 3:
-            logits = logits[:, 0, :]
-        return (pool, jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                *routed)
+        with jax.named_scope("pick"):
+            if logits.ndim == 3:
+                logits = logits[:, 0, :]
+            return (pool, jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                    *routed)
 
     return picked
 
@@ -301,7 +340,8 @@ def make_swap_out_fn():
     import jax
 
     def swap_out(pages, page_ids):
-        return jax.tree.map(lambda a: a[page_ids], pages)
+        with jax.named_scope("cache.swap"):
+            return jax.tree.map(lambda a: a[page_ids], pages)
 
     return swap_out
 
@@ -313,8 +353,9 @@ def make_swap_in_fn():
     import jax
 
     def swap_in(pages, page_ids, data):
-        return jax.tree.map(lambda a, d: a.at[page_ids].set(d),
-                            pages, data)
+        with jax.named_scope("cache.swap"):
+            return jax.tree.map(lambda a, d: a.at[page_ids].set(d),
+                                pages, data)
 
     return swap_in
 
@@ -417,17 +458,13 @@ class ModelDraft:
         self._prefill_exec = {}
         self._decode_exec = {}
         for lb in self._buckets:
-            with telemetry.span("serving.decode.compile", draft_prefill=lb):
-                self._prefill_exec[lb] = jax.jit(
-                    prefill, donate_argnums=(1,)).lower(
-                        p_sds, c_sds, i32(1, lb), i32(), i32()).compile()
-            telemetry.counter("serving.decode.compiles").inc()
+            self._prefill_exec[lb] = _compile(
+                prefill, (1,), (p_sds, c_sds, i32(1, lb), i32(), i32()),
+                draft_prefill=lb)
         for n in self._ladder:
-            with telemetry.span("serving.decode.compile", draft_lanes=n):
-                self._decode_exec[n] = jax.jit(
-                    decode, donate_argnums=(1,)).lower(
-                        p_sds, c_sds, i32(n), i32(n), i32(n)).compile()
-            telemetry.counter("serving.decode.compiles").inc()
+            self._decode_exec[n] = _compile(
+                decode, (1,), (p_sds, c_sds, i32(n), i32(n), i32(n)),
+                draft_lanes=n)
         # warm every executable against the draft scratch row
         scratch = np.int32(self._scratch)
         for lb, ex in self._prefill_exec.items():
@@ -866,7 +903,6 @@ class GenerationEngine:
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
         p_sds, pool_sds = sds(self._params), sds(self.pool.pool)
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)
-        compiles = telemetry.counter("serving.decode.compiles")
         self._prefill_exec = {}
         self._decode_exec = {}
         self._verify_exec = {}
@@ -878,12 +914,9 @@ class GenerationEngine:
             step = make_paged_step_fn(self.model)
             pmax = self.pool.pages_per_slot
             for lb in self._buckets:
-                with telemetry.span("serving.decode.compile", prefill=lb):
-                    self._prefill_exec[lb] = jax.jit(
-                        step, donate_argnums=(1,)).lower(
-                            p_sds, pool_sds, i32(1, pmax), i32(1, lb),
-                            i32(1)).compile()
-                compiles.inc()
+                self._prefill_exec[lb] = _compile(
+                    step, (1,), (p_sds, pool_sds, i32(1, pmax), i32(1, lb),
+                                 i32(1)), prefill=lb)
             if self._chunk is not None:
                 if self._chunk in self._prefill_exec:
                     # a chunk the width of a prefill bucket is the SAME
@@ -891,66 +924,45 @@ class GenerationEngine:
                     # donate the pool; the executable is stateless)
                     self._chunk_exec = self._prefill_exec[self._chunk]
                 else:
-                    with telemetry.span("serving.decode.compile",
-                                        prefill_chunk=self._chunk):
-                        self._chunk_exec = jax.jit(
-                            step, donate_argnums=(1,)).lower(
-                                p_sds, pool_sds, i32(1, pmax),
-                                i32(1, self._chunk), i32(1)).compile()
-                    compiles.inc()
+                    self._chunk_exec = _compile(
+                        step, (1,), (p_sds, pool_sds, i32(1, pmax),
+                                     i32(1, self._chunk), i32(1)),
+                        prefill_chunk=self._chunk)
             for n in self._ladder:
-                with telemetry.span("serving.decode.compile", lanes=n):
-                    self._decode_exec[n] = jax.jit(
-                        pick(step), donate_argnums=(1,)).lower(
-                            p_sds, pool_sds, i32(n, pmax), i32(n, 2),
-                            i32(n)).compile()
-                compiles.inc()
+                self._decode_exec[n] = _compile(
+                    pick(step), (1,), (p_sds, pool_sds, i32(n, pmax),
+                                       i32(n, 2), i32(n)), lanes=n)
                 if self._spec_k:
-                    with telemetry.span("serving.decode.compile",
-                                        verify=n):
-                        self._verify_exec[n] = jax.jit(
-                            step, donate_argnums=(1,)).lower(
-                                p_sds, pool_sds, i32(n, pmax),
-                                i32(n, self._spec_k + 1), i32(n)).compile()
-                    compiles.inc()
+                    self._verify_exec[n] = _compile(
+                        step, (1,), (p_sds, pool_sds, i32(n, pmax),
+                                     i32(n, self._spec_k + 1), i32(n)),
+                        verify=n)
             if self._prefix is not None:
                 data_sds = jax.tree.map(
                     lambda a: jax.ShapeDtypeStruct(
                         (pmax,) + a.shape[1:], a.dtype), pool_sds)
-                with telemetry.span("serving.decode.compile",
-                                    swap="out"):
-                    self._swap_out_exec = jax.jit(
-                        make_swap_out_fn()).lower(
-                            pool_sds, i32(pmax)).compile()
-                compiles.inc()
-                with telemetry.span("serving.decode.compile", swap="in"):
-                    self._swap_in_exec = jax.jit(
-                        make_swap_in_fn(), donate_argnums=(0,)).lower(
-                            pool_sds, i32(pmax), data_sds).compile()
-                compiles.inc()
+                self._swap_out_exec = _compile(
+                    make_swap_out_fn(), (), (pool_sds, i32(pmax)),
+                    swap="out")
+                self._swap_in_exec = _compile(
+                    make_swap_in_fn(), (0,), (pool_sds, i32(pmax), data_sds),
+                    swap="in")
             return
         prefill = make_prefill_fn(self.model, self._pool_dtype)
         decode = pick(make_decode_fn(self.model))
         for lb in self._buckets:
-            with telemetry.span("serving.decode.compile", prefill=lb):
-                self._prefill_exec[lb] = jax.jit(
-                    prefill, donate_argnums=(1,)).lower(
-                        p_sds, pool_sds, i32(1, lb), i32(), i32()).compile()
-            compiles.inc()
+            self._prefill_exec[lb] = _compile(
+                prefill, (1,), (p_sds, pool_sds, i32(1, lb), i32(), i32()),
+                prefill=lb)
         for n in self._ladder:
-            with telemetry.span("serving.decode.compile", lanes=n):
-                self._decode_exec[n] = jax.jit(
-                    decode, donate_argnums=(1,)).lower(
-                        p_sds, pool_sds, i32(n), i32(n), i32(n)).compile()
-            compiles.inc()
+            self._decode_exec[n] = _compile(
+                decode, (1,), (p_sds, pool_sds, i32(n), i32(n), i32(n)),
+                lanes=n)
             if self._spec_k:
-                with telemetry.span("serving.decode.compile", verify=n):
-                    self._verify_exec[n] = jax.jit(
-                        make_verify_fn(self.model),
-                        donate_argnums=(1,)).lower(
-                            p_sds, pool_sds, i32(n),
-                            i32(n, self._spec_k + 1), i32(n)).compile()
-                compiles.inc()
+                self._verify_exec[n] = _compile(
+                    make_verify_fn(self.model), (1,),
+                    (p_sds, pool_sds, i32(n), i32(n, self._spec_k + 1),
+                     i32(n)), verify=n)
 
     def _warmup(self) -> None:
         """Run every executable once against the scratch slot/page so no

@@ -376,10 +376,9 @@ METRIC_NAMES = {
     "profile.phase.pull_s": "histogram",
     "profile.phase.window_s": "histogram",
     # op-level attribution (DESIGN.md §21): roofline coverage + per-op
-    # time shares, plus the once-per-process degradation counters for
-    # backends without a cost model / device profiler. Per-op labeled
-    # variants ride the "profile.op." family below.
-    "profile.op.capture_unavailable": "counter",
+    # time shares, plus the once-per-process degradation counter for
+    # backends without a cost model. Per-op labeled variants ride the
+    # "profile.op." family below.
     "profile.op.coverage": "gauge",
     "profile.op.inventory_unavailable": "counter",
     "profile.op.share": "gauge",

@@ -3,8 +3,8 @@
 Covers the PR 16 surface: the HLO cost model (deterministic on a fixed
 fixture, while-trip scaling), the roofline classifier (golden arithmetic-
 intensity cases, dtype-aware peak selection, decline-don't-fabricate on
-CPU), the typed fallbacks when a backend exposes no cost model or no
-device trace, the per-window MFU satellite in host_async, and the
+CPU), the typed fallback when a backend exposes no cost model, the
+per-window MFU satellite in host_async, and the
 health-plane wiring (status digest, watch OPS line, postmortem bundle).
 """
 
@@ -16,7 +16,6 @@ import pytest
 
 from distkeras_tpu import observability, telemetry
 from distkeras_tpu import profiling
-from distkeras_tpu.profiling import capture as capture_mod
 from distkeras_tpu.profiling import cost_model, roofline
 
 
@@ -272,65 +271,6 @@ def test_source_inventory_matches_post_opt_on_conv_grad():
     assert src.total_flops > 0
     ratio = inv.total_flops / src.total_flops
     assert 0.9 <= ratio <= 1.1, (inv.total_flops, src.total_flops)
-
-
-# ------------------------------------------------------------- capture
-def _varint(n: int) -> bytes:
-    out = bytearray()
-    while True:
-        bit = n & 0x7F
-        n >>= 7
-        out.append(bit | (0x80 if n else 0))
-        if not n:
-            return bytes(out)
-
-
-def _field(num: int, payload: bytes) -> bytes:
-    return _varint((num << 3) | 2) + _varint(len(payload)) + payload
-
-
-def _vfield(num: int, value: int) -> bytes:
-    return _varint(num << 3) + _varint(value)
-
-
-def _xplane(plane_name: bytes, meta_name: bytes, dur_ps: int) -> bytes:
-    # XPlane.event_metadata is map<int64, XEventMetadata>:
-    # entry{key=1, value=XEventMetadata{id=1, name=2}}
-    entry = _vfield(1, 7) + _field(2, _vfield(1, 7) + _field(2, meta_name))
-    event = _vfield(1, 7) + _vfield(3, dur_ps)  # XEvent{metadata_id, dur}
-    line = _field(4, event)
-    plane = _field(2, plane_name) + _field(4, entry) + _field(3, line)
-    return _field(1, plane)
-
-
-def test_parse_xplane_synthetic_bytes():
-    """Device-plane events sum into per-op seconds; host planes are
-    ignored (their python-function names would pollute the join)."""
-    space = (_xplane(b"/device:TPU:0", b"fusion.9", 2_000_000)
-             + _xplane(b"/host:CPU", b"python_call", 9_000_000))
-    times = capture_mod.parse_xplane(space)
-    assert times == {"fusion.9": pytest.approx(2e-6)}
-
-
-def test_capture_typed_fallback(monkeypatch):
-    """A failing profiler degrades to an unavailable table + once-only
-    counter, never an exception on the caller."""
-
-    def boom(*a, **kw):
-        raise RuntimeError("no profiler on this backend")
-
-    monkeypatch.setattr(jax.profiler, "trace", boom)
-    telemetry.reset()
-    capture_mod._capture_noted = False
-    try:
-        table = profiling.capture_op_times(lambda: None, steps=1)
-        assert not table.available
-        assert table.seconds == {}
-        snap = telemetry.get_registry().snapshot()
-        assert snap["counters"]["profile.op.capture_unavailable"] == 1
-    finally:
-        capture_mod._capture_noted = False
-        telemetry.reset()
 
 
 # ------------------------------------------- host_async MFU satellite
