@@ -434,6 +434,34 @@ def leg_kernels():
           jax.jit(fa.paged_flash_attention)(qd, kp, vp, table, index),
           jax.jit(paged_ref)(qd, kp, vp, table, index), BF16_TOL)
 
+    # the rectangular pool's decode attention at gpt2-medium's pool: 16
+    # heads of 64 a 1024-wide line, 1024 positions a row, a decode step's
+    # 2 query positions; lanes from the scratch row's length 0 to a row's
+    # end, rows out of lane order; the reference gathers whole rows
+    from distkeras_tpu.models.gpt import _attend_rows
+    from distkeras_tpu.ops.cache_rows import gather_rows
+    from distkeras_tpu.ops.pallas import decode_attention as da
+
+    lanes, t, h, width, max_len = 8, 2, 16, 1024, 1024
+    ql = normal((lanes, t, width), jnp.bfloat16)
+    kl, vl = (normal((lanes + 1, max_len, width), jnp.bfloat16)
+              for _ in range(2))
+    rows = jnp.asarray([3, 8, 0, 7, 1, 6, 2, 5], jnp.int32)
+    held = jnp.asarray([5, 0, 126, 127, 128, 700, max_len - 2,
+                        max_len - 1], jnp.int32)
+    assert da.dispatch(ql, kl, h)
+
+    def rows_ref(ql, kl, vl, rows, held):
+        pos = held[:, None] + jnp.arange(t)[None, :]
+        return _attend_rows(ql, gather_rows(kl, rows), gather_rows(vl, rows),
+                            pos, h)
+
+    check("pool decode attention",
+          jax.jit(lambda *a: da.pool_attention(*a, h))(ql, kl, vl, rows,
+                                                       held),
+          jax.jit(rows_ref)(ql, kl, vl, rows, held), BF16_TOL)
+    del kl, vl
+
     # int8 matmul-dequant at GPT-2-small's MLP-in Dense: [1024 tokens, 768]
     # x [768, 3072], every dimension a multiple of the 256 block
     (qx, qw, sxw), = im.reference_rows(sizes=((1024, 768, 3072),))

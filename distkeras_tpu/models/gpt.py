@@ -34,20 +34,28 @@ there is kept position-minor, and every step pays to turn it round).
 ``cache_index[b]`` is the number of tokens already cached for lane
 ``b``, i.e. the position of this call's first input token. The module
 writes the block's K/V lines into the cache IN PLACE, first, then
-attends over the FULL fixed-length rows with positions
-``> cache_index + q`` masked to exact-zero softmax weight, and returns
-``(logits, new_cache)``: every cell the write changes is either an
-in-call position or masked, so writing first changes no output. One
-code path covers both phases: prefill is a T-token call at
-``cache_index=0``, decode a short call at ``cache_index=lengths``.
-Lane ``b`` reads and writes cache row ``b``; ``cache_rows`` (``[batch]``
-row ids) points the lanes at rows of a larger cache instead — the
-serving slot pool, which the step then updates in place and attends
-through a gather, building no second rectangle. Because the attention
-contraction always runs over ``max_len`` keys with an exact-zero tail,
-decode logits equal (f32, to rounding order) the standard full forward
-evaluated at the same ``max_len`` padded shape (NUMERICS.md
-"Decode-step equivalence"); cache mode requires ``attention="full"``.
+attends with positions ``> cache_index + q`` masked to exact-zero
+softmax weight, and returns ``(logits, new_cache)``: every cell the
+write changes is either an in-call position or masked, so writing first
+changes no output. One code path covers both phases: prefill is a
+T-token call at ``cache_index=0``, decode a short call at
+``cache_index=lengths``. Lane ``b`` reads and writes cache row ``b``;
+``cache_rows`` (``[batch]`` row ids) points the lanes at rows of a
+larger cache instead — the serving slot pool, which the step then
+updates in place. What the attention reads of a row depends on where it
+runs. **A short block on a TPU** (``t * heads`` within one pass of the
+MXU's rows: decode, verify, a short chunk) attends each lane's row IN
+THE POOL, block by block up to ``cache_index + t`` and no further, in
+one Pallas call with an online softmax
+(``ops/pallas/decode_attention.py``): no copy of the lanes' rows exists
+and the bytes read follow what the lanes hold. **Everywhere else** (the
+CPU, a long block, a shape that kernel declines) the lanes' rows are
+gathered whole (``ops/cache_rows.gather_rows``) and the contraction runs
+over all ``max_len`` keys with an exact-zero tail. Either way decode
+logits equal the standard full forward evaluated at the same
+``max_len`` padded shape to the order the sums are taken in (f32:
+NUMERICS.md "Decode-step equivalence"); cache mode requires
+``attention="full"``.
 
 Paged decode mode (DESIGN.md §19): passing ``page_table`` alongside
 ``cache`` switches the cache layout from one ``max_len`` row per batch
@@ -95,14 +103,16 @@ from distkeras_tpu.models.remat import remat_wrap
 from distkeras_tpu.models.transformer import MlpBlock
 from distkeras_tpu.ops.attention import MASK_VALUE, dot_product_attention
 from distkeras_tpu.ops.cache_rows import gather_rows
+from distkeras_tpu.ops.pallas import decode_attention as _pool_kernel
 from distkeras_tpu.ops.ring_attention import ring_attention
 
 #: the ``jax.named_scope`` names this file's forward declares, serve and
 #: train: every operation it traces lies under one, and
 #: ``profiling/scopes.py`` gives an executable's instruction to the
 #: innermost one on its ``op_name`` path. ``attn.cache`` is the lanes' K and
-#: V rows read out of the pool (or a row's pages), ``cache.write`` the
-#: block's lines written into it
+#: V rows read out of the pool (or a row's pages): empty in a short block's
+#: step on a TPU, which attends in the pool under ``attn.scores`` alone;
+#: ``cache.write`` the block's lines written into it
 SCOPES = ("embed", "norm", "attn.qkv", "attn.cache", "attn.scores",
           "attn.out", "cache.write", "mlp", "head")
 
@@ -262,6 +272,15 @@ class CausalSelfAttention(nn.Module):
                 new_cache = {
                     "k": cache["k"].at[rows, pos].set(lines(k), mode="drop"),
                     "v": cache["v"].at[rows, pos].set(lines(v), mode="drop")}
+            if _pool_kernel.dispatch(lines(q), new_cache["k"],
+                                     self.num_heads):
+                # a short block on a TPU: one call attends each lane's
+                # row in the pool, as far as the lane has written
+                with jax.named_scope("attn.scores"):
+                    out = _pool_kernel.pool_attention(
+                        lines(q), new_cache["k"], new_cache["v"],
+                        cache_rows, cache_index, self.num_heads)
+                return project(out), new_cache
             with jax.named_scope("attn.cache"):
                 k_rows = gather_rows(new_cache["k"], cache_rows)
                 v_rows = gather_rows(new_cache["v"], cache_rows)
@@ -376,6 +395,22 @@ class CausalLM(nn.Module):
         equivalence")."""
         del block
         return self.max_len
+
+    def decode_read_block(self, block: int, dtype=None) -> int:
+        """Positions by which a ``block``-token step over a pool of
+        ``dtype`` bounds what it reads of a lane's row: a lane that has
+        written ``n`` positions costs ``n`` rounded up to this many. 0
+        where the step reads the whole row whatever the lane holds (off
+        the TPU, a long block, a shape the pool kernel declines)."""
+        own = precision_lib.resolve(self.precision, self.dtype)[0]
+        pool = jax.ShapeDtypeStruct((1, self.max_len, self.width),
+                                    own if dtype is None else dtype)
+        if not _pool_kernel.dispatch(
+                jax.ShapeDtypeStruct((1, block, self.width), own), pool,
+                self.num_heads):
+            return 0
+        return _pool_kernel.block_positions(
+            self.width, pool.dtype.itemsize, self.max_len)
 
     @nn.compact
     def __call__(self, input_ids, train: bool = False, cache=None,

@@ -22,7 +22,9 @@ def kernel_registry() -> dict:
     breaks on) any individual kernel module. Each entry:
     ``{"module", "flag", "enabled"}`` — ``enabled`` is the raw ablation
     flag (NOT the and-with-on-tpu dispatch predicate: the report asks
-    "is the switch thrown", not "would it dispatch on this host").
+    "is the switch thrown", not "would it dispatch on this host"). A
+    kernel that no flag selects (``"flag": None``: the code takes it
+    wherever the backend and the shapes allow) is always enabled.
     Tags with no in-tree kernel ("memory-layout", "comms-overlap" — the
     latter is a runner mode, not a kernel) are honestly absent.
     """
@@ -33,6 +35,13 @@ def kernel_registry() -> dict:
             "module": "distkeras_tpu.ops.pallas.flash_attention",
             "flag": "USE_FLASH_ATTENTION",
             "enabled": flash_attention.USE_FLASH_ATTENTION,
+        },
+        # the gpt decode step's attention over the rows of the KV pool
+        # (PERF.md, PR 38): chosen by backend and shape, not by a switch
+        "pallas-decode-attention": {
+            "module": "distkeras_tpu.ops.pallas.decode_attention",
+            "flag": None,
+            "enabled": True,
         },
         # nearest in-tree kernel for the fp8-matmul tag: the fused int8
         # matmul (same MXU-narrow-dtype bet; fp8 proper needs hardware

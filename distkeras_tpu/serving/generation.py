@@ -209,14 +209,23 @@ def make_prefill_fn(model, dtype=None):
     return prefill
 
 
+def decode_block_tokens(model) -> int:
+    """Positions a lane feeds a decode step: ``[token, ghost]``, or the
+    token alone for a model that declares state leaves or selects what it
+    reads (:func:`make_decode_fn`)."""
+    return 1 if state_leaves(model) or select_leaves(model) else 2
+
+
 def make_decode_fn(model):
     """Pure ``(params, pool, slot_ids[n], tokens[n], lengths[n]) ->
     (pool', logits[n, V])``: advance ``n`` lanes one token. Each lane
     feeds ``[token, GHOST_TOKEN]`` at positions ``[len, len+1]`` (the
     ghost keeps every matmul on the gemm path — see module docstring).
     The model writes both K/V lines into pool row ``slot_ids[i]`` in
-    place and attends the lanes' rows where they lie
-    (``cache_rows=slot_ids``); only the real position's logits return.
+    place and attends the lanes' rows (``cache_rows=slot_ids``): on a TPU
+    in the pool itself, each row as far as its lane has written
+    (``CausalLM``: ``ops/pallas/decode_attention.py``), elsewhere gathered
+    whole; only the real position's logits return.
     The ghost's line sits past the lane's new length, masked until the
     next token overwrites it, and is dropped at ``max_len``. Padded
     lanes point at the pool's scratch row with length 0; their writes
@@ -242,7 +251,7 @@ def make_decode_fn(model):
     import jax
     import jax.numpy as jnp
 
-    ghost = not (state_leaves(model) or select_leaves(model))
+    ghost = decode_block_tokens(model) == 2
 
     def decode(params, pool, slot_ids, tokens, lengths):
         # a state a row would be advanced by the ghost too, and a
@@ -808,6 +817,16 @@ class GenerationEngine:
         self._trace_rows_c = telemetry.counter("serving.decode.trace_rows")
         self._device_picks_c = telemetry.counter(
             "serving.decode.device_picks")
+        # K/V positions a decode step's attention reads, beside the rows'
+        # whole length: the model says by what block its step bounds the
+        # read (0: it reads whole rows, as every paged step does)
+        self._kv_read_c = telemetry.counter(
+            "serving.decode.kv_positions_read")
+        self._kv_row_c = telemetry.counter("serving.decode.kv_positions_row")
+        self._step_tokens = decode_block_tokens(model)
+        bounded = getattr(model, "decode_read_block", None)
+        self._kv_read_block = 0 if self._paged or bounded is None else \
+            bounded(self._step_tokens, dtype)
         self._stream_err_c = telemetry.counter("serving.decode.stream_errors")
         self._loop_err_c = telemetry.counter("serving.decode.loop_errors")
         self._prefill_h = telemetry.histogram("serving.decode.prefill_s")
@@ -1781,6 +1800,7 @@ class GenerationEngine:
         dt = time.perf_counter() - tp0
         self._steps_c.inc()
         self._tokens_c.inc(n)
+        self._count_kv_read(lengths)
         if self._device_pick:
             self._device_picks_c.inc(n)
         self._step_h.record(dt)
@@ -1815,6 +1835,20 @@ class GenerationEngine:
                 if self._emit(req, s) is not None:
                     del active[s]
                 sched.lap("retire")
+
+    def _count_kv_read(self, lengths: np.ndarray) -> None:
+        """A decode step's K/V read into ``serving.decode.kv_positions_*``:
+        each lane's positions with the block the step wrote (padded lanes,
+        at length 0, too), rounded up to the model's read block and cut at
+        the row's end, beside the whole rows."""
+        row = read = self.pool.max_len * len(lengths)
+        block = self._kv_read_block
+        if block:
+            held = lengths + self._step_tokens
+            read = int(np.minimum(-(-held // block) * block,
+                                  self.pool.max_len).sum())
+        self._kv_read_c.inc(read)
+        self._kv_row_c.inc(row)
 
     def _record_routing(self, held: np.ndarray, lanes: int) -> None:
         """A decode step's tokens per held expert, ``[layers,

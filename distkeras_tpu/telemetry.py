@@ -221,6 +221,11 @@ METRIC_NAMES = {
     "serving.decode.compiles": "counter",
     "serving.decode.deadline_exceeded": "counter",
     "serving.decode.device_picks": "counter",
+    # positions of K and V a decode step's attention read (each lane's
+    # length rounded up to the model's read block; the whole row where the
+    # model bounds nothing), beside lanes x max_len
+    "serving.decode.kv_positions_read": "counter",
+    "serving.decode.kv_positions_row": "counter",
     "serving.decode.loop_errors": "counter",
     "serving.decode.padded_lanes": "histogram",
     "serving.decode.prefill_s": "histogram",

@@ -113,10 +113,22 @@ def _rectangle_moves(text, least=QUARTER, line=None):
     return found
 
 
+@pytest.fixture
+def as_on_the_chip(monkeypatch):
+    """``models/gpt.py`` asks what the backend is, and here that is the
+    CPU whatever the compile is for: answer for the described chip, so
+    that the step compiled is the one the chip runs (a short block attends
+    in the pool, ``ops/pallas/decode_attention.py``)."""
+    from distkeras_tpu.ops.pallas import decode_attention
+
+    monkeypatch.setattr(decode_attention, "_on_tpu", lambda: True)
+
+
 @pytest.mark.parametrize("step,size", [
     ("decode", 8), ("decode", 16), ("decode", 32),
     ("prefill", 64), ("prefill", 768), ("verify", 32)])
-def test_compiled_step_keeps_the_pool_as_stored(shapes, step, size):
+def test_compiled_step_keeps_the_pool_as_stored(shapes, as_on_the_chip,
+                                                step, size):
     text = _compile(shapes, step, size)
     leaves = _pool_layouts(text)
     # 2 layers x (k, v), as parameters and as results
@@ -126,6 +138,63 @@ def test_compiled_step_keeps_the_pool_as_stored(shapes, step, size):
         default = ",".join(str(d) for d in reversed(range(rank)))
         assert layout == default, f"bf16[{dims}] is kept as {{{layout}}}"
     assert _rectangle_moves(text) == []
+
+
+def _lanes_rows(text, lanes):
+    """Instructions of the entry computation whose result is the lanes'
+    whole rows, ``[lanes, max_len, width]``, or those rows in runs,
+    ``[lanes * runs, max_len / runs, width]`` (``gather_rows``): what the
+    fixed-length path reads out of the pool."""
+    entry = text[text.index("\nENTRY"):]
+    found = []
+    for m in re.finditer(rf"= \(?bf16\[(\d+),(\d+),{WIDTH}\]\S* ([\w\-]+)\(",
+                         entry):
+        first, second = int(m.group(1)), int(m.group(2))
+        if first * second == lanes * MAX_LEN and first % lanes == 0:
+            found.append(m.group(0))
+    return found
+
+
+@pytest.mark.parametrize("step,size", [
+    ("decode", 8), ("decode", 16), ("decode", 32), ("verify", 32)])
+def test_short_block_attends_in_the_pool(shapes, as_on_the_chip, step,
+                                         size):
+    """One Mosaic call a layer under ``attn.scores``; no gather, fusion or
+    anything else whose result is the lanes' rows; each call's two leaves
+    are what ``cache.write`` left in place (the scatter's result, itself
+    aliased to the pool's parameter), in the default layout, with no copy
+    between; and the leaves are still donated through the step."""
+    text = _compile(shapes, step, size)
+    calls = [line for line in text.split("\n")
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2                       # the layers
+    assert _lanes_rows(text, size) == []
+    assert "/attn.cache/" not in text
+    pool = rf"bf16\[{NUM_SLOTS + 1},{MAX_LEN},{WIDTH}\]"
+    for call in calls:
+        assert re.search(r'op_name="[^"]*/attn\.scores/', call), call
+        constraints = call[call.index("operand_layout_constraints"):]
+        assert len(re.findall(pool + r"\{2,1,0\}", constraints)) == 2
+        operands = call[call.index("custom-call(") + 12:].split(")")[0]
+        for leaf in operands.split(", ")[-2:]:
+            made = re.search(rf"\n\s*{re.escape(leaf)} = ({pool})\S* "
+                             rf"(\w+)\((%[\w.\-]+)[^\n]*", text)
+            assert made, leaf
+            assert made.group(2) == "fusion", made.group(0)[:200]
+            assert "/cache.write/" in made.group(0)
+            assert made.group(3).startswith("%pool_"), made.group(0)[:200]
+    assert _rectangle_moves(text) == []
+    aliases = text[text.index("input_output_alias"):].split("\n", 1)[0]
+    assert aliases.count("may-alias") + aliases.count("must-alias") == 4
+
+
+def test_off_the_chip_the_step_gathers_the_lanes_rows(shapes):
+    """Unsteered, the same compile takes the path the CPU runs (and the
+    paged fallback): the instrument above sees the rows it looks for."""
+    text = _compile(shapes, "decode", 32)
+    assert 'custom_call_target="tpu_custom_call"' not in text
+    assert len(_lanes_rows(text, 32)) >= 4       # k and v, two layers
+    assert "/attn.cache/" in text
 
 
 # ------------------------------------------------- the latent line (PR 27)
@@ -176,7 +245,7 @@ def test_compiled_latent_step_keeps_the_pool_as_stored(latent_shapes, step,
 @pytest.mark.parametrize("family,line", [
     ("gpt2_medium", None), ("mistral_small_4", 384)])
 def test_greedy_step_hands_back_tokens_and_keeps_the_pool(
-        shapes, latent_shapes, family, line):
+        shapes, latent_shapes, as_on_the_chip, family, line):
     """What a greedy ``GenerationEngine`` compiles per ladder entry
     (``pick_on_device`` around ``make_decode_fn``, the pool donated), at
     32 lanes: the executable keeps the name the trace readers look for,

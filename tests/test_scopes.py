@@ -165,6 +165,36 @@ def test_tiled_layouts_parse_and_the_compilers_own_copies_are_inferred():
         "(bf16[8,128],bf16[8,128],u32[])"
 
 
+_KERNEL_TEXT = """\
+HloModule jit_decode, is_scheduled=true
+
+ENTRY %main.9 (rows.1: s32[32], len.1: s32[32], q.1: bf16[32,32,1024], k.1: bf16[33,1024,1024], v.1: bf16[33,1024,1024]) -> f32[32,2,1024] {
+  %rows.1 = s32[32]{0:T(128)} parameter(0)
+  %len.1 = s32[32]{0:T(128)} parameter(1)
+  %q.1 = bf16[32,32,1024]{2,1,0:T(8,128)(2,1)} parameter(2)
+  %k.1 = bf16[33,1024,1024]{2,1,0:T(8,128)(2,1)} parameter(3)
+  %v.1 = bf16[33,1024,1024]{2,1,0:T(8,128)(2,1)} parameter(4)
+  ROOT %pool_attention.2 = f32[32,2,1024]{2,1,0:T(2,128)S(1)} custom-call(%rows.1, %len.1, %q.1, %k.1, %v.1), custom_call_target="tpu_custom_call", operand_layout_constraints={s32[32]{0}, s32[32]{0}, bf16[32,32,1024]{2,1,0}, bf16[33,1024,1024]{2,1,0}, bf16[33,1024,1024]{2,1,0}}, frontend_attributes={kernel_metadata={}}, metadata={op_name="jit(decode)/CausalLM/layer_0/attn/attn.scores/jit(_pool_attention)/pool_attention/pallas_call" stack_frame_id=56}, backend_config={"custom_call_config":{"body":"TUzvUg"}}
+}
+"""
+
+
+def test_a_mosaic_call_is_a_row_of_the_scope_it_was_traced_under():
+    """The gpt decode step's attention on a TPU is one custom call a
+    layer (``ops/pallas/decode_attention.py``), traced under
+    ``attn.scores``: a row of its own there, by the line the TPU compiler
+    writes for it, so ``decode_attn_device_share`` and
+    ``scope_attributed_share`` hold its seconds. Nothing of the step is
+    under ``attn.cache`` any more; the name stays declared for the paths
+    that still read rows out of a pool."""
+    table = scopes.table_of(_KERNEL_TEXT)
+    assert [(r.name, r.opcode, r.scope, r.scope_inferred)
+            for r in table.rows] == [
+        ("pool_attention.2", "custom-call", "attn.scores", False)]
+    assert scopes.plain_type(table.rows[0].out_type) == "f32[32,2,1024]"
+    assert {"attn.cache", "attn.scores"} <= set(scopes.declared_scopes())
+
+
 # --------------------------------------- (b) the four families' executables
 
 #: rehearsal configuration -> the scopes its family's file declares that
