@@ -386,7 +386,7 @@ def leg_kernels():
     # shape (sequence 2048, 12 heads of 64, bf16); batch cut to 2
     # because the XLA reference materializes [b, h, T, T] f32 logits
     q, k, v, w = (normal((2, 2048, 12, 64), jnp.bfloat16) for _ in range(4))
-    assert fa.fits(q.shape)
+    assert fa.fits(q.shape, q.dtype)
 
     def loss(f):
         return lambda q, k, v: jnp.sum(

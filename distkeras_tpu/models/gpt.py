@@ -4,7 +4,14 @@ No reference parity (dist-keras predates transformers; SURVEY.md §5 marks
 long-context ABSENT) — this is the framework's first-class long-context
 story: a GPT-style decoder whose attention can run either
 
-- ``attention="full"``: single-device causal attention,
+- ``attention="full"``: single-device causal attention; with no cache, on
+  a TPU, on one device as traced (a ``shard_map`` body or a single-device
+  program; GSPMD over a mesh keeps XLA, which it partitions) and at a
+  shape the kernel takes, the fused training kernel of
+  ``ops/pallas/flash_attention.py`` (forward and backward, no ``[t, t]``
+  scores in HBM), elsewhere ``dot_product_attention`` (long sequences
+  too: the kernel keeps a lane block's whole sequence in VMEM, so past
+  2048 positions of 64-wide bfloat16 heads they keep the O(t^2) path),
 - ``attention="flash"``: the fused pallas TPU kernel (O(seq) memory,
   TPU only; measured 1.4x over the XLA path at seq 8192 on v5e on an
   earlier installation, not re-measured), or
@@ -99,11 +106,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from distkeras_tpu import precision as precision_lib
+from distkeras_tpu import telemetry
 from distkeras_tpu.models.remat import remat_wrap
 from distkeras_tpu.models.transformer import MlpBlock
 from distkeras_tpu.ops.attention import MASK_VALUE, dot_product_attention
 from distkeras_tpu.ops.cache_rows import gather_rows
 from distkeras_tpu.ops.pallas import decode_attention as _pool_kernel
+from distkeras_tpu.ops.pallas import flash_attention as _flash_kernel
 from distkeras_tpu.ops.ring_attention import ring_attention
 
 #: the ``jax.named_scope`` names this file's forward declares, serve and
@@ -301,7 +310,16 @@ class CausalSelfAttention(nn.Module):
                 out = apply_attention(q, k, v, causal=True,
                                       attention="flash")
             elif self.attention == "full":
-                out = dot_product_attention(q, k, v, causal=True)
+                # on a TPU, one device's call at a shape the training
+                # kernel takes attends in one fused causal kernel, forward
+                # and backward: no [t, t] scores in HBM (PERF.md §6); XLA
+                # everywhere else, GSPMD over a mesh included
+                telemetry.counter("model.attention.calls").inc()
+                if _flash_kernel.train_dispatch(q):
+                    telemetry.counter("model.attention.fused_calls").inc()
+                    out = _flash_kernel.flash_attention(q, k, v, causal=True)
+                else:
+                    out = dot_product_attention(q, k, v, causal=True)
             else:
                 raise ValueError(
                     f"Unknown attention {self.attention!r}; "

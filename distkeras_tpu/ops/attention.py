@@ -99,14 +99,15 @@ def apply_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         raise ValueError(
             "attention='flash': the fused kernels know only the causal "
             "mask, not a padding mask; use attention='xla'")
-    if _fa.USE_FLASH_ATTENTION and _fa.fits(q.shape):
+    fits = _fa.fits(q.shape, q.dtype)
+    if _fa.USE_FLASH_ATTENTION and fits:
         return _fa.flash_attention(q, k, v, causal=causal)
     if not causal:
         raise ValueError(
             f"attention='flash' on a bidirectional call of shape "
             f"{q.shape}: the upstream kernel is causal-only and the in-repo "
             f"kernel declined (USE_FLASH_ATTENTION="
-            f"{_fa.USE_FLASH_ATTENTION}, fits={_fa.fits(q.shape)}); use "
+            f"{_fa.USE_FLASH_ATTENTION}, fits={fits}); use "
             f"attention='xla'")
     return flash_attention_causal(q, k, v)
 

@@ -212,6 +212,10 @@ METRIC_NAMES = {
     "serving.server.requests": "counter",
     "serving.shutdown_timeouts": "counter",
     "serving.submitted": "counter",
+    # the causal attention calls of models/gpt.py with no cache, as traced,
+    # and those of them that run the fused training kernel
+    "model.attention.calls": "counter",
+    "model.attention.fused_calls": "counter",
     # generative serving (KV-cache decode loop, DESIGN.md §14)
     "serving.decode.admitted": "counter",
     "serving.decode.cache_bytes": "gauge",

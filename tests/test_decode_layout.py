@@ -23,12 +23,14 @@ written. Its cases compile the steps at the published widths, 2 layers,
 and hold them to the same two assertions.
 """
 
+import contextlib
 import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from distkeras_tpu.models import gpt as gpt_lib
@@ -118,10 +120,12 @@ def as_on_the_chip(monkeypatch):
     """``models/gpt.py`` asks what the backend is, and here that is the
     CPU whatever the compile is for: answer for the described chip, so
     that the step compiled is the one the chip runs (a short block attends
-    in the pool, ``ops/pallas/decode_attention.py``)."""
-    from distkeras_tpu.ops.pallas import decode_attention
+    in the pool, ``ops/pallas/decode_attention.py``; a call with no cache
+    in the training kernel, ``ops/pallas/flash_attention.py``)."""
+    from distkeras_tpu.ops.pallas import decode_attention, flash_attention
 
     monkeypatch.setattr(decode_attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
 
 
 @pytest.mark.parametrize("step,size", [
@@ -195,6 +199,130 @@ def test_off_the_chip_the_step_gathers_the_lanes_rows(shapes):
     assert 'custom_call_target="tpu_custom_call"' not in text
     assert len(_lanes_rows(text, 32)) >= 4       # k and v, two layers
     assert "/attn.cache/" in text
+
+
+_TRAINING_KERNEL = re.compile(r"%flash_attention_(fwd|bwd)\.\d+ = ")
+
+
+@pytest.mark.parametrize("step,size", [("decode", 32), ("prefill", 768)])
+def test_serving_steps_lower_without_the_training_kernel(
+        shapes, as_on_the_chip, step, size):
+    """The cache branch decides for itself: on the chip a serving step, a
+    768-token prefill too, has none of the training kernel's calls."""
+    assert _TRAINING_KERNEL.search(_compile(shapes, step, size)) is None
+
+
+def _token_loss(model):
+    def loss(p, x):
+        logits = model.apply({"params": p}, x, train=True)
+        return -jnp.mean(jnp.take_along_axis(
+            jax.nn.log_softmax(logits), x[..., None], axis=-1))
+    return loss
+
+
+@pytest.mark.parametrize("on_the_chip", [True, False])
+def test_training_step_attends_in_the_fused_kernel(one_chip, monkeypatch,
+                                                   on_the_chip):
+    """value_and_grad of the token loss at gpt2-medium's widths, 2 layers
+    under ``remat="blocks"``, one 1024-token sequence, on a mesh of the one
+    described chip (as a one-chip trainer runs it). On the chip each
+    layer's attention is the training kernel's forward, its recompute and
+    its backward under ``attn.scores``, and no ``[t, t]`` array of a
+    head's scores exists; unsteered (the CPU's path) the scores are
+    there, so the instrument sees what it looks for."""
+    if on_the_chip:
+        from distkeras_tpu.ops.pallas import flash_attention
+        monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
+    model = gpt_lib.CausalLM(vocab_size=50304, max_len=MAX_LEN,
+                             num_layers=2, num_heads=16, width=WIDTH,
+                             mlp_dim=4096, remat="blocks")
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: model.init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+    ids = jax.ShapeDtypeStruct((1, MAX_LEN), np.int32, sharding=one_chip)
+
+    with jax.set_mesh(Mesh(np.array(list(one_chip.device_set)),
+                           ("workers",))):
+        text = jax.jit(jax.value_and_grad(_token_loss(model))).lower(
+            params, ids).compile().as_text()
+    calls = [m for m in _TRAINING_KERNEL.finditer(text)]
+    scores = re.findall(rf"\[(?:1,)?16,{MAX_LEN},{MAX_LEN}\]", text)
+    if not on_the_chip:
+        assert calls == [] and scores
+        return
+    assert sorted(m.group(1) for m in calls) == ["bwd"] * 2 + ["fwd"] * 4
+    for m in calls:
+        line = text[m.start():].split("\n", 1)[0]
+        assert re.search(r'op_name="[^"]*/attn\.scores/', line), line[:300]
+    assert scores == []
+
+
+@pytest.fixture(scope="module")
+def four_chips():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e topology can be described here: {e}")
+    return np.array(topo.devices)
+
+
+@pytest.mark.parametrize("form", ["shard_map", "gspmd", "gspmd_in_mesh"])
+def test_training_step_on_four_chips(four_chips, monkeypatch, form):
+    """value_and_grad of a 2-layer model (8 heads of 64, 512 tokens, a
+    batch of 4) for a described 2x2 v5e, the backend answering "TPU". A
+    ``shard_map`` body over the four chips (the trainers' form, with its
+    varying axes checked) runs the training kernel on each chip's rows and
+    gathers nothing. Under GSPMD (``PjitTrainer``'s form: batch over
+    ``workers``, heads over ``model`` by ``tensor.partition_specs``; with
+    and without the mesh in the trace) the kernel, which the partitioner
+    cannot split, is not taken: attention is XLA's, split to each chip's
+    2 rows and 4 heads."""
+    from distkeras_tpu.ops.pallas import flash_attention
+    from distkeras_tpu.parallel import tensor
+
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
+    t = 512
+    model = gpt_lib.CausalLM(vocab_size=1024, max_len=t, num_layers=2,
+                             num_heads=8, width=512, mlp_dim=1024,
+                             remat="blocks")
+    loss = _token_loss(model)
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    put = lambda mesh, spec: lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=NamedSharding(mesh, spec))
+    if form == "shard_map":
+        mesh = Mesh(four_chips, ("workers",))
+        params = jax.tree.map(put(mesh, P()), shapes)
+
+        def step(p, x):
+            value, grads = jax.value_and_grad(loss)(p, x)
+            return jax.lax.pmean((value, grads), "workers")
+
+        fn = jax.shard_map(step, mesh=mesh, in_specs=(P(), P("workers")),
+                           out_specs=P())
+        where = contextlib.nullcontext()
+    else:
+        mesh = Mesh(four_chips.reshape(2, 2), ("workers", "model"))
+        params = jax.tree.map(
+            lambda a, spec: put(mesh, spec)(a), shapes,
+            tensor.partition_specs(shapes, mesh=mesh))
+        fn = jax.value_and_grad(loss)
+        where = (jax.set_mesh(mesh) if form == "gspmd_in_mesh"
+                 else contextlib.nullcontext())
+    ids = jax.ShapeDtypeStruct((4, t), np.int32,
+                               sharding=NamedSharding(mesh, P("workers")))
+    with where:
+        text = jax.jit(fn).lower(params, ids).compile().as_text()
+    calls = sorted(m.group(1) for m in _TRAINING_KERNEL.finditer(text))
+    assert re.findall(r"= \S+ all-gather", text) == []
+    if form == "shard_map":
+        assert calls == ["bwd"] * 2 + ["fwd"] * 4
+        return
+    assert calls == []
+    assert set(re.findall(rf"\[(\d+,\d+),{t},{t}\]", text)) == {"2,4"}
 
 
 # ------------------------------------------------- the latent line (PR 27)

@@ -103,6 +103,38 @@ def test_backward_parity_vs_reference_grads():
             err_msg=f"d{name} diverged from the reference gradient")
 
 
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("t,blocks", [
+    (256, (64, 64)),      # 4 x 4 tiles: the diagonal, below and above it
+    (256, (64, 128)),     # two q-blocks a key block
+    (256, (128, 64)),     # two key blocks a q-block, one masked half-way
+    (512, None),          # the shape's own tiles: 2 x 2 of 256
+])
+def test_backward_parity_several_blocks(causal, t, blocks):
+    """dQ, dK and dV where each key block meets several q-blocks (and each
+    q-block several key blocks): tiles off the diagonal, wholly visible
+    or skipped, and dQ summed over key blocks, against the reference."""
+    q, k, v = _qkv(b=1, t=t, h=2, d=32, seed=5)
+    block_q, block_k = blocks or (None, None)
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum(jnp.sin(f(q, k, v)))
+
+    flash = lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+        interpret=True)
+    ref = lambda q, k, v: _ref(q, k, v, causal)
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(ref(q, k, v)),
+                               rtol=1e-5, atol=1e-5)
+    g_flash = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for got, want, name in zip(g_flash, g_ref, "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-5,
+            err_msg=f"d{name} diverged from the reference gradient")
+
+
 def test_remat_composes_with_custom_vjp():
     """jax.checkpoint over the kernel recomputes the forward in the
     backward pass — gradients must be identical to the un-remat call
@@ -130,14 +162,20 @@ def test_kernel_enabled_requires_flag_and_tpu(monkeypatch):
 
 
 def test_fits_predicate():
-    assert fa.fits((2, 256, 4, 32))
-    assert fa.fits((1, 128, 1, 128))
-    assert not fa.fits((2, 100, 4, 32))    # seq not block-aligned
-    assert not fa.fits((2, 64, 4, 32))     # below one default tile
-    assert not fa.fits((2, 256, 4, 4))     # head_dim under sublane tile
-    assert not fa.fits((2, 256, 4, 130))   # head_dim over one lane tile
-    assert not fa.fits((256, 4, 32))       # rank
-    assert fa.fits((1, 64, 2, 32), block_q=64, block_k=64)  # explicit
+    f32 = jnp.float32
+    assert fa.fits((2, 256, 4, 32), f32)
+    assert fa.fits((1, 128, 1, 128), f32)
+    assert not fa.fits((2, 100, 4, 32), f32)    # seq not block-aligned
+    assert not fa.fits((2, 64, 4, 32), f32)     # below one default tile
+    assert not fa.fits((2, 256, 4, 4), f32)     # head_dim under sublane tile
+    assert not fa.fits((2, 256, 4, 130), f32)   # head_dim over one lane tile
+    assert not fa.fits((256, 4, 32), f32)       # rank
+    assert fa.fits((1, 64, 2, 32), f32, block_q=64, block_k=64)  # explicit
+    # a lane block's whole sequence is resident: the dtype sets how long
+    assert fa.fits((8, 2048, 16, 64), jnp.bfloat16)
+    assert not fa.fits((8, 2048, 16, 64), f32)
+    with pytest.raises(ValueError, match="both blocks or neither"):
+        fa.fits((1, 64, 2, 32), f32, block_q=64)
 
 
 def test_flash_attention_raises_on_unfit_shape():
@@ -146,7 +184,10 @@ def test_flash_attention_raises_on_unfit_shape():
         fa.flash_attention(q, k, v, interpret=True)
     q, k, v = _qkv(b=1, t=100, h=2, d=32)  # seq not tile-aligned
     with pytest.raises(ValueError, match="fits"):
-        fa.flash_attention(q, k, v, block_q=128, interpret=True)
+        fa.flash_attention(q, k, v, block_q=128, block_k=128,
+                           interpret=True)
+    with pytest.raises(ValueError, match="both blocks or neither"):
+        fa.flash_attention(q, k, v, block_k=128, interpret=True)
 
 
 def test_resolve_attention_modes():
