@@ -97,6 +97,31 @@ a selection (one that declares ``cache_select_leaves``) also returns
 ``attended``, ``[layers, batch, t]`` int32: the positions each query
 attended, a window layer's windows among them.
 
+**A residual of several streams** (``hc_mult = n > 1``; manifold-constrained
+hyper-connections, mHC, arXiv:2512.24880, on hyper-connections,
+arXiv:2409.19606). The residual is ``n`` streams of the model's width, held
+as one float32 array ``x [b, t, n * width]`` (stream ``i`` the ``i``-th
+``width`` values: no axis of ``n`` lies in a tile). The embedding is copied
+into every stream; around each sub-layer ``F`` (a layer's attention, its
+dense MLP or its expert layer), with parameters of its own (``phi_pre,
+phi_post [n width, n]``, ``phi_res [n width, n^2]``, ``b_pre, b_post [n]``,
+``b_res [n, n]``, scalars ``alpha_*``):
+
+- ``v = RMSNorm(x)`` over all ``n * width`` values, no scale of its own;
+  ``H_pre = sigmoid(alpha_pre v phi_pre + b_pre)``, ``H_post = 2
+  sigmoid(alpha_post v phi_post + b_post)``, ``H_res =
+  SinkhornKnopp(exp(clip(alpha_res mat(v phi_res) + b_res)))``: the
+  iterations ``M <- T_r(T_c(M))`` (columns, then rows, each divided by its
+  sum plus ``hc_eps``), row-major ``mat`` (:class:`HyperConnection`);
+- ``h = sum_i H_pre[i] x_i``; ``y = F(h)``, with ``F``'s own pre-norm;
+- ``x_i <- sum_j H_res[i, j] x_j + H_post[i] y``.
+
+The head reads ``sum_i x_i`` (HC's final sum) through the final norm. The
+mapping is float32 throughout (its product at ``precision=HIGHEST``; the
+mixes are products and sums of single values, not contractions). The cache
+is untouched: the streams are a token's activations, and the cached line is
+what attention's own projections make of ``h``.
+
 Two switches every layer of a model shares, both off by default:
 ``head_gate``, a sigmoid gate a head on the attention output, ``g_t =
 sigmoid(x_t W_g)``, ``y_t = [g_th o_th]_h W_o`` (arXiv:2505.06708, G1);
@@ -105,12 +130,14 @@ and the normed key-value latent times ``(width / kv_lora_rank)^1/2``
 (LongCat-Flash's ``mla_scale_q_lora`` / ``mla_scale_kv_lora``): the cached
 line holds the scaled latent. With nothing configured the module lowers to
 the program it lowered to before it had any of this
-(tests/test_latent_sparse.py).
+(tests/test_latent_sparse.py), one stream (``hc_mult = 1``) among it.
 """
 
 from __future__ import annotations
 
+import functools
 import math
+import operator
 from typing import NamedTuple, Optional, Tuple
 
 import flax.linen as nn
@@ -118,6 +145,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from distkeras_tpu import telemetry
 from distkeras_tpu.ops.attention import MASK_VALUE
 from distkeras_tpu.ops.cache_rows import gather_rows
 
@@ -129,13 +157,19 @@ from distkeras_tpu.ops.cache_rows import gather_rows
 #: turn, the absorbed products, the long block's up-projection of the rows
 #: it attends; ``attn.latent.square`` the long block's scores, mask,
 #: softmax and weighted sum a query block; ``cache.write`` the block's
-#: lines, index keys and ring written into the cache; a residual sum goes
-#: with the sub-layer whose result it takes
+#: lines, index keys and ring written into the cache; with one residual
+#: stream a residual sum goes with the sub-layer whose result it takes.
+#: With several (``hc_mult > 1``) the streams' update is the mechanism's
+#: own: ``hc.map`` a sub-layer's mapping (norm, projection, sigmoids,
+#: Sinkhorn), ``hc.pre`` the mix into its input (and the head's sum of the
+#: streams; the compiler fuses it into the norm that reads it, so its time
+#: reads as ``norm``'s), ``hc.post`` the update of the streams by ``H_res``
+#: and its output
 SCOPES = ("embed", "norm", "attn.latent", "attn.latent.square", "attn.index",
           "attn.select", "attn.sparse", "attn.window", "cache.write",
           "moe.route", "moe.experts", "moe.experts.gather",
           "moe.experts.products", "moe.experts.add", "moe.shared",
-          "mlp.dense", "head")
+          "mlp.dense", "head", "hc.map", "hc.pre", "hc.post")
 
 #: longest block that takes the absorbed form. By operations the two
 #: forms meet near t = 150 (absorbed pays 2.25 x the score and value
@@ -261,6 +295,8 @@ def _fan_in(key, shape, dtype):
             * shape[-2] ** -0.5).astype(dtype)
 
 
+_unit_normal = nn.initializers.normal(1.0)
+
 _MATRICES = frozenset((
     "q_a", "q_b", "kv_a", "kv_b", "o", "router", "gate", "up", "down",
     "shared_gate", "shared_up", "shared_down", "head",
@@ -275,16 +311,19 @@ def param_init(name: str):
     Norm vectors one, the embedding unit normal, every matrix and stack
     of matrices :func:`_fan_in`, a router's selection bias normal of
     deviation 0.1 (enough to move a choice, as a trained one does), the
-    index key's LayerNorm bias zero; a name it does not know raises."""
+    index key's LayerNorm bias zero; a hyper-connection's ``phi_*``
+    :func:`_fan_in`, its ``alpha_*`` one and its ``b_*`` unit normal, so
+    that ``H_res`` is far from the identity and from the uniform matrix; a
+    name it does not know raises."""
     if name == "index_k_bias":
         return nn.initializers.zeros
-    if name.endswith("norm") or "_norm_" in name:
+    if name.endswith("norm") or "_norm_" in name or name.startswith("alpha_"):
         return nn.initializers.ones
-    if name == "tok_embed":
-        return nn.initializers.normal(1.0)
+    if name == "tok_embed" or name.startswith("b_"):
+        return _unit_normal
     if name == "router_bias":
         return nn.initializers.normal(0.1)
-    if name in _MATRICES:
+    if name in _MATRICES or name.startswith("phi_"):
         return _fan_in
     raise KeyError(f"no initialiser for a parameter called {name!r}")
 
@@ -1014,6 +1053,65 @@ class DenseMLP(nn.Module):
                        mat("up", d, self.width), mat("down", self.width, d))
 
 
+class HyperConnection(nn.Module):
+    """One sub-layer's mapping of ``streams`` residual streams (module
+    docstring; mHC's equations 7 to 9): ``x [b, t, streams * width]``
+    float32 -> ``(pre [n, b, t], post [n, b, t], res [n, n, b, t])``,
+    ``H_pre``, ``H_post`` and ``H_res`` with the tokens last, so that the
+    Sinkhorn iterations' sums over rows and columns are sums of whole
+    arrays of tokens. Float32; the one product at ``precision=HIGHEST``."""
+    streams: int
+    sinkhorn_iters: int
+    eps: float
+    clamp: Tuple[float, float]
+    rms_eps: float
+
+    @nn.compact
+    def __call__(self, x):
+        n = self.streams
+        b, t, wide = x.shape
+        f32 = jnp.float32
+        vec = lambda name, *shape: self.param(name, param_init(name), shape,
+                                              f32)
+        telemetry.counter("model.hc.sublayers").inc()
+        with jax.named_scope("hc.map"):
+            v = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1,
+                                           keepdims=True) + self.rms_eps)
+            phi = jnp.concatenate([vec("phi_pre", wide, n),
+                                   vec("phi_post", wide, n),
+                                   vec("phi_res", wide, n * n)], axis=1)
+            a = jnp.einsum("btc,ck->kbt", v, phi,
+                           precision=jax.lax.Precision.HIGHEST)
+            bias = lambda name, *shape: vec(name, *shape).reshape(-1, 1, 1)
+            pre = jax.nn.sigmoid(vec("alpha_pre") * a[:n]
+                                 + bias("b_pre", n))
+            post = 2.0 * jax.nn.sigmoid(vec("alpha_post") * a[n:2 * n]
+                                        + bias("b_post", n))
+            res = vec("alpha_res") * a[2 * n:] + bias("b_res", n, n)
+            m = jnp.exp(jnp.clip(res, *self.clamp)).reshape(n, n, b, t)
+            for _ in range(self.sinkhorn_iters):
+                m = m / (jnp.sum(m, axis=0, keepdims=True) + self.eps)
+                m = m / (jnp.sum(m, axis=1, keepdims=True) + self.eps)
+        return pre, post, m
+
+
+def _streams(x, n: int):
+    """The ``n`` streams of ``x [b, t, n * width]``, each ``[b, t,
+    width]``."""
+    c = x.shape[-1] // n
+    return [x[..., i * c:(i + 1) * c] for i in range(n)]
+
+
+def _add(parts):
+    """``parts[0] + parts[1] + ...``, in that order."""
+    return functools.reduce(operator.add, parts)
+
+
+def _merged(x, n: int):
+    """HC's final sum of the ``n`` streams of ``x``: what the head reads."""
+    return _add(_streams(x, n))
+
+
 class WindowSizes(NamedTuple):
     """A window layer's own sizes: the causal window (it counts the query
     itself), the cells of the ring that holds it (at least ``window +
@@ -1048,7 +1146,10 @@ class LatentMoELM(nn.Module):
     full (the sizes above, under the indexer if there is one), ``"S"`` a
     window layer at ``window_sizes`` whose cache is a ring; empty: every
     layer ``"F"``. ``head_gate`` and ``rank_rescale`` are every layer's
-    (module docstring)."""
+    (module docstring). ``hc_mult > 1`` makes the residual that many
+    streams under manifold-constrained hyper-connections (module
+    docstring): ``hc_sinkhorn_iters`` iterations, ``hc_eps`` on each sum,
+    ``hc_clamp`` the interval ``H_res``'s logits are clipped to."""
     vocab_size: int
     max_len: int
     num_layers: int
@@ -1083,6 +1184,10 @@ class LatentMoELM(nn.Module):
     window_sizes: Optional[WindowSizes] = None
     head_gate: bool = False
     rank_rescale: bool = False
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: Tuple[float, float] = (-30.0, 30.0)
 
     @property
     def experts_held(self) -> int:
@@ -1197,6 +1302,9 @@ class LatentMoELM(nn.Module):
             else:
                 pos = cache_index[:, None] + jnp.arange(t)[None, :]
             x = embed[ids].astype(jnp.float32)
+            n = self.hc_mult
+            if n > 1:                   # the embedding in every stream
+                x = jnp.tile(x, (1, 1, n))
 
         @jax.named_scope("norm")
         def norm(name, a):
@@ -1204,31 +1312,61 @@ class LatentMoELM(nn.Module):
                 a, self.param(name, param_init(name), (self.width,),
                               jnp.float32), self.rms_eps)
 
+        def enter(name, x):
+            """A sub-layer's input and the mapping around it (``None``
+            with one stream: the input is the residual itself)."""
+            if n == 1:
+                return x, None
+            hc = HyperConnection(n, self.hc_sinkhorn_iters, self.hc_eps,
+                                 self.hc_clamp, self.rms_eps, name=name)(x)
+            with jax.named_scope("hc.pre"):
+                return _add([w[..., None] * s for w, s in
+                             zip(hc[0], _streams(x, n))]), hc
+
+        def leave(x, y, hc):
+            """The residual after a sub-layer's output ``y``: its sum, or
+            with several streams ``x_i <- sum_j H_res[i, j] x_j + H_post[i]
+            y``."""
+            if hc is None:
+                return x + y.astype(jnp.float32)
+            _, post, res = hc
+            y = y.astype(jnp.float32)
+            with jax.named_scope("hc.post"):
+                old = _streams(x, n)
+                return jnp.concatenate([
+                    _add([res[i, j][..., None] * s for j, s in enumerate(old)])
+                    + post[i][..., None] * y for i in range(n)], axis=-1)
+
         new_cache, routed, attended = [], [], []
         for i, kind in enumerate(self.kinds):
+            h, hc = enter(f"hc_attn_{i}", x)
             with jax.named_scope("attn.latent"):
                 y, layer_cache, chosen = self._attention(kind, f"attn_{i}")(
-                    norm(f"attn_norm_{i}", x), pos,
+                    norm(f"attn_norm_{i}", h), pos,
                     None if cache is None else cache[i], cache_rows,
                     real_len)
-                x = x + y.astype(jnp.float32)
+                x = leave(x, y, hc)
             new_cache.append(layer_cache)
             attended.append(chosen)
+            h, hc = enter(f"hc_mlp_{i}", x)
             if i < self.dense_layers:
                 with jax.named_scope("mlp.dense"):
-                    x = x + DenseMLP(self.dense_width, self.dtype,
-                                     name=f"mlp_{i}")(
-                        norm(f"mlp_norm_{i}", x)).astype(jnp.float32)
+                    x = leave(x, DenseMLP(self.dense_width, self.dtype,
+                                          name=f"mlp_{i}")(
+                        norm(f"mlp_norm_{i}", h)), hc)
                 continue
             y, sent = ExpertShare(
                 self.moe_width, self.num_experts, self.experts_per_token,
                 self.expert_share, self.routed_scaling, self.dtype,
                 self.scoring, name=f"moe_{i}")(
-                    norm(f"moe_norm_{i}", x).reshape(b * t, self.width))
+                    norm(f"moe_norm_{i}", h).reshape(b * t, self.width))
             with jax.named_scope("moe.shared"):
-                x = x + y.reshape(b, t, self.width)
+                x = leave(x, y.reshape(b, t, self.width), hc)
             with jax.named_scope("moe.route"):
                 routed.append(sent.reshape(b, t, -1))
+        if n > 1:
+            with jax.named_scope("hc.pre"):
+                x = _merged(x, n)
         with jax.named_scope("head"):
             if real_len is not None:    # the one position a prefill returns
                 x = jnp.take_along_axis(

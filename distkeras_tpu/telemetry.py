@@ -216,6 +216,9 @@ METRIC_NAMES = {
     # and those of them that run the fused training kernel
     "model.attention.calls": "counter",
     "model.attention.fused_calls": "counter",
+    # the sub-layers of models/latent_moe.py traced with a hyper-connection
+    # mapping around them (a residual of several streams)
+    "model.hc.sublayers": "counter",
     # generative serving (KV-cache decode loop, DESIGN.md §14)
     "serving.decode.admitted": "counter",
     "serving.decode.cache_bytes": "gauge",

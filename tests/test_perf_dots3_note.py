@@ -167,11 +167,12 @@ def test_the_cell_is_the_issue_s_traffic(perf_modules):
     assert t["prompt"]["min"] > cell["config"]["index_topk"]
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert bench["workloads"][-1] == {
+    assert next(w for w in bench["workloads"] if w["name"] == CELL) == {
         "name": CELL, "config": "dots3_note", "traffic": "packdoc_closed",
         "chips": 1, "why": cell["cell"]["why"]}
-    assert bench["configs"][-1]["file"] == "perf/configs/dots3_note.json"
-    assert bench["configs"][-1]["reduced"] == cell["config"]["reduced"]
+    entry = next(c for c in bench["configs"] if c["name"] == "dots3_note")
+    assert entry["file"] == "perf/configs/dots3_note.json"
+    assert entry["reduced"] == cell["config"]["reduced"]
     mine = [m["name"] for m in bench["per_layer"]
             if CELL in m.get("workloads", [])]
     # the three this cell brought, in the order it appended them (later

@@ -3,7 +3,7 @@
 - the table of a small jitted function: every instruction that runs as a
   device event of its own under the innermost declared scope of its
   ``op_name`` path, fusions whole, ``""`` for what was traced outside;
-- the four families' serving executables (the rehearsal configurations of
+- the families' serving executables (the rehearsal configurations of
   ``perf/configs``, built by their ``perf/builders``): at least 95 % of the
   instructions under a declared scope, and every scope a family declares
   occurring;
@@ -195,16 +195,22 @@ def test_a_mosaic_call_is_a_row_of_the_scope_it_was_traced_under():
     assert {"attn.cache", "attn.scores"} <= set(scopes.declared_scopes())
 
 
-# --------------------------------------- (b) the four families' executables
+# ---------------------------------------- (b) the families' executables
 
 #: rehearsal configuration -> the scopes its family's file declares that
-#: this configuration has no mechanism for
+#: no instruction of its executables carries: this configuration has no
+#: mechanism for them, or (``hc.pre``, the mix into a sub-layer's input)
+#: the compiler fuses them into the next scope's instruction, the
+#: sub-layer's norm
 FAMILIES = {
     "gpt2_tiny": (),
     "mistral_small_4_tiny": ("attn.index", "attn.select", "attn.sparse",
-                             "attn.window", "mlp.dense"),
+                             "attn.window", "mlp.dense", "hc.map", "hc.pre",
+                             "hc.post"),
     "nemotron3_nano_tiny": (),
-    "dots3_note_tiny": (),
+    "dots3_note_tiny": ("hc.map", "hc.pre", "hc.post"),
+    "xing4_29b_a4b_tiny": ("attn.index", "attn.select", "attn.sparse",
+                           "attn.window", "hc.pre"),
 }
 _tables = {}
 
