@@ -432,7 +432,13 @@ class CausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, train: bool = False, cache=None,
-                 cache_index=None, page_table=None, cache_rows=None):
+                 cache_index=None, page_table=None, cache_rows=None,
+                 real_len=None):
+        """Logits ``[b, t, vocab]`` float32; with ``cache`` ``(logits,
+        new_cache)``. ``real_len [b]`` (a cache call only: a prefill of a
+        whole prompt, that many of its positions real) cuts the logits to
+        those of position ``real_len - 1``, ``[b, 1, vocab]``: the final
+        LayerNorm and the head run on that row alone."""
         dtype = precision_lib.resolve(self.precision, self.dtype)[0]
         ids = input_ids.astype(jnp.int32)
         b, t = ids.shape  # t = LOCAL block length under sequence parallelism
@@ -468,6 +474,10 @@ class CausalLM(nn.Module):
                         x, train, cache=cache[i], cache_index=cache_index,
                         page_table=page_table, cache_rows=cache_rows)
                 new_cache.append(layer_cache)
+            if real_len is not None:    # the one row a prefill returns
+                with jax.named_scope("head"):
+                    x = jnp.take_along_axis(
+                        x, (real_len - 1)[:, None, None], axis=1)
             return head(x), tuple(new_cache)
         if self.attention == "ring":
             # global positions of this device's block. psum(1) over the mesh

@@ -36,14 +36,16 @@ the other families'. An ``M`` block keeps ``{"ssm": [rows, H, P, N]
 float32, "conv": [rows, conv_kernel - 1, conv_dim]}``: a state a row with
 no position axis, which no mask hides (:attr:`HybridLM.cache_state_leaves`
 names them; what the engine promises each kind is in DESIGN.md). An ``E``
-block keeps nothing (``{}``). So a cache call is told how many of its
-block's positions are real (``real_len [b]``, every one by default): past
-it ``Delta = 0``, which leaves ``h`` exactly as it was, and the
-convolution's tail is the last real inputs. With ``real_len`` the logits
-are those of position ``real_len - 1`` alone, ``[b, 1, vocab]``: a prefill
-needs one row of the head, not its bucket's. A cache call returns
-``(logits, new_cache, routed)`` as :class:`latent_moe.LatentMoELM` does,
-``routed [E blocks, b, t, experts_held]``.
+block keeps nothing (``{}``). A prefill tells a model of every family how
+many of its block's positions are real (``real_len [b]``;
+``serving.generation.make_prefill_fn``); a cache call without it takes
+every one. Past it ``Delta = 0``, which leaves ``h`` exactly as it was,
+and the convolution's tail is the last real inputs. With ``real_len`` the
+logits are those of position ``real_len - 1`` alone, ``[b, 1, vocab]``,
+as every family's: a prefill needs one row of the head, not its bucket's.
+A cache call returns ``(logits, new_cache, routed)`` as
+:class:`latent_moe.LatentMoELM` does, ``routed [E blocks, b, t,
+experts_held]``.
 """
 
 from __future__ import annotations

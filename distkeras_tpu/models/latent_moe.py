@@ -736,8 +736,12 @@ class LatentAttention(nn.Module):
         the block's lines are written in place first and ``(out, new_cache,
         attended)`` returns; without, the block attends itself. ``attended
         [b, t]`` int32, the positions each query attended, is ``None``
-        without an indexer or a window. ``real_len [b]`` is for a window's
-        long block (:func:`_attend_window`)."""
+        without an indexer or a window. ``real_len [b]`` marks a prefill:
+        a whole prompt from position 0, that many of its positions real. A
+        window's long block builds its rings from the real positions
+        (:func:`_attend_window`), and a selection's long block attends in
+        stretches from position 0 (``from_zero`` of :func:`_attend_chosen`),
+        which only such a block may."""
         dtype = self.dtype
         width = x.shape[-1]
         heads, rank = self.num_heads, self.kv_lora_rank
@@ -1283,10 +1287,13 @@ class LatentMoELM(nn.Module):
         new_cache, routed)`` and, for a model under a selection (it
         declares ``cache_select_leaves``), ``attended [layers, b, t]``
         int32, the positions each query attended a layer (a window layer:
-        its window's). ``real_len [b]``
-        (a prefill of a model with rings: the block's real positions) also
-        cuts the logits to those of position ``real_len - 1``, ``[b, 1,
-        vocab]``."""
+        its window's). ``real_len [b]`` says the call is a prefill
+        (``serving.generation.make_prefill_fn``, every family): a whole
+        prompt written from position 0 into fresh rows, its first
+        ``real_len`` positions real. The attention takes it so
+        (:meth:`LatentAttention.__call__`), and the logits are cut to those
+        of position ``real_len - 1``, ``[b, 1, vocab]``: the streams' sum,
+        the final norm and the head run on that row alone."""
         del train                       # no dropout: serving only
         if page_table is not None:
             raise ValueError(
@@ -1364,13 +1371,14 @@ class LatentMoELM(nn.Module):
                 x = leave(x, y.reshape(b, t, self.width), hc)
             with jax.named_scope("moe.route"):
                 routed.append(sent.reshape(b, t, -1))
-        if n > 1:
+        if real_len is not None:        # the one position a prefill returns
+            with jax.named_scope("head"):
+                x = jnp.take_along_axis(
+                    x, (real_len - 1)[:, None, None], axis=1)
+        if n > 1:                       # a row's streams, summed on the row
             with jax.named_scope("hc.pre"):
                 x = _merged(x, n)
         with jax.named_scope("head"):
-            if real_len is not None:    # the one position a prefill returns
-                x = jnp.take_along_axis(
-                    x, (real_len - 1)[:, None, None], axis=1)
             head = self.param("head", param_init("head"),
                               (self.width, self.vocab_size), self.dtype)
             logits = jnp.dot(norm("final_norm", x).astype(self.dtype), head,

@@ -183,29 +183,44 @@ def make_prefill_fn(model, dtype=None):
     ``dynamic_update_slice`` a leaf: a leaf without a position axis is
     overwritten whole) and return the logits at position ``length - 1``
     (the first-token distribution). Bucket padding beyond ``length`` writes
-    cells the length mask hides until real tokens overwrite them; a model
-    that declares state leaves is told ``length`` (``real_len``), commits
-    its state after position ``length - 1`` and hands back that position's
-    logits alone."""
+    cells the length mask hides until real tokens overwrite them.
+
+    Every family is told ``length`` (``real_len``, DESIGN.md section 14):
+    the block is a whole prompt written from position 0, of which that
+    many positions are real. A model then applies its final norm and head
+    to position ``length - 1`` alone and hands back ``[1, 1, V]``; one
+    that keeps a state commits it after that position. A model that hands
+    back more rows breaks the contract and is refused as it is traced.
+    ``head_rows`` (an attribute of the function returned) maps each block
+    length the function was traced for to the rows of logits the model
+    computed there: what ``serving.prefill.head_positions`` counts."""
     import jax
     import jax.numpy as jnp
 
-    stateful = bool(state_leaves(model))
+    head_rows = {}
 
     def prefill(params, pool, ids, slot, length):
+        block = ids.shape[1]
         row = model.init_cache(
-            1, dtype, positions=model.prefill_row_len(ids.shape[1]))
-        told = {"real_len": jnp.reshape(length, (1,))} if stateful else {}
+            1, dtype, positions=model.prefill_row_len(block))
+        real_len = jnp.reshape(length, (1,))
         logits, new_row, *_ = model.apply(
             {"params": params}, ids, cache=row,
-            cache_index=jnp.zeros((1,), jnp.int32), **told)
+            cache_index=jnp.zeros((1,), jnp.int32), real_len=real_len)
+        if logits.shape[1] != 1:
+            raise ValueError(
+                f"{type(model).__name__} computed {logits.shape[1]} rows of "
+                f"logits for a {block}-token prefill told its real length: "
+                f"the contract is the row at real_len - 1 alone")
+        head_rows[block] = logits.shape[1]
         with jax.named_scope("cache.write"):
             pool = jax.tree.map(
                 lambda p, c: jax.lax.dynamic_update_slice_in_dim(
                     p, c, slot, axis=0), pool, new_row)
         with jax.named_scope("head"):
-            return pool, logits[0, 0 if stateful else length - 1]
+            return pool, logits[0, 0]
 
+    prefill.head_rows = head_rows
     return prefill
 
 
@@ -307,14 +322,19 @@ def make_paged_step_fn(model):
     after a prefix-cache hit, or one chunk of a chunked prefill at its
     cursor), decode is T=2 (token + ghost), verify is T=spec_k+1. The
     model's paged write-back routes every cell to its physical page;
-    ghost/overflow cells land in the scratch page."""
+    ghost/overflow cells land in the scratch page. The whole block's
+    logits come back and the host picks its row, so ``head_rows`` (as
+    :func:`make_prefill_fn`'s) maps each block length to itself."""
+    head_rows = {}
 
     def step(params, pages, page_tables, tokens, lengths):
         logits, new_pages = model.apply(
             {"params": params}, tokens, cache=pages, cache_index=lengths,
             page_table=page_tables)
+        head_rows[tokens.shape[1]] = logits.shape[1]
         return new_pages, logits
 
+    step.head_rows = head_rows
     return step
 
 
@@ -812,6 +832,11 @@ class GenerationEngine:
         self._prefill_tokens_c = telemetry.counter("serving.prefill.tokens")
         self._prefill_positions_c = telemetry.counter(
             "serving.prefill.positions")
+        # the positions at which those prefills computed the head: the
+        # rows of logits the model handed back, learned a block length as
+        # the executable was traced (make_prefill_fn's ``head_rows``)
+        self._head_positions_c = telemetry.counter(
+            "serving.prefill.head_positions")
         self._steps_c = telemetry.counter("serving.decode.steps")
         self._tokens_c = telemetry.counter("serving.decode.tokens")
         self._trace_rows_c = telemetry.counter("serving.decode.trace_rows")
@@ -931,6 +956,7 @@ class GenerationEngine:
         pick = pick_on_device if self._device_pick else (lambda fn: fn)
         if self._paged:
             step = make_paged_step_fn(self.model)
+            self._head_rows = step.head_rows
             pmax = self.pool.pages_per_slot
             for lb in self._buckets:
                 self._prefill_exec[lb] = _compile(
@@ -968,6 +994,7 @@ class GenerationEngine:
                     swap="in")
             return
         prefill = make_prefill_fn(self.model, self._pool_dtype)
+        self._head_rows = prefill.head_rows
         decode = pick(make_decode_fn(self.model))
         for lb in self._buckets:
             self._prefill_exec[lb] = _compile(
@@ -1484,6 +1511,7 @@ class GenerationEngine:
         self._prefills_c.inc()
         self._prefill_tokens_c.inc(n)
         self._prefill_positions_c.inc(lb)
+        self._head_positions_c.inc(self._head_rows[lb])
         self._prefill_h.record(now - t0)
         self._ttft_h.record(now - req.t_submit)
         self._owe_row(req, "trace.prefill", tp0,
@@ -1579,6 +1607,7 @@ class GenerationEngine:
                 logits_row = np.asarray(logits)[0, n - start - 1]
             self._prefill_tokens_c.inc(int(suffix.size))
             self._prefill_positions_c.inc(lb)
+            self._head_positions_c.inc(self._head_rows[lb])
         self._finish_prefill(req, slot, logits_row, ran_prefill, t0, tp0,
                              hit)
 
@@ -1643,6 +1672,7 @@ class GenerationEngine:
             self._chunk_steps_c.inc()
             self._prefill_tokens_c.inc(int(chunk.size))
             self._prefill_positions_c.inc(self._chunk)
+            self._head_positions_c.inc(self._head_rows[self._chunk])
             req.prefill_pos = pos + chunk.size
             self.pool.lengths[slot] = req.prefill_pos
             if req.prefill_pos >= n:

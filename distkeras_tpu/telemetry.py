@@ -317,7 +317,9 @@ METRIC_NAMES = {
     "serving.sparse.positions_attended": "counter",
     "serving.sparse.positions_cached": "counter",
     # prefill's padding: real prompt tokens, and the bucket (or chunk)
-    # positions computed for them (GenerationEngine, every family)
+    # positions computed for them (GenerationEngine, every family); and
+    # the positions of those at which the head was computed
+    "serving.prefill.head_positions": "counter",
     "serving.prefill.positions": "counter",
     "serving.prefill.tokens": "counter",
     # live rollout / canary / rollback plane (serving/rollout.py,

@@ -21,6 +21,8 @@ The load-bearing guarantees:
   request, fails or shuts down (ISSUE 33).
 """
 
+import os
+import sys
 import threading
 import time
 
@@ -42,6 +44,10 @@ from distkeras_tpu.serving.generation import (make_decode_fn,
                                               make_prefill_fn,
                                               make_verify_fn)
 from test_sched_phases import ENGINES
+
+sys.path.insert(0, os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "perf"))
+import harness  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -159,6 +165,87 @@ def test_prefill_then_decode_matches_full_forward(lm, bucket, steps,
             seq.append(toks[j])
             np.testing.assert_allclose(logits[j], ref(seq), **TOL)
             toks[j] = int(np.argmax(logits[j]))
+
+
+# ----------------------------------------------- the prefill's one row
+
+@pytest.mark.parametrize("length", [1, 9, 16],
+                         ids=["one", "middle", "whole_bucket"])
+def test_prefill_returns_the_full_forward_s_row_at_its_length(lm, length):
+    """``make_prefill_fn`` tells ``CausalLM`` the prompt's length and takes
+    the one row of logits it hands back: the full forward's row ``length -
+    1`` at a length of 1, one inside the bucket and the bucket's own."""
+    model, params = lm
+    seq = _prompt(length, seed=length)
+    _, _, lasts = _prefilled(model, params, [seq], 1, bucket=16)
+    np.testing.assert_allclose(lasts[0], _ref_fn(model, params)(seq), **TOL)
+
+
+def test_told_its_real_length_the_model_computes_one_row_of_the_head(lm):
+    """Under ``real_len`` a cache call hands back ``[1, 1, vocab]``, where
+    it hands back the block's ``[1, 16, vocab]`` without; the prefill
+    records the rows it was handed as it is traced."""
+    model, params = lm
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    row = jax.eval_shape(lambda: model.init_cache(1, positions=16))
+    call = lambda **told: jax.eval_shape(
+        lambda p, c, ids: model.apply(
+            {"params": p}, ids, cache=c,
+            cache_index=jnp.zeros((1,), jnp.int32), **told),
+        params, row, i32(1, 16))[0].shape
+    assert call(real_len=jnp.array([9])) == (1, 1, model.vocab_size)
+    assert call() == (1, 16, model.vocab_size)
+    prefill = make_prefill_fn(model)
+    pool = jax.eval_shape(lambda: model.init_cache(3))
+    _, last = jax.eval_shape(prefill, params, pool, i32(1, 16), i32(), i32())
+    assert last.shape == (model.vocab_size,)
+    assert prefill.head_rows == {16: 1}
+
+
+class _WholeBlock:
+    """``CausalLM`` with a head over the whole block: ``real_len`` is
+    dropped on its way to the model."""
+
+    def __init__(self, model):
+        self.model = model
+
+    def __getattr__(self, name):
+        return getattr(self.model, name)
+
+    def apply(self, variables, ids, real_len=None, **kw):
+        del real_len
+        return self.model.apply(variables, ids, **kw)
+
+
+def test_a_prefill_refuses_a_model_that_returns_the_whole_block(lm):
+    model, params = lm
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    pool = jax.eval_shape(lambda: model.init_cache(3))
+    with pytest.raises(ValueError, match="16 rows of logits"):
+        jax.eval_shape(make_prefill_fn(_WholeBlock(model)), params, pool,
+                       i32(1, 16), i32(), i32())
+
+
+def test_head_positions_count_one_row_a_prefill(lm):
+    """Over five admissions in two buckets the engine counts one head row
+    a prefill (``serving.prefill.head_positions``) beside the buckets'
+    positions, and the benchmark's metric file reads the share as 100
+    times the prefills over the positions."""
+    model, params = lm
+    sizes = (3, 12, 7, 16, 5)
+    with GenerationEngine(model, params, num_slots=2, slot_ladder=(1, 2),
+                          prefill_buckets=(8, 16)) as eng:
+        for f in [eng.generate(_prompt(n, seed=n), max_new_tokens=2)
+                  for n in sizes]:
+            f.result(timeout=60)
+    counters = telemetry.get_registry().snapshot()["counters"]
+    positions = sum(8 if n <= 8 else 16 for n in sizes)
+    assert counters["serving.prefill.head_positions"] == len(sizes)
+    assert counters["serving.prefill.positions"] == positions
+    spec = harness.load_json("metrics", "prefill_head_row_share.json")
+    reader = harness.load_module("readers", spec["reader"])
+    assert reader.read(None, None, **spec["args"]) == \
+        pytest.approx(100.0 * len(sizes) / positions)
 
 
 @pytest.mark.parametrize("t", [2, 4])

@@ -354,6 +354,34 @@ def test_every_family_counts_its_prefill_padding():
     assert snap["gauges"]["serving.decode.state_bytes"] == 0
 
 
+def test_the_prefill_lowers_to_the_program_it_was(tiny):
+    """Its state made this family's prefill told its real length before
+    every family was: ``make_prefill_fn`` lowers, for a bucket of one chunk
+    and one of five, to the text of that form, written out here."""
+    model, _ = tiny
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    pool = jax.eval_shape(lambda: model.init_cache(3))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    def prefill(params, pool, ids, slot, length):
+        row = model.init_cache(
+            1, None, positions=model.prefill_row_len(ids.shape[1]))
+        told = {"real_len": jnp.reshape(length, (1,))}
+        logits, new_row, *_ = model.apply(
+            {"params": params}, ids, cache=row,
+            cache_index=jnp.zeros((1,), jnp.int32), **told)
+        pool = jax.tree.map(
+            lambda p, c: jax.lax.dynamic_update_slice_in_dim(
+                p, c, slot, axis=0), pool, new_row)
+        return pool, logits[0, 0]
+
+    text = lambda fn, n: jax.jit(fn).lower(
+        params, pool, i32(1, n), i32(), i32()).as_text()
+    for n in (8, 40):
+        assert text(prefill, n) == text(make_prefill_fn(model), n)
+
+
 # ----------------------------------------------------- what the engine refuses
 
 @pytest.mark.parametrize("kw", [

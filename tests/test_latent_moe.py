@@ -204,6 +204,42 @@ def test_prefill_then_decode_through_the_pool_matches_every_position(tiny):
                 <= 2 * model.experts_per_token).all()
 
 
+@functools.lru_cache(maxsize=None)
+def _prefill(model):
+    return jax.jit(make_prefill_fn(model))
+
+
+@pytest.mark.parametrize("length", [1, 50, 80],
+                         ids=["one", "middle", "whole_bucket"])
+def test_prefill_returns_the_reference_row_at_its_length(tiny, length):
+    """A prefill in an 80-token bucket (the expanded form), told the
+    prompt's length, hands back the reference's logits at position ``length
+    - 1``: the head's one row, whatever the bucket's padding holds."""
+    model, params = tiny
+    ids = np.asarray(jax.random.randint(jax.random.key(length), (1, 80), 1,
+                                        model.vocab_size), np.int32)
+    want = np.asarray(reference(model, params, ids[:, :length]))[0, -1]
+    pool = KVCachePool(model, num_slots=2)
+    _, got = _prefill(model)(params, pool.pool, ids, np.int32(1),
+                             np.int32(length))
+    assert got.shape == (model.vocab_size,)
+    assert rel(got, want) < F32_TOLERANCE
+
+
+@pytest.mark.parametrize("t", [16, 80], ids=["absorbed", "expanded"])
+def test_told_its_real_length_the_model_computes_one_row_of_the_head(
+        tiny, t):
+    model, params = tiny
+    row = jax.eval_shape(lambda: model.init_cache(1, positions=t))
+    call = lambda **told: jax.eval_shape(
+        lambda p, c, ids: model.apply(
+            {"params": p}, ids, cache=c,
+            cache_index=jnp.zeros((1,), jnp.int32), **told),
+        params, row, jax.ShapeDtypeStruct((1, t), jnp.int32))[0].shape
+    assert call(real_len=jnp.array([t - 3])) == (1, 1, model.vocab_size)
+    assert call() == (1, t, model.vocab_size)
+
+
 def test_absorbed_form_equals_expanded_form_at_equal_inputs():
     heads, rank, nope, rope, v_dim = 4, 24, 8, 8, 12
     dims = (rank, nope, rope, v_dim, heads)
@@ -463,8 +499,9 @@ def test_pool_over_causal_lm_builds_the_leaves_it_built_before():
 def test_causal_lm_steps_lower_to_the_program_they_were():
     """The decode and prefill steps over ``CausalLM`` written as they
     were before the model owned its cache (the verify step and a slice;
-    a fresh ``[1, max_len, width]`` row) lower to the same program text
-    as ``make_decode_fn`` / ``make_prefill_fn`` give now."""
+    a fresh ``[1, max_len, width]`` row, told its real length and handing
+    back its one row of logits) lower to the same program text as
+    ``make_decode_fn`` / ``make_prefill_fn`` give now."""
     model = gpt_tiny()
     params = jax.eval_shape(lambda: model.init(
         jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
@@ -480,13 +517,14 @@ def test_causal_lm_steps_lower_to_the_program_they_were():
     def prefill(params, pool, ids, slot, length):
         row = jax.tree.map(
             lambda a: jnp.zeros((1,) + a.shape[1:], a.dtype), pool)
+        real_len = jnp.reshape(length, (1,))
         logits, new_row = model.apply(
             {"params": params}, ids, cache=row,
-            cache_index=jnp.zeros((1,), jnp.int32))
+            cache_index=jnp.zeros((1,), jnp.int32), real_len=real_len)
         pool = jax.tree.map(
             lambda p, c: jax.lax.dynamic_update_slice_in_dim(
                 p, c, slot, axis=0), pool, new_row)
-        return pool, logits[0, length - 1]
+        return pool, logits[0, 0]
 
     text = lambda fn, *args: jax.jit(fn).lower(params, pool, *args).as_text()
     strip = lambda s: s.replace("jit_decode", "").replace("jit_prefill", "")

@@ -691,6 +691,62 @@ def test_a_model_with_nothing_configured_carries_nothing_of_it(step):
             assert len(lowered.out_info) == (4 if has else 3)
 
 
+def test_the_prefill_lowers_to_the_program_it_was(tiny):
+    """Its rings are state, so this family's prefill was told its real
+    length before every family was: ``make_prefill_fn`` lowers, for a short
+    and a long bucket, to the text of that form, written out here."""
+    model, _ = tiny
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    pool = jax.eval_shape(lambda: model.init_cache(3))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    def prefill(params, pool, ids, slot, length):
+        row = model.init_cache(
+            1, None, positions=model.prefill_row_len(ids.shape[1]))
+        told = {"real_len": jnp.reshape(length, (1,))}
+        logits, new_row, *_ = model.apply(
+            {"params": params}, ids, cache=row,
+            cache_index=jnp.zeros((1,), jnp.int32), **told)
+        pool = jax.tree.map(
+            lambda p, c: jax.lax.dynamic_update_slice_in_dim(
+                p, c, slot, axis=0), pool, new_row)
+        return pool, logits[0, 0]
+
+    text = lambda fn, n: jax.jit(fn).lower(
+        params, pool, i32(1, n), i32(), i32()).as_text()
+    for n in (16, 100):
+        assert text(prefill, n) == text(make_prefill_fn(model), n)
+
+
+def test_an_indexed_model_without_rings_prefills_in_stretches(monkeypatch):
+    """``real_len`` says a whole prompt from position 0, and a model under
+    a selection with no ring (no state, so told it only since every family
+    is) takes it so: with query blocks of 32 each full layer's 100-token
+    prefill scores its queries in stretches against the rows up to each
+    stretch's end (``from_zero``), and the logits at ``length - 1`` are the
+    reference's."""
+    monkeypatch.setattr(lm, "_QUERY_BLOCK", 32)
+    model = dots_tiny(layer_kinds=None, window_sizes=None)
+    assert state_leaves(model) == () and select_leaves(model) == ("ik",)
+    params = _compiled(model, "init")(jax.random.key(0))
+    cfg = dict(config_of(dots_tiny()), layer_types=["full_attention"] * 4)
+    reference = jax.jit(lambda p, i: ref.forward(p, i, cfg))
+    met, scores = [], lm.index_scores
+    monkeypatch.setattr(lm, "index_scores", lambda iq, iw, keys, at: (
+        met.append(keys.shape[1]), scores(iq, iw, keys, at))[1])
+    prefill = jax.jit(make_prefill_fn(model))
+    seq = np.random.default_rng(5).integers(
+        1, model.vocab_size, 100).astype(np.int32)
+    for n in (100, 77):
+        pool = KVCachePool(model, num_slots=2)
+        _, got = prefill(params, pool.pool, seq[None], np.int32(0),
+                         np.int32(n))
+        want = np.asarray(reference(params, seq[None, :n]))[0, -1]
+        assert rel(got, want) < TOL, n
+    assert met == [32, 64, 96, 100] * 4
+
+
 #: sha256 (first 16 digits) of what ``latent_moe_tiny(max_len=96)`` lowers
 #: to at the commit before this module had an indexer, layer kinds, gates or
 #: the rescale (PR 33; ``jax.jit(...).lower(...).as_text()``, which carries

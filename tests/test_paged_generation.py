@@ -167,6 +167,24 @@ def test_engine_paged_matches_rect_and_reference(lm):
     assert got == want
 
 
+@pytest.mark.parametrize("chunk", [None, 8], ids=["paged", "chunked"])
+def test_paged_prefills_count_the_whole_block_of_the_head(lm, chunk):
+    """The paged step hands back every position's logits and the host
+    picks the row: its prefills and chunks count the whole block at
+    ``serving.prefill.head_positions``, as many as ``.positions``."""
+    model, params = lm
+    with GenerationEngine(model, params, num_slots=2,
+                          prefill_buckets=(8, 32), page_size=16,
+                          prefill_chunk=chunk) as eng:
+        for f in [eng.generate(_prompt(n, seed=n), max_new_tokens=2)
+                  for n in (5, 20)]:
+            f.result(timeout=60)
+    counters = telemetry.get_registry().snapshot()["counters"]
+    assert counters["serving.prefill.positions"] == (32 if chunk else 40)
+    assert counters["serving.prefill.head_positions"] == \
+        counters["serving.prefill.positions"]
+
+
 # ------------------------------------------------------------ prefix cache
 
 def test_prefix_full_hit_identical_output_zero_prefills(lm):

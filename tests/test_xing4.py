@@ -232,6 +232,36 @@ def test_prefill_then_decode_through_the_pool_matches_every_position(tiny):
         assert held.shape == (2, model.experts_held)     # the expert layers
 
 
+@pytest.mark.parametrize("length", [1, 50, 96],
+                         ids=["one", "middle", "whole_bucket"])
+def test_prefill_returns_the_reference_row_at_its_length(tiny, length):
+    """A prefill in a 96-token bucket, told the prompt's length, sums the
+    four streams, norms and computes the head at position ``length - 1``
+    alone: the reference's logits there, whatever the padding holds."""
+    model, params = tiny
+    seq = np.random.default_rng(length).integers(
+        1, model.vocab_size, 96).astype(np.int32)
+    want = np.asarray(reference(params, seq[None, :length]))[0, -1]
+    pool = KVCachePool(model, num_slots=2)
+    ids = seq[None].copy()
+    _, got = _compiled(model, "prefill")(params, pool.pool, ids,
+                                         np.int32(1), np.int32(length))
+    assert got.shape == (model.vocab_size,)
+    assert rel(got, want) < TOL
+
+
+def test_told_its_real_length_the_model_computes_one_row_of_the_head(tiny):
+    model, params = tiny
+    row = jax.eval_shape(lambda: model.init_cache(1, positions=96))
+    call = lambda **told: jax.eval_shape(
+        lambda p, c, ids: model.apply(
+            {"params": p}, ids, cache=c,
+            cache_index=jnp.zeros((1,), jnp.int32), **told),
+        params, row, jax.ShapeDtypeStruct((1, 96), jnp.int32))[0].shape
+    assert call(real_len=jnp.array([40])) == (1, 1, model.vocab_size)
+    assert call() == (1, 96, model.vocab_size)
+
+
 def test_engine_tokens_are_the_reference_argmax_and_counters_add_up(tiny):
     """Through ``GenerationEngine`` with no keyword of its own: prompts in
     padded buckets, 12 tokens each, three at once on four slots; every
